@@ -3,12 +3,15 @@
 Reference: crypto/batch/batch.go — CreateBatchVerifier (:10),
 SupportsBatchVerifier (:21); only ed25519 supports batching.
 
-TPU-native addition: a process-global backend selector (the `crypto.backend`
-config key from BASELINE.json's north star). Backends:
-  * "tpu"  — JAX/XLA data-parallel verifier (ops/ed25519_jax.py); used when a
-             TPU (or any JAX device) is available. Falls back to "cpu" when
-             JAX import or device init fails.
-  * "cpu"  — per-signature OpenSSL loop (crypto/ed25519.py).
+TPU-native addition: a process-global backend selector, set with
+``set_backend()`` or COMETBFT_TPU_CRYPTO_BACKEND.  Backends:
+  * "tpu"  — the data-parallel device verifier (ops/ed25519_jax.py).
+             Asking for it on a host whose JAX platform is not a TPU
+             is an error at selection time (ops/device.py decides).
+  * "cpu"  — the native RLC batch equation over a Pippenger MSM
+             (crypto/ed25519.CpuBatchVerifier); never imports JAX.
+  * "auto" — "tpu" when ops/device.py finds a TPU, else "cpu";
+             resolved once per process, blocking, and logged.
 
 Every verifier this module hands out also answers ``verify_async()``
 (keys.BatchVerifier): an awaitable verdict future whose work runs on
@@ -84,65 +87,67 @@ def _observe_verify(backend: str, n: int, elapsed_s: float) -> None:
     verify_seconds_histogram().with_labels(
         backend, str(pad_bucket(n))).observe(elapsed_s)
 
-_backend: Optional[str] = None
-_auto_probe: Optional[str] = None   # cached auto-detection result
-_probe_thread: Optional[threading.Thread] = None
-_probe_result: Optional[str] = None
-
-
-def _platform_probe() -> None:
-    """Resolve the default JAX backend in a daemon thread: device
-    init can block for minutes on a pooled/tunneled TPU, and a node
-    must not hang its first CheckTx on that."""
-    global _probe_result
-    try:
-        import jax
-        _probe_result = \
-            "tpu" if jax.default_backend() == "tpu" else "cpu"
-    except Exception:
-        _probe_result = "cpu"
+_backend: Optional[str] = None      # set_backend() choice; None = env/auto
+_resolved: Optional[str] = None     # what env/auto resolved to, once
+_resolve_lock = threading.Lock()
 
 
 def set_backend(name: str) -> None:
-    """Select the batch-verification backend: 'tpu', 'cpu', or 'auto'."""
+    """Select the batch-verification backend: 'tpu', 'cpu', or 'auto'.
+    'tpu' without a TPU raises here, not at the first batch."""
     global _backend
     if name not in ("tpu", "cpu", "auto"):
         raise ValueError(f"unknown crypto backend {name!r}")
+    if name == "tpu":
+        from ..ops import device
+        device.require_tpu()
     _backend = None if name == "auto" else name
 
 
 def get_backend() -> str:
-    global _auto_probe
+    """The backend in force.  Without set_backend() the choice comes
+    from COMETBFT_TPU_CRYPTO_BACKEND (default auto) and is resolved
+    ONCE: the first ask blocks on JAX's backend start-up — seconds on a
+    chip host, so the node asks at start, off the event loop — and the
+    answer never flips mid-run."""
+    global _resolved
     if _backend is not None:
         return _backend
-    env = os.environ.get("COMETBFT_TPU_CRYPTO_BACKEND")
-    if env:
-        env = env.lower()
-        if env in ("tpu", "cpu"):
-            return env
-        if env != "auto":
-            raise ValueError(
-                f"COMETBFT_TPU_CRYPTO_BACKEND={env!r}: expected tpu|cpu|auto")
-    # auto: the kernel path only pays off on an actual TPU — on a
-    # CPU-only box the XLA kernel is orders of magnitude slower than
-    # the OpenSSL loop, so importability of jax is NOT the signal;
-    # the resolved platform is
-    global _auto_probe, _probe_thread
-    if _auto_probe is None:
-        if _probe_thread is None:
-            _probe_thread = threading.Thread(
-                target=_platform_probe, daemon=True)
-            _probe_thread.start()
-        # grace period only: the CPU path serves correctly while a
-        # slow device claim resolves in the background — blocking a
-        # node's first commit verification on the pool would invert
-        # the probe's purpose
-        _probe_thread.join(timeout=float(os.environ.get(
-            "COMETBFT_TPU_PROBE_TIMEOUT", "2")))
-        if _probe_result is None:
-            return "cpu"    # probe unresolved; retry next call
-        _auto_probe = _probe_result
-    return _auto_probe
+    if _resolved is None:
+        with _resolve_lock:
+            if _resolved is None:
+                _resolved = _resolve_backend()
+    return _resolved
+
+
+def _resolve_backend() -> str:
+    env = os.environ.get("COMETBFT_TPU_CRYPTO_BACKEND", "").lower() \
+        or "auto"
+    if env == "cpu":
+        return "cpu"
+    if env not in ("tpu", "auto"):
+        raise ValueError(
+            f"COMETBFT_TPU_CRYPTO_BACKEND={env!r}: expected tpu|cpu|auto")
+    from ..libs.log import new_logger
+    from ..ops import device
+    log = new_logger("crypto")
+    if env == "tpu":
+        dev = device.require_tpu()
+    else:
+        try:
+            dev = device.probe()
+        except ImportError as e:
+            # no JAX installed is a CPU-only host, not a lost device;
+            # a backend that fails to START raises out of here instead
+            log.info("crypto backend resolved", backend="cpu",
+                     reason=f"jax not importable: {e}")
+            return "cpu"
+    backend = "tpu" if dev.is_tpu else "cpu"
+    log.info("crypto backend resolved", backend=backend,
+             requested=env, platform=dev.platform,
+             device_kind=dev.kind, devices=dev.count,
+             compile_cache=dev.cache_dir)
+    return backend
 
 
 # bls12381.KEY_TYPE, spelled locally so this module does not import
@@ -196,14 +201,17 @@ def batch_verify_by_type(entries) -> list:
 
 
 # --- TPU dispatch circuit breaker ------------------------------------
-# A failed kernel compile/dispatch on this platform is deterministic
-# per process (e.g. the Pallas TPU kernel on a GPU or unknown
-# accelerator): without a breaker the dispatch re-attempted — and
-# re-paid — the failed compile on EVERY batch (ADVICE r5 #1).  The
-# first non-transient failure latches the breaker open and every later
-# batch goes straight to the CPU verifier; transient faults (pooled
-# TPU hiccups) open it for a timeout and then re-probe once.  Breaker
-# state is exported on the process-global metrics registry.
+# A validator that loses its device mid-run must keep voting, so a
+# failed kernel compile/dispatch falls back to the CPU verifier for
+# that batch.  A compile failure is deterministic per process:
+# without a breaker the dispatch re-attempted — and re-paid — it on
+# EVERY batch.  The first non-transient failure latches the breaker
+# open and every later batch goes straight to the CPU verifier;
+# transient faults open it for a timeout and then re-probe once.  The
+# fallback is loud: the caught exception is logged when it opens the
+# breaker, the state is exported on the process-global metrics
+# registry, and measurement commands (chip_smoke.py, bench.py) treat
+# a breaker that is not closed as failure.
 
 _TPU_BREAKER = None
 
@@ -246,11 +254,12 @@ def _is_transient_kernel_error(e: BaseException) -> bool:
 class GuardedTpuBatchVerifier(BatchVerifier):
     """TPU batch verifier behind the process-global circuit breaker.
 
-    verify() attempts the JAX/XLA kernel only while the breaker
-    admits it; a dispatch failure records against the breaker (latched
-    open for non-transient faults, so the failing kernel is attempted
-    at most once per process) and the SAME batch falls back to the CPU
-    verifier — callers always get a verdict."""
+    verify() attempts the device kernel only while the breaker admits
+    it; a dispatch failure records against the breaker (latched open
+    for non-transient faults, so the failing kernel is attempted at
+    most once per process), is logged with its type and message, and
+    the SAME batch falls back to the CPU verifier — callers always
+    get a verdict."""
 
     def __init__(self, breaker=None):
         self._breaker = breaker if breaker is not None else tpu_breaker()
@@ -280,8 +289,14 @@ class GuardedTpuBatchVerifier(BatchVerifier):
                     out = verify_batch([(pk.bytes(), m, s)
                                         for pk, m, s in self._items])
             except Exception as e:  # noqa: BLE001 — fall back below
-                br.record_failure(
-                    latch=not _is_transient_kernel_error(e))
+                latch = not _is_transient_kernel_error(e)
+                br.record_failure(latch=latch)
+                from ..libs.log import new_logger
+                new_logger("crypto").error(
+                    "TPU batch verify failed; falling back to the CPU "
+                    "verifier", error=f"{type(e).__name__}: {e}",
+                    batch=len(self._items), breaker=br.state,
+                    latched=latch, exc_info=True)
             else:
                 br.record_success()
                 _observe_verify("tpu", len(self._items),

@@ -2,12 +2,12 @@
 terminal state for non-transient faults.
 
 Built for the TPU kernel dispatch path (crypto/batch.py): a failed
-Pallas compile on a non-TPU accelerator is deterministic per process,
-so re-attempting it per batch burns seconds of compile time on every
-commit (ADVICE r5 #1).  The breaker classifies that as non-transient
-and LATCHES open — the fallback path is taken forever, no re-probe.
-Transient faults (pooled-TPU hiccups, timeouts) open the breaker for
-``reset_timeout_s`` and then admit a single half-open probe.
+kernel compile is deterministic per process, so re-attempting it per
+batch burns seconds of compile time on every commit.  The breaker
+classifies that as non-transient and LATCHES open — the fallback
+path is taken forever, no re-probe.  Transient faults (timeouts,
+connection resets) open the breaker for ``reset_timeout_s`` and then
+admit a single half-open probe.
 
 State is exported as a gauge on whatever metrics registry the caller
 wires in, so a degraded node is visible at /metrics.

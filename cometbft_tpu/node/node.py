@@ -234,10 +234,25 @@ class Node:
         """Boot order mirrors node.OnStart."""
         cfg = self.config
 
-        # compile the C++ fast paths off-thread so the first big
-        # merkle hash in the consensus loop never waits on g++
-        from ..crypto._native_loader import prebuild_async
-        prebuild_async()
+        # resolve the crypto backend here, once, off the event loop:
+        # under `auto` the first ask starts the JAX backend, which
+        # blocks for seconds on a chip host — and fails the start,
+        # loudly, if the backend cannot come up
+        from ..crypto import batch as crypto_batch
+        backend = await asyncio.to_thread(crypto_batch.get_backend)
+        if backend == "tpu":
+            # a device node needs the native host prep before its
+            # first batch and the kernel compiled before consensus
+            # starts — else the first commit stalls for a Mosaic
+            # compile inside the consensus timeouts
+            await asyncio.to_thread(
+                warm_device_path,
+                self.initial_state.validators.size())
+        else:
+            # compile the C++ fast paths off-thread so the first big
+            # merkle hash in the consensus loop never waits on g++
+            from ..crypto._native_loader import prebuild_async
+            prebuild_async()
 
         if cfg.base.priv_validator_laddr:
             from ..privval.signer import (
@@ -644,6 +659,18 @@ class Node:
                 "voting_power": str(_voting_power(state, pub)),
             },
         }
+
+
+def warm_device_path(n_validators: int) -> None:
+    """Build the native host prep and compile every kernel shape an
+    n_validators set dispatches at: a full commit (verify_commit), the
+    +2/3 prefix verify_commit_light stops at, and a small vote batch.
+    Blocking — g++ and Mosaic compiles; call it off the event loop."""
+    from ..crypto._native_loader import load
+    from ..ops import ed25519_jax
+    load(allow_build=True)
+    for n in sorted({1, n_validators * 2 // 3 + 1, n_validators}):
+        ed25519_jax.warmup(n)
 
 
 def _voting_power(state, pub) -> int:

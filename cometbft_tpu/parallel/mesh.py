@@ -17,13 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    # dependency gate: jax < 0.5 ships shard_map under experimental;
-    # the installed 0.4.37 has no top-level alias
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+from ..ops import device
 from ..ops.ed25519_jax import _verify_kernel
 
 BATCH_AXIS = "sig_batch"
@@ -31,6 +25,7 @@ BATCH_AXIS = "sig_batch"
 
 def make_mesh(n_devices: int | None = None) -> Mesh:
     """1-D mesh over the first n_devices JAX devices."""
+    device.probe()      # first touch places the compile cache
     devs = jax.devices()
     if n_devices is not None:
         if len(devs) < n_devices:
@@ -63,13 +58,20 @@ def _sharded_verify_fn(ndev: int, kernel: str, interpret: bool,
     else:
         def body(a, r, s, k):
             return _verify_kernel(a, r, _win_cols(s), _win_cols(k))
+    # the jitted executable takes the body's name: what a compile log
+    # shows for this kernel
+    body.__name__ = f"sharded_{kernel}_verify"
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(BATCH_AXIS), P(BATCH_AXIS),
                   P(BATCH_AXIS), P(BATCH_AXIS)),
         out_specs=P(BATCH_AXIS),
+        # the varying-axes check cannot type a pallas_call body (its
+        # out_shape and in-kernel constants carry no mesh axes); the
+        # body is lane-parallel with no collective for it to guard
+        check_vma=not kernel.startswith("pallas"),
     )
     return jax.jit(shard)
 
@@ -173,7 +175,7 @@ def sharded_verify_tally(mesh: Mesh):
         count = jax.lax.psum(jnp.sum(ok.astype(jnp.int32)), BATCH_AXIS)
         return ok, count
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(BATCH_AXIS), P(BATCH_AXIS),

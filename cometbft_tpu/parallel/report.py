@@ -1,10 +1,11 @@
-"""Sharded-dispatch evidence for the 10k north star (VERDICT r4 #6).
+"""Sharded-dispatch model for the 10k north star.
 
 The < 5 ms claim for the 10k commit has always rested on 8-way
-sharding.  This module pins down what this environment CAN prove and
-derives the sharded estimate from MEASURED single-chip numbers
-(BENCH_CACHE.json when the round has one, else round 4's live-TPU
-measurement), with every assumption stated in the artifact:
+sharding.  This module pins down what a CPU host CAN prove and
+derives the sharded estimate from the one single-chip number on
+record (round 4's device-only measurement, taken before the current
+kernel source and not reproduced since), with every assumption stated
+in the artifact — a model, not a measurement:
 
   * geometry: the production verify_sharded padding/rounding for
     m = 10240 over ndev devices (per-shard lanes, pallas grid steps);
@@ -34,25 +35,12 @@ N_STAR = 10_000
 BUCKET = 10_240
 NDEV = 8
 
-# Round-4 live-TPU measurement (KERNEL_NOTES.md "MEASURED on TPU
-# v5e-1"): the 24-limb pallas kernel, device-only, m=16384 — the
-# fallback calibration when the current round has no cache record.
+# Round-4 measurement (KERNEL_NOTES.md "MEASURED on TPU v5e-1"): the
+# 24-limb pallas kernel, device-only, m=16384 — the model's only
+# calibration, older than the current kernel source.
 R4_MEASURED = {"device_ms": 116.0, "bucket": 16384,
-               "source": "round-4 live measurement (KERNEL_NOTES.md)"}
-
-
-def _best_device_record() -> dict:
-    from ..tools import tpu_probe
-    recs = [r for r in tpu_probe.read_records()
-            if r.get("platform") == "tpu" and "error" not in r
-            and r.get("metric") == "pallas_device_only"
-            and r.get("value_ms")]
-    if not recs:
-        return dict(R4_MEASURED)
-    best = min(recs, key=lambda r: r["value_ms"] / r.get("bucket", 1))
-    return {"device_ms": best["value_ms"], "bucket": best["bucket"],
-            "source": f"BENCH_CACHE.json {best.get('ts')} "
-                      f"rev {best.get('git_rev')}"}
+               "source": "round-4 measurement (KERNEL_NOTES.md), "
+                         "older than the current kernel source"}
 
 
 def _collectives(hlo: str) -> list[str]:
@@ -128,7 +116,7 @@ def sharded_10k_report(ndev: int = NDEV, m: int = BUCKET,
     executed = bool(list(ok) == golden)
 
     # --- timing model from measured numbers -------------------------
-    cal = _best_device_record()
+    cal = dict(R4_MEASURED)
     us_per_lane = cal["device_ms"] * 1000.0 / cal["bucket"]
     # dispatch overhead: bounded by the spread of the measured runs
     # (launch + sync, single chip); use 0.5 ms/chip as the stated cap
@@ -147,7 +135,7 @@ def sharded_10k_report(ndev: int = NDEV, m: int = BUCKET,
             "(launch + output sync; the mask all-gather is 1 byte/"
             "lane = 1.3 kB/chip, negligible on ICI)",
             "every chip runs the same kernel the single-chip "
-            "measurement ran (same AOT artifact, smaller grid)",
+            "measurement ran (smaller grid)",
         ],
         "single_chip_10240_ms": round(single_ms, 1),
         "sharded_8way_ms": round(sharded_ms, 1),

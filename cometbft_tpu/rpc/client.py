@@ -161,9 +161,11 @@ class HTTPClient:
 
     async def abci_query(self, path: str, data: bytes,
                          height: int = 0, prove: bool = False) -> dict:
+        # the server reads a bare string as the key itself; bytes
+        # travel as 0x-prefixed hex (rpc/core._decode_hex_or_str)
         return await self.call("abci_query", path=path,
-                               data=data.hex(), height=str(height),
-                               prove=prove)
+                               data="0x" + data.hex(),
+                               height=str(height), prove=prove)
 
     async def broadcast_tx_sync(self, tx: bytes) -> dict:
         return await self.call("broadcast_tx_sync",

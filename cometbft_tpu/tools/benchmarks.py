@@ -24,6 +24,53 @@ def _now() -> float:
     return time.perf_counter()
 
 
+# --- seeded workloads -------------------------------------------------------
+# Everything a measurement command verifies is made from its --seed
+# (gen_priv_key_from_secret, never `secrets`), so two runs of the same
+# command check the same data and a reference can recompute it.
+
+MSG_LEN = 110       # a precommit's canonical sign-bytes, roughly
+
+
+def seeded_privs(n: int, seed: int, tag: str = "val") -> list:
+    """n distinct deterministic ed25519 private keys."""
+    from ..crypto import ed25519
+    return [ed25519.gen_priv_key_from_secret(
+        b"%s-%d-%d" % (tag.encode(), seed, i)) for i in range(n)]
+
+
+def seeded_sig_items(n: int, seed: int) -> list:
+    """n (pub_bytes, msg, sig) triples with DISTINCT keys over
+    distinct MSG_LEN-byte messages — the shape of an n-validator
+    commit burst at the raw BatchVerifier seam."""
+    import hashlib
+    base = hashlib.sha512(b"msg-%d" % seed).digest() * 2
+    items = []
+    for i, priv in enumerate(seeded_privs(n, seed, "sig")):
+        msg = base[:MSG_LEN - 8] + i.to_bytes(8, "little")
+        items.append((priv.pub_key().bytes(), msg, priv.sign(msg)))
+    return items
+
+
+def seeded_commit(n_vals: int, seed: int, height: int = 1,
+                  chain_id: str = "seeded-chain"):
+    """An n_vals-validator equal-power ed25519 set and the commit it
+    signs at ``height``: (chain_id, vset, block_id, commit).
+    The keys depend on the seed only, the block id and signatures on
+    the height too — a fresh height is a fresh commit no memo has
+    seen."""
+    import hashlib
+
+    from ..types.block_id import BlockID
+    from ..types.part_set import PartSetHeader
+
+    vset, privs = _make_valset(seeded_privs(n_vals, seed))
+    bid = BlockID(
+        hash=hashlib.sha256(b"block-%d-%d" % (seed, height)).digest(),
+        part_set_header=PartSetHeader(1, b"\x34" * 32))
+    commit = _signed_commit(chain_id, vset, privs, height, bid)
+    return chain_id, vset, bid, commit
+
 
 def _make_valset(privs):
     """ValidatorSet sorted the consensus way, with privkeys re-paired
@@ -88,11 +135,12 @@ def config2_batch_verify(sizes=(64, 1024, 10_000)) -> dict:
             "results_ms": results}
 
 
-def config3_light_client(n_vals=1000, hops=4) -> dict:
-    """Reference: light/verifier.go VerifyNonAdjacent with a large
-    valset (BASELINE config #3: 1k-validator SignedHeader chain)."""
+def light_chain(n_vals: int, hops: int, seed=None):
+    """BASELINE config #3's fixture: a trusted SignedHeader at height
+    1 and ``hops`` later ones, 10 heights apart, all signed by one
+    n_vals-validator set.  Returns (trusted, vset, targets, now).
+    Keys come from ``seed`` when given, else from the OS."""
     from ..crypto import ed25519
-    from ..light.verifier import DEFAULT_TRUST_LEVEL, verify
     from ..types.block import Header, SignedHeader
     from ..types.block_id import BlockID
     from ..types.part_set import PartSetHeader
@@ -100,7 +148,8 @@ def config3_light_client(n_vals=1000, hops=4) -> dict:
 
     chain_id = "light-bench"
     vset, privs = _make_valset(
-        [ed25519.gen_priv_key() for _ in range(n_vals)])
+        seeded_privs(n_vals, seed, "light") if seed is not None
+        else [ed25519.gen_priv_key() for _ in range(n_vals)])
 
     def signed_header(height: int) -> SignedHeader:
         hdr = Header(chain_id=chain_id, height=height,
@@ -116,12 +165,26 @@ def config3_light_client(n_vals=1000, hops=4) -> dict:
 
     trusted = signed_header(1)
     targets = [signed_header(1 + 10 * (i + 1)) for i in range(hops)]
-    now = Timestamp(1700000600, 0)
+    return trusted, vset, targets, Timestamp(1700000600, 0)
+
+
+def light_verify(trusted, vset, target, now) -> None:
+    """One skipping hop with config #3's trust parameters (reference:
+    light/verifier.go Verify -> VerifyNonAdjacent); raises on an
+    unverifiable header."""
+    from ..light.verifier import DEFAULT_TRUST_LEVEL, verify
+    verify(trusted, vset, target, vset,
+           365 * 24 * 3600 * 10 ** 9, now, 10 ** 9,
+           DEFAULT_TRUST_LEVEL)
+
+
+def config3_light_client(n_vals=1000, hops=4) -> dict:
+    """Reference: light/verifier.go VerifyNonAdjacent with a large
+    valset (BASELINE config #3: 1k-validator SignedHeader chain)."""
+    trusted, vset, targets, now = light_chain(n_vals, hops)
     t0 = _now()
     for sh in targets:
-        verify(trusted, vset, sh, vset,
-               365 * 24 * 3600 * 10 ** 9, now, 10 ** 9,
-               DEFAULT_TRUST_LEVEL)
+        light_verify(trusted, vset, sh, now)
     dt = (_now() - t0) * 1000
     return {"config": 3, "metric": "light_skipping_verify_ms_per_hop",
             "validators": n_vals, "hops": hops,

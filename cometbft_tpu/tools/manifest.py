@@ -453,11 +453,17 @@ class RunReport:
 
 async def run_manifest(manifest: Manifest, outdir: str,
                        target_height: int = 8,
-                       timeout_s: float = 90.0) -> RunReport:
+                       timeout_s: float = 90.0,
+                       while_up=None) -> RunReport:
     """Boot every node, inject load, apply perturbations once the net
     is past the halfway height, wait for target_height everywhere,
     then check cross-node block-hash/app-hash invariants
-    (reference: runner/main.go stage order; tests/block_test.go)."""
+    (reference: runner/main.go stage order; tests/block_test.go).
+
+    ``while_up(nodes)`` — an optional coroutine function — is awaited
+    with the live nodes once every one of them is at target_height,
+    before teardown: the place for checks that need the net serving
+    (reference: the e2e tests run against the still-running net)."""
     from ..node.node import Node
     from ..rpc.client import HTTPClient
     from . import loadtime
@@ -551,6 +557,8 @@ async def run_manifest(manifest: Manifest, outdir: str,
         await wait_height(target_height, timeout_s / 2)
         report.reached_target_s = \
             asyncio.get_event_loop().time() - boot_t0
+        if while_up is not None:
+            await while_up(nodes)
 
         # wait for injected evidence to land in committed blocks
         if report.evidence_injected:

@@ -218,12 +218,22 @@ class TestCircuitBreaker:
 # ---------------------------------------------------------------------
 # TPU dispatch behind the breaker (crypto/batch.py)
 
+def _pretend_tpu(monkeypatch):
+    """set_backend("tpu") refuses a host without a TPU; these tests
+    inject the kernel fault themselves, so the device gate is told
+    one is there."""
+    from cometbft_tpu.ops import device
+    monkeypatch.setattr(device, "_device",
+                        device.Device("tpu", "fake", 1, ""))
+
+
 class TestTpuDispatchBreaker:
     def test_failing_kernel_attempted_at_most_once(self, monkeypatch):
         from cometbft_tpu.crypto import batch as crypto_batch
         from cometbft_tpu.crypto import ed25519
         from cometbft_tpu.ops import ed25519_jax as ej
 
+        _pretend_tpu(monkeypatch)
         attempts = []
 
         def exploding_verify(items):
@@ -256,17 +266,60 @@ class TestTpuDispatchBreaker:
             crypto_batch.set_backend("cpu")
             crypto_batch.reset_tpu_breaker()
 
+    def test_fallback_logs_the_caught_exception_once_per_latch(
+            self, monkeypatch, crypto_log):
+        """The fallback is loud: one log record names the exception's
+        type and message when it opens the breaker; later batches
+        never reach the kernel, so they add none."""
+        import logging
+
+        from cometbft_tpu.crypto import batch as crypto_batch
+        from cometbft_tpu.crypto import ed25519
+        from cometbft_tpu.ops import ed25519_jax as ej
+
+        _pretend_tpu(monkeypatch)
+
+        def exploding_verify(items):
+            raise NotImplementedError(
+                "Mosaic failed to compile TPU kernel: unsupported "
+                "unaligned sublane slice")
+
+        monkeypatch.setattr(ej, "verify_batch", exploding_verify)
+        crypto_batch.reset_tpu_breaker()
+        try:
+            crypto_batch.set_backend("tpu")
+            pk = ed25519.gen_priv_key()
+            pub = pk.pub_key()
+            for _ in range(3):
+                bv = crypto_batch.create_batch_verifier(pub)
+                for m in (b"a", b"b"):
+                    bv.add(pub, m, pk.sign(m))
+                ok, mask = bv.verify()
+                assert ok and list(mask) == [True, True]
+            errors = [r for r in crypto_log
+                      if r.levelno >= logging.ERROR]
+            assert len(errors) == 1
+            text = errors[0].getMessage()
+            assert "NotImplementedError" in text
+            assert "unaligned sublane slice" in text
+            assert "breaker=latched_open" in text
+            assert errors[0].exc_info is not None     # traceback kept
+        finally:
+            crypto_batch.set_backend("cpu")
+            crypto_batch.reset_tpu_breaker()
+
     def test_transient_fault_reprobes_after_cooldown(self, monkeypatch):
         from cometbft_tpu.crypto import batch as crypto_batch
         from cometbft_tpu.crypto import ed25519
         from cometbft_tpu.ops import ed25519_jax as ej
 
+        _pretend_tpu(monkeypatch)
         attempts = []
 
         def flaky_verify(items):
             attempts.append(1)
             if len(attempts) == 1:
-                raise ConnectionError("tpu pool connection reset")
+                raise ConnectionError("device connection reset")
             return True, [True] * len(items)
 
         monkeypatch.setattr(ej, "verify_batch", flaky_verify)

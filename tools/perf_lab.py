@@ -746,10 +746,10 @@ def bench_bftlint_selfcheck(fast: bool):
 
 def _pipeline_workload(n: int = 10000):
     """n (pub, msg, sig) triples with DISTINCT keys — the shape of a
-    10k-validator commit burst (tpu_probe's disk-cached workload, so
-    the ~90 s keygen is paid once per checkout, not per run)."""
-    from cometbft_tpu.tools import tpu_probe
-    return tpu_probe.load_or_make_workload(n)
+    10k-validator commit burst (the seeded generator chip_smoke.py
+    and bench.py use)."""
+    from cometbft_tpu.tools import benchmarks
+    return benchmarks.seeded_sig_items(n, seed=0)
 
 
 def _cpu_bv(items, monolithic: bool):
