@@ -1,0 +1,4 @@
+"""`fallback_share` in a live-net cell, where it should move the tx commit
+latencies: the same reading, under a name of its own because a
+per-layer metric names one end-to-end metric."""
+from benchmark.layers.fallback_share import read  # noqa: F401

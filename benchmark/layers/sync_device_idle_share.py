@@ -1,0 +1,4 @@
+"""`device_idle_share` in a catch-up cell, where it should move
+sync_heights_per_s: the same reading, under a name of its own because
+a per-layer metric names one end-to-end metric."""
+from benchmark.layers.device_idle_share import read  # noqa: F401

@@ -1,0 +1,4 @@
+"""`pad_share` in a catch-up cell, where it should move
+sync_heights_per_s: the same reading, under a name of its own because
+a per-layer metric names one end-to-end metric."""
+from benchmark.layers.pad_share import read  # noqa: F401
