@@ -159,7 +159,9 @@ class TracingClient:
 def apply_tracing(app_conns) -> None:
     """Wrap the four named connections with flight-recorder spans
     (all transports — a builtin app's FinalizeBlock time is exactly
-    what the per-height breakdown needs to attribute)."""
+    what the per-height breakdown needs to attribute).  Every
+    AppConns class calls this on itself when built, so the spans do
+    not depend on who builds the conns."""
     for conn in ("consensus", "mempool", "query", "snapshot"):
         inner = getattr(app_conns, conn, None)
         if inner is not None and not isinstance(inner, TracingClient):
@@ -318,6 +320,7 @@ class AppConns:
             self.mempool = UnsyncLocalClient(app)
             self.query = UnsyncLocalClient(app)
             self.snapshot = UnsyncLocalClient(app)
+        apply_tracing(self)
 
 
 class ClientCreator:
@@ -538,6 +541,7 @@ class SocketAppConns:
         self.mempool = SocketClient(address)
         self.query = SocketClient(address)
         self.snapshot = SocketClient(address)
+        apply_tracing(self)
 
     async def start(self) -> None:
         for c in (self.consensus, self.mempool, self.query, self.snapshot):

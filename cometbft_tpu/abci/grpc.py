@@ -18,6 +18,7 @@ from ..libs.log import Logger, new_logger
 from ..wire import abci_pb, decode, encode
 from . import pb as codec
 from . import types as abci
+from .client import apply_tracing
 
 SERVICE = "cometbft.abci.v2.ABCIService"
 
@@ -274,6 +275,7 @@ class GRPCAppConns:
         self.query = cli
         self.snapshot = cli
         self._cli = cli
+        apply_tracing(self)
 
     async def start(self) -> None:
         await self._cli.connect()

@@ -68,14 +68,17 @@ class BlockPool:
     def _wakeup(self) -> None:
         self._wake.set()
 
-    async def wait_apply(self, timeout: float = 0.25) -> None:
+    async def wait_apply(self, timeout: float = 0.25) -> bool:
         """Park the apply loop until a block lands or the pool head
-        advances (fallback tick covers the caught-up transition)."""
+        advances (fallback tick covers the caught-up transition).
+        True when it had to park: nothing was waiting to be applied."""
+        parked = not self._apply_wake.is_set()
         try:
             await asyncio.wait_for(self._apply_wake.wait(), timeout)
         except asyncio.TimeoutError:
             pass
         self._apply_wake.clear()
+        return parked
 
     # ------------------------------------------------------------------
     def start(self) -> None:

@@ -10,6 +10,7 @@ from __future__ import annotations
 import asyncio
 from typing import Callable, Optional
 
+from ..libs import tracing
 from ..libs.log import Logger, new_logger
 from ..p2p.conn import ChannelDescriptor
 from ..p2p.switch import Peer, Reactor
@@ -123,6 +124,7 @@ class BlocksyncReactor(Reactor):
 
     async def receive(self, chan_id: int, peer: Peer,
                       msg_bytes: bytes) -> None:
+        t0 = tracing.now_ns()
         d = decode(MESSAGE, msg_bytes)
         if "block_request" in d:
             await self._respond_to_block_request(
@@ -144,22 +146,26 @@ class BlocksyncReactor(Reactor):
             ec = ExtendedCommit.from_proto(br["ext_commit"]) \
                 if br.get("ext_commit") is not None else None
             self.pool.add_block(peer.id, block, ec, len(msg_bytes))
+            tracing.record_span(tracing.BLOCKSYNC, "block_decode", t0,
+                                height=block.header.height)
         elif "no_block_response" in d:
             pass   # peer doesn't have it; timeouts handle reassignment
 
     async def _respond_to_block_request(self, peer: Peer,
                                         height: int) -> None:
-        block = self.block_store.load_block(height)
-        if block is None:
-            peer.send(BLOCKSYNC_CHANNEL, encode(MESSAGE, {
-                "no_block_response": {"height": height}}))
-            return
-        resp: dict = {"block": block.to_proto()}
-        ec = self.block_store.load_block_ext_commit(height)
-        if ec is not None:
-            resp["ext_commit"] = ec.to_proto()
-        peer.send(BLOCKSYNC_CHANNEL,
-                  encode(MESSAGE, {"block_response": resp}))
+        with tracing.span(tracing.BLOCKSYNC, "block_serve",
+                          height=height):
+            block = self.block_store.load_block(height)
+            if block is None:
+                peer.send(BLOCKSYNC_CHANNEL, encode(MESSAGE, {
+                    "no_block_response": {"height": height}}))
+                return
+            resp: dict = {"block": block.to_proto()}
+            ec = self.block_store.load_block_ext_commit(height)
+            if ec is not None:
+                resp["ext_commit"] = ec.to_proto()
+            peer.send(BLOCKSYNC_CHANNEL,
+                      encode(MESSAGE, {"block_response": resp}))
 
     # ------------------------------------------------------------------
     def _send_block_request(self, peer_id: str, height: int) -> bool:
@@ -202,7 +208,9 @@ class BlocksyncReactor(Reactor):
                     return
                 # park until a block arrives / the head advances; the
                 # 250ms fallback drives the caught-up grace check
-                await pool.wait_apply()
+                t_wait = tracing.now_ns()
+                parked = await pool.wait_apply()
+                t_woke = tracing.now_ns()
                 # caught up?  Require it to HOLD across more than one
                 # status-broadcast round so a single early low-height
                 # StatusResponse can't end the sync prematurely
@@ -222,75 +230,87 @@ class BlocksyncReactor(Reactor):
                     caught_up_since = 0.0
 
                 first, second, first_ext = pool.peek_two_blocks()
-                if first is None or second is None:
+                ready = first is not None and second is not None
+                if parked or not ready:
+                    # the loop had nothing to apply
+                    tracing.record_span(
+                        tracing.BLOCKSYNC, "sync_wait", t_wait, t_woke)
+                if not ready:
                     continue
-                first_parts = first.make_part_set()
-                first_id = BlockID(hash=first.hash(),
-                                   part_set_header=first_parts.header())
-                try:
-                    # the second block's LastCommit certifies the first
-                    if second.last_commit is None:
-                        raise VerificationError("missing last commit")
-                    verify_commit_light(
-                        self.state.chain_id, self.state.validators,
-                        first_id, first.header.height,
-                        second.last_commit)
-                    # the commit only certifies the header hash; validate
-                    # the full block (data/evidence hashes, header wiring)
-                    # before persisting/executing it — reference:
-                    # internal/blocksync/reactor.go:552 ValidateBlock
-                    self.block_exec.validate_block(self.state, first)
-                except (VerificationError, BlockValidationError) as e:
-                    self.logger.error("invalid block in sync",
-                                      height=first.header.height,
-                                      err=str(e))
-                    pool.redo_request(first.header.height, str(e))
-                    pool.redo_request(first.header.height + 1, str(e))
-                    continue
-
-                seen_commit = second.last_commit
-                ext_enabled = self.state.consensus_params.feature \
-                    .vote_extensions_enabled(first.header.height)
-                if ext_enabled:
-                    if first_ext is None:
-                        self.logger.error(
-                            "peer sent block without extended commit "
-                            "while extensions are enabled",
-                            height=first.header.height)
-                        pool.redo_request(first.header.height,
-                                          "missing extended commit")
-                        continue
-                    try:
-                        # reference reactor.go:565 — never persist an
-                        # extended commit missing extension signatures
-                        first_ext.ensure_extensions(True)
-                    except Exception as e:
-                        self.logger.error(
-                            "peer sent extended commit with missing "
-                            "extension signatures",
-                            height=first.header.height, err=str(e))
-                        pool.redo_request(first.header.height, str(e))
-                        continue
-                    self.block_store.save_block_with_extended_commit(
-                        first, first_parts, first_ext)
-                else:
-                    self.block_store.save_block(first, first_parts,
-                                                seen_commit)
-                self.state = await self.block_exec.apply_verified_block(
-                    self.state, first_id, first,
-                    pool.max_peer_height())
-                self.metrics.latest_block_height.set(
-                    first.header.height)
-                self.metrics.num_txs.set(len(first.data.txs))
-                self.metrics.total_txs.add(len(first.data.txs))
-                self.metrics.block_size_bytes.set(
-                    first_parts.byte_size)
-                pool.pop_request()
+                with tracing.span(tracing.BLOCKSYNC, "sync_height",
+                                  height=first.header.height) as sp:
+                    applied = await self._sync_height(
+                        pool, first, second, first_ext)
+                    sp.note(outcome="applied" if applied
+                            else "refused")
         except asyncio.CancelledError:
             raise
         except Exception as e:
             self.logger.error("sync routine failed", err=str(e))
             raise
+
+    async def _sync_height(self, pool, first, second,
+                           first_ext) -> bool:
+        """Verify, store and apply ``first`` (certified by
+        ``second.last_commit``).  False when it was refused and asked
+        for again."""
+        height = first.header.height
+        with tracing.span(tracing.BLOCKSYNC, "part_set"):
+            first_parts = first.make_part_set()
+            first_id = BlockID(hash=first.hash(),
+                               part_set_header=first_parts.header())
+        try:
+            # the second block's LastCommit certifies the first
+            if second.last_commit is None:
+                raise VerificationError("missing last commit")
+            verify_commit_light(
+                self.state.chain_id, self.state.validators,
+                first_id, height, second.last_commit)
+            # the commit only certifies the header hash; validate
+            # the full block (data/evidence hashes, header wiring)
+            # before persisting/executing it — reference:
+            # internal/blocksync/reactor.go:552 ValidateBlock
+            self.block_exec.validate_block(self.state, first)
+        except (VerificationError, BlockValidationError) as e:
+            self.logger.error("invalid block in sync", height=height,
+                              err=str(e))
+            pool.redo_request(height, str(e))
+            pool.redo_request(height + 1, str(e))
+            return False
+
+        seen_commit = second.last_commit
+        ext_enabled = self.state.consensus_params.feature \
+            .vote_extensions_enabled(height)
+        if ext_enabled:
+            if first_ext is None:
+                self.logger.error(
+                    "peer sent block without extended commit "
+                    "while extensions are enabled", height=height)
+                pool.redo_request(height, "missing extended commit")
+                return False
+            try:
+                # reference reactor.go:565 — never persist an
+                # extended commit missing extension signatures
+                first_ext.ensure_extensions(True)
+            except Exception as e:
+                self.logger.error(
+                    "peer sent extended commit with missing "
+                    "extension signatures", height=height, err=str(e))
+                pool.redo_request(height, str(e))
+                return False
+            self.block_store.save_block_with_extended_commit(
+                first, first_parts, first_ext)
+        else:
+            self.block_store.save_block(first, first_parts,
+                                        seen_commit)
+        self.state = await self.block_exec.apply_verified_block(
+            self.state, first_id, first, pool.max_peer_height())
+        self.metrics.latest_block_height.set(height)
+        self.metrics.num_txs.set(len(first.data.txs))
+        self.metrics.total_txs.add(len(first.data.txs))
+        self.metrics.block_size_bytes.set(first_parts.byte_size)
+        pool.pop_request()
+        return True
 
     async def _finish_sync(self, pool) -> None:
         """Hand off to consensus WITHOUT cancelling the task running

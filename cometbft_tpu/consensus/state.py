@@ -1394,9 +1394,7 @@ class ConsensusState:
             raise ConsensusError("proposal parts header != commit header")
         if block.hash() != block_id.hash:
             raise ConsensusError("proposal block != commit hash")
-        with tracing.span(tracing.CONSENSUS, "validate_block",
-                          height=height):
-            self.block_exec.validate_block(self.sm_state, block)
+        self.block_exec.validate_block(self.sm_state, block)
 
         self.logger.info("Finalizing commit of block",
                          height=height,
@@ -1405,31 +1403,29 @@ class ConsensusState:
 
         fail.fail()    # crash point: before block save (state.go:1872)
 
-        with tracing.span(tracing.CONSENSUS, "save_block",
-                          height=height):
-            if self.block_store.height < block.header.height:
-                precommits = rs.votes.precommits(rs.commit_round)
-                seen_ext = precommits.make_extended_commit(
-                    self.sm_state.consensus_params.feature
-                    .vote_extensions_enable_height)
-                if self.sm_state.consensus_params.feature \
-                        .vote_extensions_enabled(block.header.height):
-                    self.block_store.save_block_with_extended_commit(
-                        block, block_parts, seen_ext)
-                else:
-                    seen = seen_ext.to_commit()
-                    # a height decided by an injected/restored
-                    # aggregate (catchup) may hold sub-quorum live
-                    # votes: persist the VERIFIED aggregate instead,
-                    # or restart reconstruction would restore a
-                    # majority-less vote set that cannot re-propose
-                    agg_seen = precommits.stored_aggregate_commit
-                    if agg_seen is not None and \
-                            not precommits \
-                            .has_two_thirds_votes_for_maj23():
-                        seen = agg_seen
-                    self.block_store.save_block(block, block_parts,
-                                                seen)
+        if self.block_store.height < block.header.height:
+            precommits = rs.votes.precommits(rs.commit_round)
+            seen_ext = precommits.make_extended_commit(
+                self.sm_state.consensus_params.feature
+                .vote_extensions_enable_height)
+            if self.sm_state.consensus_params.feature \
+                    .vote_extensions_enabled(block.header.height):
+                self.block_store.save_block_with_extended_commit(
+                    block, block_parts, seen_ext)
+            else:
+                seen = seen_ext.to_commit()
+                # a height decided by an injected/restored
+                # aggregate (catchup) may hold sub-quorum live
+                # votes: persist the VERIFIED aggregate instead,
+                # or restart reconstruction would restore a
+                # majority-less vote set that cannot re-propose
+                agg_seen = precommits.stored_aggregate_commit
+                if agg_seen is not None and \
+                        not precommits \
+                        .has_two_thirds_votes_for_maj23():
+                    seen = agg_seen
+                self.block_store.save_block(block, block_parts,
+                                            seen)
 
         fail.fail()    # crash point: block saved, WAL barrier not yet
                        # written (state.go:1889)
@@ -1459,12 +1455,8 @@ class ConsensusState:
             next_state = provisional_next_state(self.sm_state, bid,
                                                 block)
         else:
-            with tracing.span(tracing.CONSENSUS, "apply_block",
-                              height=height,
-                              num_txs=len(block.data.txs)):
-                state_copy = await self.block_exec \
-                    .apply_verified_block(state_copy, bid, block,
-                                          block.header.height)
+            state_copy = await self.block_exec.apply_verified_block(
+                state_copy, bid, block, block.header.height)
 
             fail.fail()    # crash point: applied, consensus state not
                            # yet advanced (state.go:1933)
@@ -1495,12 +1487,9 @@ class ConsensusState:
 
         async def _apply_task() -> None:
             try:
-                with tracing.span(tracing.CONSENSUS, "apply_block",
-                                  height=height,
-                                  num_txs=len(block.data.txs)):
-                    new_state = await self.block_exec \
-                        .apply_verified_block(state_copy, bid, block,
-                                              block.header.height)
+                new_state = await self.block_exec \
+                    .apply_verified_block(state_copy, bid, block,
+                                          block.header.height)
                 fail.fail()    # crash point: applied, consensus state
                                # not yet advanced (state.go:1933)
                 tracing.instant(tracing.CONSENSUS, "commit",

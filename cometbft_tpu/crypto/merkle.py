@@ -294,9 +294,19 @@ def root_from_leaf_hashes(hashes: Sequence[bytes]) -> bytes:
     The statetree caches kv leaf hashes across commits and recomputes
     only the changed ones, so the root builder must accept hashes
     directly rather than re-hash every item per block."""
+    return root_and_cost_from_leaf_hashes(hashes)[0]
+
+
+def root_and_cost_from_leaf_hashes(
+        hashes: Sequence[bytes]) -> tuple[bytes, int]:
+    """root_from_leaf_hashes and the number of inner hashes it
+    computed: this builder rebuilds every inner node, one for each
+    leaf but the first.  Whoever changes how the root is built changes
+    the count here, beside it (tests/test_statetree.py holds the count
+    to the digests really taken)."""
     if not hashes:
-        return empty_hash()
-    return _root_from_leaf_hashes(hashes)
+        return empty_hash(), 0
+    return _root_from_leaf_hashes(hashes), len(hashes) - 1
 
 
 def multiproof_from_byte_slices(

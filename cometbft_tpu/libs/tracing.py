@@ -25,10 +25,19 @@ guard in tests/test_tracing.py holds the disabled path under 1µs per
 call.  Category enables and the ring size come from
 ``instrumentation.trace_*`` (config.py), wired by the node.
 
-Events are tuples ``(ts_ns, dur_ns, name, height, attrs)`` on a
-``deque(maxlen=size)`` per category; ``time.monotonic_ns()`` is the
-only clock, so timelines are immune to wall-clock steps and strictly
-ordered within a process.
+Events are tuples ``(ts_ns, dur_ns, name, height, attrs, id, parent,
+tid)`` on a ``deque(maxlen=size)`` per category;
+``time.monotonic_ns()`` is the only clock, so timelines are immune to
+wall-clock steps and strictly ordered within a process.
+
+Causality: every event has a process-unique ``id``; a span opened
+while another is open in the same context (a ``contextvars``
+variable, so it follows ``await`` and ``asyncio.to_thread``) records
+that span's id as its ``parent`` and, when it names no height,
+inherits the parent's — the height is the request identifier.  A
+would-be parent that has already closed (a long-lived task created
+inside a span) is no parent.  ``tid`` is the recording thread.  Self
+time of a span is its duration minus what its children cover.
 
 Clock anchors: monotonic timestamps are process-local, so two nodes'
 timelines cannot be compared directly.  The recorder keeps a bounded
@@ -44,6 +53,8 @@ addrbook save/load conversion.
 """
 from __future__ import annotations
 
+import contextvars
+import itertools
 import json
 import os
 import threading
@@ -58,13 +69,31 @@ CRYPTO = "crypto"
 P2P = "p2p"
 MEMPOOL = "mempool"
 ABCI = "abci"
+BLOCKSYNC = "blocksync"
+STATE = "state"
 SUPERVISOR = "supervisor"
 NEMESIS = "nemesis"
 
-CATEGORIES = (CONSENSUS, CRYPTO, P2P, MEMPOOL, ABCI, SUPERVISOR,
-              NEMESIS)
+CATEGORIES = (CONSENSUS, CRYPTO, P2P, MEMPOOL, ABCI, BLOCKSYNC, STATE,
+              SUPERVISOR, NEMESIS)
 
 now_ns = time.monotonic_ns
+_get_ident = threading.get_ident
+
+# process-unique event ids (next() on a count is atomic under the GIL)
+_IDS = itertools.count(1)
+# the innermost span open in this context (task or thread)
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "cometbft_tpu_span", default=None)
+
+
+def _causal(height: int) -> tuple[int, int]:
+    """(parent id, height) for an event recorded now: the open span
+    of this context is its parent and lends its height."""
+    p = _CURRENT.get()
+    if p is None or p.closed:
+        return 0, height
+    return p.id, height or p.height
 
 
 class Recorder:
@@ -128,21 +157,20 @@ class Recorder:
         return ring
 
     def record(self, category: str, name: str, start_ns: int,
-               end_ns: int, height: int,
-               attrs: Optional[dict]) -> None:
+               end_ns: int, height: int, attrs: Optional[dict],
+               span_id: int = 0, parent: int = 0) -> None:
         self._ring(category).append(
             (start_ns, end_ns - start_ns, name,
-             height or self.current_height, attrs))
+             height or self.current_height, attrs,
+             span_id or next(_IDS), parent, _get_ident()))
         if end_ns >= self._next_anchor_ns:
             self.refresh_anchor()
 
     def record_instant(self, category: str, name: str, height: int,
-                       attrs: Optional[dict]) -> None:
+                       attrs: Optional[dict], parent: int = 0) -> None:
         ts = now_ns()
-        self._ring(category).append(
-            (ts, 0, name, height or self.current_height, attrs))
-        if ts >= self._next_anchor_ns:
-            self.refresh_anchor()
+        self.record(category, name, ts, ts, height, attrs,
+                    parent=parent)
 
     def refresh_anchor(self, force: bool = False) -> None:
         """Sample a fresh (monotonic_ns, wall_ns) pair.  Driven
@@ -170,11 +198,12 @@ class Recorder:
         for cat, ring in list(self._rings.items()):
             if category is not None and cat != category:
                 continue
-            for ts, dur, name, h, attrs in list(ring):
+            for ts, dur, name, h, attrs, eid, parent, tid in list(ring):
                 if height is not None and h != height:
                     continue
                 ev = {"ts_ns": ts, "dur_ns": dur, "category": cat,
-                      "name": name, "height": h}
+                      "name": name, "height": h, "id": eid,
+                      "parent": parent, "tid": tid}
                 if attrs:
                     ev["attrs"] = attrs
                 out.append(ev)
@@ -266,35 +295,83 @@ _NOP = _NopSpan()
 
 
 class _Span:
-    __slots__ = ("_r", "cat", "name", "height", "attrs", "t0")
+    """One span.  ``with`` times it and makes it the parent of what
+    opens inside; ``begin``/``end`` time it without binding the
+    context, for an interval that is not one lexical block (a
+    pipelined tile: :func:`under` then names it the parent of each
+    piece).  ``_r`` is None for a :func:`timed` span whose category
+    is off: it reads the clock for its caller and records nothing."""
+    __slots__ = ("_r", "cat", "name", "height", "attrs", "t0", "t1",
+                 "id", "parent", "closed", "_token")
 
-    def __init__(self, r: Recorder, cat: str, name: str, height: int,
-                 attrs: Optional[dict]):
+    def __init__(self, r: Optional[Recorder], cat: str, name: str,
+                 height: int, attrs: Optional[dict]):
         self._r = r
         self.cat = cat
         self.name = name
         self.height = height
         self.attrs = attrs
-        self.t0 = 0
+        self.t0 = self.t1 = 0
+        self.id = self.parent = 0
+        self.closed = False
+        self._token = None
 
-    def __enter__(self):
+    def begin(self):
+        if self._r is not None:
+            self.parent, self.height = _causal(self.height)
+            self.id = next(_IDS)
         self.t0 = now_ns()
         return self
 
+    def end(self) -> None:
+        self.t1 = now_ns()
+        self.closed = True
+        if self._r is not None:
+            self._r.record(self.cat, self.name, self.t0, self.t1,
+                           self.height, self.attrs, self.id,
+                           self.parent)
+
+    def __enter__(self):
+        self.begin()
+        if self._r is not None:
+            self._token = _CURRENT.set(self)
+        return self
+
     def __exit__(self, exc_type, exc, tb):
-        if exc_type is not None:
-            a = self.attrs or {}
-            a["error"] = exc_type.__name__
-            self.attrs = a
-        self._r.record(self.cat, self.name, self.t0, now_ns(),
-                       self.height, self.attrs)
+        if self._r is not None:
+            _CURRENT.reset(self._token)
+            if exc_type is not None:
+                self.note(error=exc_type.__name__)
+        self.end()
         return False
+
+    @property
+    def seconds(self) -> float:
+        """The span's own clock readings, for a metric observed at
+        the same boundary."""
+        return (self.t1 - self.t0) / 1e9
 
     def note(self, **attrs) -> None:
         """Attach attributes discovered mid-span."""
         if self.attrs is None:
             self.attrs = {}
         self.attrs.update(attrs)
+
+
+class _Under:
+    """Make an open span the context's parent for a block."""
+    __slots__ = ("_sp", "_token")
+
+    def __init__(self, sp: _Span):
+        self._sp = sp
+
+    def __enter__(self):
+        self._token = _CURRENT.set(self._sp)
+        return self._sp
+
+    def __exit__(self, *exc):
+        _CURRENT.reset(self._token)
+        return False
 
 
 # ---------------------------------------------------------------------
@@ -310,6 +387,27 @@ def span(category: str, name: str, height: int = 0, **attrs):
     return _Span(r, category, name, height, attrs or None)
 
 
+def timed(category: str, name: str, height: int = 0, **attrs) -> _Span:
+    """A span whose clock readings its caller also uses (``.seconds``
+    after it closed): one pair of readings feeds the span and the
+    metric observed at the same boundary.  Always reads the clock;
+    records only when the category is on."""
+    r = _R
+    on = r.enabled and (r.categories is None or
+                        category in r.categories)
+    return _Span(r if on else None, category, name, height,
+                 attrs or None)
+
+
+def under(sp):
+    """Context manager: inside it ``sp`` (begun, not yet ended) is
+    the parent of whatever opens.  Inert for a span that records
+    nothing."""
+    if getattr(sp, "_r", None) is None:
+        return _NOP
+    return _Under(sp)
+
+
 def instant(category: str, name: str, height: int = 0,
             **attrs) -> None:
     """Record a zero-duration point event."""
@@ -317,7 +415,8 @@ def instant(category: str, name: str, height: int = 0,
     if not r.enabled or (r.categories is not None and
                          category not in r.categories):
         return
-    r.record_instant(category, name, height, attrs or None)
+    parent, height = _causal(height)
+    r.record_instant(category, name, height, attrs or None, parent)
 
 
 def record_span(category: str, name: str, start_ns: int,
@@ -325,14 +424,15 @@ def record_span(category: str, name: str, start_ns: int,
                 **attrs) -> None:
     """Record a span whose start was captured by the caller (e.g. the
     consensus step tracker, which learns a step ended only when the
-    next one begins)."""
+    next one begins).  Its parent is the span open around the call."""
     r = _R
     if not r.enabled or (r.categories is not None and
                          category not in r.categories):
         return
+    parent, height = _causal(height)
     r.record(category, name, start_ns,
              end_ns if end_ns is not None else now_ns(), height,
-             attrs or None)
+             attrs or None, parent=parent)
 
 
 def set_height(height: int) -> None:

@@ -283,11 +283,6 @@ class Node:
                 default_timeout_s=cfg.base.abci_call_timeout_ns / 1e9,
                 retries=cfg.base.abci_call_retries)
 
-        # flight-recorder span per ABCI call: the execute slice of the
-        # per-height timeline (/trace, tools/trace_report.py)
-        from ..abci.client import apply_tracing
-        apply_tracing(self.app_conns)
-
         # per-method ABCI timing (reference: proxy metrics)
         from ..abci.metrics import instrument_app_conns
         instrument_app_conns(self.app_conns, self.proxy_metrics)

@@ -405,12 +405,15 @@ def verify_batch(
 def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
     """Tiled, overlapped dispatch: host_prep of tile i+1 runs while
     tile i's kernel executes (JAX async dispatch — the jitted call
-    returns a device future; np.asarray at settle time blocks).
+    returns a device future; _force at settle time blocks).
     Multi-chip meshes pre-partition ONCE per process
     (parallel/mesh.pipeline_partitioner) so per-tile dispatch pays no
-    mesh/sharding re-resolution."""
-    import time as _time
+    mesh/sharding re-resolution.
 
+    A tile's kernel_execute span runs from the instant before its
+    _launch to the end of its settle (h2d and launch at its start,
+    device_wait and d2h at its end); the next tile's host_prep is a
+    sibling that overlaps it — the overlap the pipeline exists for."""
     from ..crypto.pipeline import overlap_histogram, tile_plan
 
     n = len(items)
@@ -419,23 +422,21 @@ def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
     hist = _dispatch_histogram()
     out = np.zeros(n, bool)
     plan = tile_plan(n, tile)
-    t_run0 = _time.perf_counter()
+    t_run0 = tracing.now_ns()
     phase_s = 0.0
-    inflight = None         # (lo, hi, m, warm, pre_bad, dev, t_disp)
+    inflight = None         # (lo, hi, m, warm, pre_bad, dev, span)
 
     def settle(inflight, prep_inside: float):
-        lo, hi, m, warm, pre_bad, dev, t_disp = inflight
-        pad_bucket = str(m)
-        with tracing.span(tracing.CRYPTO, "kernel_execute",
-                          batch=hi - lo, bucket=m, kernel=choice,
-                          warm=warm, pipelined=True) as sp:
+        lo, hi, m, warm, pre_bad, dev, sp = inflight
+        with tracing.under(sp):
             ok = _force(dev, sp)
-        t1 = _time.perf_counter()
+        sp.end()
         # dispatch -> settled: the window the device (or the XLA
         # runtime thread) owned the tile, i.e. what host_prep of the
         # NEXT tile overlapped with
+        pad_bucket = str(m)
         hist.with_labels("kernel_execute", choice, pad_bucket,
-                         "1" if warm else "0").observe(t1 - t_disp)
+                         "1" if warm else "0").observe(sp.seconds)
         ok = ok[:hi - lo].copy()
         ok[pre_bad[:hi - lo]] = False
         out[lo:hi] = ok
@@ -445,30 +446,31 @@ def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
         # a pipeline whose device did nothing until the force would
         # still read ~2.0 "overlap"; what remains above the contained
         # prep is execution the async dispatch genuinely hid
-        return max(0.0, (t1 - t_disp) - prep_inside)
+        return max(0.0, sp.seconds - prep_inside)
 
-    for lo, hi in plan:
+    for i, (lo, hi) in enumerate(plan):
         chunk = items[lo:hi]
         m = _padded(hi - lo, choice)
         warm = (choice, m) in _SEEN_SHAPES
-        pad_bucket = str(m)
-        t0 = _time.perf_counter()
-        with tracing.span(tracing.CRYPTO, "host_prep", batch=hi - lo,
-                          bucket=m, pipelined=True):
+        with tracing.timed(tracing.CRYPTO, "host_prep", batch=hi - lo,
+                           bucket=m, pipelined=True) as prep:
             a_b, r_b, s_w8, k_w8, pre_bad = prep_arrays(chunk, m)
-        t1 = _time.perf_counter()
+        pad_bucket = str(m)
         hist.with_labels("host_prep", choice, pad_bucket,
-                         "1" if warm else "0").observe(t1 - t0)
-        phase_s += t1 - t0
-        dev = _launch(a_b, r_b, s_w8, k_w8, choice=choice,
-                      part=_partitioner(m, choice), donate=donate)
-        t_disp = _time.perf_counter()
+                         "1" if warm else "0").observe(prep.seconds)
+        phase_s += prep.seconds
+        sp = tracing.timed(tracing.CRYPTO, "kernel_execute",
+                           batch=hi - lo, bucket=m, kernel=choice,
+                           warm=warm, pipelined=True, tile=i).begin()
+        with tracing.under(sp):
+            dev = _launch(a_b, r_b, s_w8, k_w8, choice=choice,
+                          part=_partitioner(m, choice), donate=donate)
         _SEEN_SHAPES.add((choice, m))
         if inflight is not None:
-            phase_s += settle(inflight, prep_inside=t1 - t0)
-        inflight = (lo, hi, m, warm, pre_bad, dev, t_disp)
+            phase_s += settle(inflight, prep_inside=prep.seconds)
+        inflight = (lo, hi, m, warm, pre_bad, dev, sp)
     phase_s += settle(inflight, prep_inside=0.0)
-    wall = _time.perf_counter() - t_run0
+    wall = (tracing.now_ns() - t_run0) / 1e9
     if wall > 0:
         overlap_histogram().observe(phase_s / wall)
     return bool(out.all()), out.tolist()
@@ -491,24 +493,39 @@ def _launch(a_b, r_b, s_w8, k_w8, *, choice: str,
     same bucket."""
     if part is not None:
         return part.dispatch(a_b, r_b, s_w8, k_w8)
-    da = jax.device_put(a_b)
-    dr = jax.device_put(r_b)
-    ds = jax.device_put(s_w8)
-    dk = jax.device_put(k_w8)
-    if choice.startswith("pallas"):
-        return _pallas_verify_packed(da, dr, ds, dk, kernel=choice,
-                                     interpret=interpret, block=block)
-    if donate:
-        return _jit_verify_packed_donated(da, dr, ds, dk)
-    return _jit_verify_packed(da, dr, ds, dk)
+    with tracing.span(tracing.CRYPTO, "h2d"):
+        da = jax.device_put(a_b)
+        dr = jax.device_put(r_b)
+        ds = jax.device_put(s_w8)
+        dk = jax.device_put(k_w8)
+    with tracing.span(tracing.CRYPTO, "launch"):
+        if choice.startswith("pallas"):
+            return _pallas_verify_packed(
+                da, dr, ds, dk, kernel=choice, interpret=interpret,
+                block=block)
+        if donate:
+            return _jit_verify_packed_donated(da, dr, ds, dk)
+        return _jit_verify_packed(da, dr, ds, dk)
 
 
 def _force(dev, sp=None) -> np.ndarray:
     """Block until the kernel finishes and bring the mask to the host,
     recording on the kernel_execute span where it was computed — the
     platform and device count of the output array itself, not of a
-    label chosen before the dispatch."""
-    ok = np.asarray(dev)
+    label chosen before the dispatch.
+
+    With the recorder on, the wait and the read-back are two spans;
+    the copy is queued behind the kernel before the wait, as the bare
+    np.asarray of the untraced path does (waiting first and only then
+    asking for it cost 0.12 ms a dispatch on a v5e; PERF.md, PR 24)."""
+    if tracing.enabled(tracing.CRYPTO):
+        with tracing.span(tracing.CRYPTO, "device_wait"):
+            dev.copy_to_host_async()
+            dev.block_until_ready()
+        with tracing.span(tracing.CRYPTO, "d2h"):
+            ok = np.asarray(dev)
+    else:
+        ok = np.asarray(dev)
     if sp is not None:
         devs = dev.devices()
         sp.note(platform=next(iter(devs)).platform, devices=len(devs))
@@ -552,31 +569,30 @@ def _verify_chunk(items) -> np.ndarray:
     n = len(items)
     choice = _kernel_choice()
     m = _padded(n, choice)
-    import time as _time
     warm = (choice, m) in _SEEN_SHAPES
     hist = _dispatch_histogram()
-    t0 = _time.perf_counter()
-    with tracing.span(tracing.CRYPTO, "host_prep", batch=n,
-                      bucket=m):
+    # each phase's one pair of clock readings feeds its span and
+    # crypto_kernel_dispatch_seconds
+    with tracing.timed(tracing.CRYPTO, "host_prep", batch=n,
+                       bucket=m) as prep:
         a_b, r_b, s_win, k_win, pre_bad = prep_arrays(items, m)
-    t1 = _time.perf_counter()
     # compile-vs-execute attribution: the first dispatch of a
     # (kernel, bucket) shape includes trace+compile (unless warmup()
     # or the persistent cache served it); warm dispatches are pure
     # execution
-    with tracing.span(tracing.CRYPTO, "kernel_execute", batch=n,
-                      bucket=m, kernel=choice, warm=warm) as sp:
+    with tracing.timed(tracing.CRYPTO, "kernel_execute", batch=n,
+                       bucket=m, kernel=choice, warm=warm) as sp:
         out = _dispatch(n, a_b, r_b, s_win, k_win, pre_bad, sp=sp)
-    t2 = _time.perf_counter()
-    w = "1" if warm else "0"
-    hist.with_labels("host_prep", choice, str(m), w).observe(t1 - t0)
-    hist.with_labels("kernel_execute", choice, str(m),
-                     w).observe(t2 - t1)
+    pad_bucket = str(m)
+    hist.with_labels("host_prep", choice, pad_bucket,
+                     "1" if warm else "0").observe(prep.seconds)
+    hist.with_labels("kernel_execute", choice, pad_bucket,
+                     "1" if warm else "0").observe(sp.seconds)
     if warm:
         # only warm dispatches steer bucket refinement — a cold one
         # includes trace+compile, which is exactly the cost refinement
         # must NOT mistake for per-lane kernel work
-        _tune_record(n, m, t1 - t0, t2 - t1)
+        _tune_record(n, m, prep.seconds, sp.seconds)
     _SEEN_SHAPES.add((choice, m))
     return out
 

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..libs import tracing
 from ..ops import device
 from ..ops.ed25519_jax import _verify_kernel
 
@@ -123,11 +124,13 @@ class PipelinePartitioner:
                 [k_w8, np.zeros((pad, 64), k_w8.dtype)])
         # async sharded transfers into the pre-resolved sharding —
         # the jitted call below sees correctly-partitioned inputs
-        da = jax.device_put(a_b, self.sharding)
-        dr = jax.device_put(r_b, self.sharding)
-        ds = jax.device_put(s_w8, self.sharding)
-        dk = jax.device_put(k_w8, self.sharding)
-        return self.fn(da, dr, ds, dk)
+        with tracing.span(tracing.CRYPTO, "h2d"):
+            da = jax.device_put(a_b, self.sharding)
+            dr = jax.device_put(r_b, self.sharding)
+            ds = jax.device_put(s_w8, self.sharding)
+            dk = jax.device_put(k_w8, self.sharding)
+        with tracing.span(tracing.CRYPTO, "launch"):
+            return self.fn(da, dr, ds, dk)
 
 
 @functools.lru_cache(maxsize=None)

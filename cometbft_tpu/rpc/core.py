@@ -641,8 +641,10 @@ async def _trace(env, height, category, limit):
     """Flight-recorder timeline (libs/tracing.py): spans + instant
     events from the per-category ring buffers, strictly ordered by
     monotonic timestamp.  ?height=H keeps one height's events,
-    ?category=consensus|crypto|p2p|mempool|abci keeps one ring,
-    ?limit=N keeps the newest N."""
+    ?category=consensus|crypto|p2p|mempool|abci|blocksync|state keeps
+    one ring, ?limit=N keeps the newest N.  Every event names itself
+    (``id``), the span open around it (``parent``, "0" for none) and
+    its thread (``tid``)."""
     from ..libs import tracing
     try:
         h = int(height or 0)
@@ -669,7 +671,9 @@ async def _trace(env, height, category, limit):
         # int64s ride as strings, the surface-wide convention
         "events": [{**e, "ts_ns": str(e["ts_ns"]),
                     "dur_ns": str(e["dur_ns"]),
-                    "height": str(e["height"])} for e in events],
+                    "height": str(e["height"]), "id": str(e["id"]),
+                    "parent": str(e["parent"]),
+                    "tid": str(e["tid"])} for e in events],
     }
 
 

@@ -11,7 +11,7 @@ from typing import Optional
 
 from ..abci import types as abci
 from ..crypto import encoding as crypto_encoding, merkle
-from ..libs import fail
+from ..libs import fail, tracing
 from ..libs.log import Logger, new_logger
 from ..types.block import Block
 from ..types.block_id import BlockID
@@ -322,15 +322,18 @@ class BlockExecutor:
     # ------------------------------------------------------------------
     def validate_block(self, state: State, block: Block) -> None:
         """Reference: execution.go ValidateBlock."""
-        if self._last_validated_hash != block.hash():
-            validate_block(state, block)
-            self._last_validated_hash = block.hash()
-        try:
-            self.evpool.check_evidence(block.evidence)
-        except BlockValidationError:
-            raise
-        except Exception as e:  # EvidenceError -> invalid block
-            raise BlockValidationError(f"invalid evidence: {e}") from e
+        with tracing.span(tracing.STATE, "validate_block",
+                          height=block.header.height):
+            if self._last_validated_hash != block.hash():
+                validate_block(state, block)
+                self._last_validated_hash = block.hash()
+            try:
+                self.evpool.check_evidence(block.evidence)
+            except BlockValidationError:
+                raise
+            except Exception as e:  # EvidenceError -> invalid block
+                raise BlockValidationError(
+                    f"invalid evidence: {e}") from e
 
     async def apply_block(self, state: State, block_id: BlockID,
                           block: Block,
@@ -354,6 +357,18 @@ class BlockExecutor:
     async def _apply_block(self, state: State, block_id: BlockID,
                            block: Block,
                            syncing_to_height: int) -> State:
+        # one pair of clock readings: the apply_block span and the
+        # operator's block_processing_time histogram
+        with tracing.timed(tracing.STATE, "apply_block",
+                           height=block.header.height) as sp:
+            state = await self._apply_block_steps(
+                state, block_id, block, syncing_to_height)
+        self.metrics.block_processing_time.observe(sp.seconds * 1e3)
+        return state
+
+    async def _apply_block_steps(self, state: State, block_id: BlockID,
+                                 block: Block,
+                                 syncing_to_height: int) -> State:
         h = block.header
         abci_response = await self.proxy_app.finalize_block(
             abci.FinalizeBlockRequest(
@@ -381,24 +396,29 @@ class BlockExecutor:
                        # (execution.go:267)
 
         # save results BEFORE app commit (crash-consistency barrier)
-        self.store.save_finalize_block_response(h.height, abci_response)
+        with tracing.span(tracing.STATE, "save_finalize_response"):
+            self.store.save_finalize_block_response(h.height,
+                                                    abci_response)
 
         fail.fail()    # crash point: responses saved, state not updated
                        # (execution.go:274)
 
-        validator_updates = validate_validator_updates(
-            abci_response.validator_updates,
-            state.consensus_params.validator)
-        if validator_updates:
-            self.metrics.validator_set_updates.add()
-        if abci_response.consensus_param_updates is not None:
-            self.metrics.consensus_param_updates.add()
+        with tracing.span(tracing.STATE, "update_state"):
+            validator_updates = validate_validator_updates(
+                abci_response.validator_updates,
+                state.consensus_params.validator)
+            if validator_updates:
+                self.metrics.validator_set_updates.add()
+            if abci_response.consensus_param_updates is not None:
+                self.metrics.consensus_param_updates.add()
 
-        state = update_state(state, block_id, block, abci_response,
-                             validator_updates)
+            state = update_state(state, block_id, block, abci_response,
+                                 validator_updates)
 
         # lock mempool, app Commit, update mempool
-        retain_height = await self.commit(state, block, abci_response)
+        with tracing.span(tracing.STATE, "app_commit"):
+            retain_height = await self.commit(state, block,
+                                              abci_response)
 
         self.evpool.update(state, block.evidence)
 
@@ -406,7 +426,8 @@ class BlockExecutor:
                        # (execution.go:315)
 
         state.app_hash = abci_response.app_hash
-        self.store.save(state)
+        with tracing.span(tracing.STATE, "state_save"):
+            self.store.save(state)
 
         # app-requested pruning: hand the retain height to the pruner
         # service (reference: execution.go pruneBlocks -> state/pruner.go)
@@ -414,8 +435,9 @@ class BlockExecutor:
         if self.pruner is not None and retain_height > 0:
             self.pruner.set_application_retain_height(retain_height)
 
-        self._fire_events(block, block_id, abci_response,
-                          validator_updates)
+        with tracing.span(tracing.STATE, "fire_events"):
+            self._fire_events(block, block_id, abci_response,
+                              validator_updates)
         return state
 
     async def commit(self, state: State, block: Block,

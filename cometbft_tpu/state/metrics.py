@@ -17,6 +17,13 @@ class Metrics:
             "state", "validator_set_updates",
             "Number of validator set updates returned by the "
             "application since process start.")
+        self.block_processing_time = m.histogram(
+            "state", "block_processing_time",
+            "Time spent processing a block in ms: FinalizeBlock, "
+            "saving its response, the state update, the application's "
+            "Commit with the mempool update, the state save and the "
+            "events (the apply_block span's boundary).",
+            buckets=tuple(1.0 + 10.0 * i for i in range(10)))
         self.application_block_retain_height = m.gauge(
             "state", "application_block_retain_height",
             "The retain height set by the application.")

@@ -33,6 +33,7 @@ header_height = version + 1 mapping proof envelopes carry.
 """
 from __future__ import annotations
 
+import bisect
 import json
 import struct
 import threading
@@ -42,6 +43,7 @@ from typing import Iterable, Optional
 from ..crypto import merkle
 from ..crypto._native_loader import batched_hashes
 from ..db.db import DB
+from ..libs import tracing
 from ..wire.proto import decode_uvarint, encode_uvarint
 
 _NODE = b"n/"
@@ -253,33 +255,40 @@ class StateTree:
             if self._pending is not None and \
                     self._pending[0] == version:
                 return self._pending[4]
-            new_map = dict(self._map)
-            new_leafh = dict(self._leafh)
-            new_sorted = list(self._sorted)
-            changed: list[bytes] = []
-            import bisect
-            for k, v in self._working.items():
-                if v is None:
-                    if k in new_map:
-                        del new_map[k]
-                        del new_leafh[k]
-                        i = bisect.bisect_left(new_sorted, k)
-                        new_sorted.pop(i)
-                elif new_map.get(k) != v:
-                    if k not in new_map:
-                        bisect.insort(new_sorted, k)
-                    new_map[k] = v
-                    changed.append(k)
-            if changed:
-                hashes = _leaf_hashes(
-                    [merkle.value_op_leaf(k, new_map[k])
-                     for k in changed])
-                new_leafh.update(zip(changed, hashes))
-            root = merkle.root_from_leaf_hashes(
-                [new_leafh[k] for k in new_sorted])
-            self._pending = (version, new_sorted, new_map,
-                             new_leafh, root)
-            return root
+            with tracing.span(tracing.ABCI, "state_root") as sp:
+                return self._compute_root(version, sp)
+
+    def _compute_root(self, version: int, sp) -> bytes:
+        """working_root's computation (its lock held).  ``sp``, the
+        state_root span, is told the leaf and inner hashes computed,
+        as the functions that computed them count them."""
+        new_map = dict(self._map)
+        new_leafh = dict(self._leafh)
+        new_sorted = list(self._sorted)
+        changed: list[bytes] = []
+        for k, v in self._working.items():
+            if v is None:
+                if k in new_map:
+                    del new_map[k]
+                    del new_leafh[k]
+                    i = bisect.bisect_left(new_sorted, k)
+                    new_sorted.pop(i)
+            elif new_map.get(k) != v:
+                if k not in new_map:
+                    bisect.insort(new_sorted, k)
+                new_map[k] = v
+                changed.append(k)
+        hashes: list[bytes] = []
+        if changed:
+            hashes = _leaf_hashes(
+                [merkle.value_op_leaf(k, new_map[k])
+                 for k in changed])
+            new_leafh.update(zip(changed, hashes))
+        root, inner = merkle.root_and_cost_from_leaf_hashes(
+            [new_leafh[k] for k in new_sorted])
+        sp.note(hashes=len(hashes) + inner)
+        self._pending = (version, new_sorted, new_map, new_leafh, root)
+        return root
 
     def commit(self, version: int,
                app_hash_override: Optional[bytes] = None,

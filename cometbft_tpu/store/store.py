@@ -12,6 +12,7 @@ import threading
 from typing import Optional
 
 from ..db import DB, Batch
+from ..libs import tracing
 from ..types.block import Block, BlockMeta
 from ..types.block_id import BlockID
 from ..types.commit import AggregateCommit, Commit, ExtendedCommit
@@ -138,7 +139,8 @@ class BlockStore:
         if block is None:
             raise BlockStoreError("cannot save nil block")
         height = block.header.height
-        with self._lock:
+        with tracing.span(tracing.STATE, "store_save_block",
+                          height=height), self._lock:
             expected = self._height + 1 if self._height else height
             if height != expected:
                 raise BlockStoreError(

@@ -22,10 +22,10 @@ verdicts identical to the per-signature path.
 from __future__ import annotations
 
 import hashlib
-import time
 from typing import Callable, NamedTuple, Optional
 
 from ..crypto import batch as crypto_batch
+from ..libs import tracing
 from ..libs.bits import BitArray
 from .commit import AggregateCommit, Commit, CommitSig, CommitError
 from .block_id import BlockID
@@ -58,25 +58,30 @@ def commit_verify_histogram():
 
 
 class _observe_kind:
-    """Context manager timing one commit verification into the
-    kind-labeled histogram (failures observe too — a rejected commit
-    still paid the verification cost)."""
+    """Context manager around one commit verification: the
+    ``commit_verify`` span (its ``height`` is the request identifier
+    every span below inherits) and, from the same pair of clock
+    readings, the kind-labeled histogram (failures observe too — a
+    rejected commit still paid the verification cost)."""
 
-    __slots__ = ("kind", "t0")
+    __slots__ = ("kind", "sp")
 
-    def __init__(self, kind: str):
+    def __init__(self, kind: str, height: int):
         self.kind = kind
+        self.sp = tracing.timed(tracing.CONSENSUS, "commit_verify",
+                                height)
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
+        self.sp.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self.sp.__exit__(*exc)
         # bounded: every instantiation site passes one of the four
         # literal kinds {aggregate, batch, grouped, single}
         kind = self.kind
         commit_verify_histogram().with_labels(kind).observe(
-            time.perf_counter() - self.t0)
+            self.sp.seconds)
         return False
 
 
@@ -152,10 +157,29 @@ def _dispatch_aggregate(chain_id: str, vals: ValidatorSet,
     one aggregate signature covers every signer, so "all signatures"
     and "stop at 2/3" coincide."""
     _verify_basic_vals_and_commit(vals, commit, height, block_id)
-    with _observe_kind("aggregate"):
+    with _observe_kind("aggregate", height):
         _verify_aggregate_commit(
             chain_id, vals, commit,
             vals.total_voting_power() * 2 // 3, cache=cache)
+
+
+def _verify_per_signature(height: int, chain_id: str,
+                          vals: ValidatorSet, commit: Commit,
+                          voting_power_needed: int, ignore_sig,
+                          count_sig, count_all_signatures: bool,
+                          look_up_by_index: bool,
+                          cache: Optional[SignatureCache]) -> None:
+    """Pick the per-signature path the set's keys allow and run it
+    inside its commit_verify span / histogram observation."""
+    if _should_batch_verify(vals, commit):
+        kind, verify = "batch", _verify_commit_batch
+    elif _should_group_verify(vals, commit):
+        kind, verify = "grouped", _verify_commit_grouped
+    else:
+        kind, verify = "single", _verify_commit_single
+    with _observe_kind(kind, height):
+        verify(chain_id, vals, commit, voting_power_needed, ignore_sig,
+               count_sig, count_all_signatures, look_up_by_index, cache)
 
 
 def verify_commit(chain_id: str, vals: ValidatorSet, block_id: BlockID,
@@ -170,27 +194,12 @@ def verify_commit(chain_id: str, vals: ValidatorSet, block_id: BlockID,
                             cache)
         return
     _verify_basic_vals_and_commit(vals, commit, height, block_id)
-    voting_power_needed = vals.total_voting_power() * 2 // 3
-    ignore = lambda c: c.block_id_flag == BLOCK_ID_FLAG_ABSENT  # noqa: E731
-    count = lambda c: c.block_id_flag == BLOCK_ID_FLAG_COMMIT  # noqa: E731
-    if _should_batch_verify(vals, commit):
-        with _observe_kind("batch"):
-            _verify_commit_batch(
-                chain_id, vals, commit, voting_power_needed, ignore,
-                count, count_all_signatures=True, look_up_by_index=True,
-                cache=cache)
-    elif _should_group_verify(vals, commit):
-        with _observe_kind("grouped"):
-            _verify_commit_grouped(
-                chain_id, vals, commit, voting_power_needed, ignore,
-                count, count_all_signatures=True, look_up_by_index=True,
-                cache=cache)
-    else:
-        with _observe_kind("single"):
-            _verify_commit_single(
-                chain_id, vals, commit, voting_power_needed, ignore,
-                count, count_all_signatures=True, look_up_by_index=True,
-                cache=cache)
+    _verify_per_signature(
+        height, chain_id, vals, commit,
+        vals.total_voting_power() * 2 // 3,
+        lambda c: c.block_id_flag == BLOCK_ID_FLAG_ABSENT,
+        lambda c: c.block_id_flag == BLOCK_ID_FLAG_COMMIT,
+        count_all_signatures=True, look_up_by_index=True, cache=cache)
 
 
 def verify_commit_light(chain_id: str, vals: ValidatorSet,
@@ -206,27 +215,13 @@ def verify_commit_light(chain_id: str, vals: ValidatorSet,
                             cache)
         return
     _verify_basic_vals_and_commit(vals, commit, height, block_id)
-    voting_power_needed = vals.total_voting_power() * 2 // 3
-    ignore = lambda c: c.block_id_flag != BLOCK_ID_FLAG_COMMIT  # noqa: E731
-    count = lambda c: True  # noqa: E731
-    if _should_batch_verify(vals, commit):
-        with _observe_kind("batch"):
-            _verify_commit_batch(
-                chain_id, vals, commit, voting_power_needed, ignore,
-                count, count_all_signatures=count_all_signatures,
-                look_up_by_index=True, cache=cache)
-    elif _should_group_verify(vals, commit):
-        with _observe_kind("grouped"):
-            _verify_commit_grouped(
-                chain_id, vals, commit, voting_power_needed, ignore,
-                count, count_all_signatures=count_all_signatures,
-                look_up_by_index=True, cache=cache)
-    else:
-        with _observe_kind("single"):
-            _verify_commit_single(
-                chain_id, vals, commit, voting_power_needed, ignore,
-                count, count_all_signatures=count_all_signatures,
-                look_up_by_index=True, cache=cache)
+    _verify_per_signature(
+        height, chain_id, vals, commit,
+        vals.total_voting_power() * 2 // 3,
+        lambda c: c.block_id_flag != BLOCK_ID_FLAG_COMMIT,
+        lambda c: True,
+        count_all_signatures=count_all_signatures,
+        look_up_by_index=True, cache=cache)
 
 
 def verify_commit_light_trusting(
@@ -269,31 +264,17 @@ def verify_commit_light_trusting(
             raise VerificationError(
                 f"invalid commit -- wrong set size: "
                 f"{signer_vals.size()} vs {commit.size()}")
-        with _observe_kind("aggregate"):
+        with _observe_kind("aggregate", commit.height):
             _verify_aggregate_commit(
                 chain_id, signer_vals, commit, voting_power_needed,
                 cache=cache, tally_vals=vals)
         return
-    ignore = lambda c: c.block_id_flag != BLOCK_ID_FLAG_COMMIT  # noqa: E731
-    count = lambda c: True  # noqa: E731
-    if _should_batch_verify(vals, commit):
-        with _observe_kind("batch"):
-            _verify_commit_batch(
-                chain_id, vals, commit, voting_power_needed, ignore,
-                count, count_all_signatures=count_all_signatures,
-                look_up_by_index=False, cache=cache)
-    elif _should_group_verify(vals, commit):
-        with _observe_kind("grouped"):
-            _verify_commit_grouped(
-                chain_id, vals, commit, voting_power_needed, ignore,
-                count, count_all_signatures=count_all_signatures,
-                look_up_by_index=False, cache=cache)
-    else:
-        with _observe_kind("single"):
-            _verify_commit_single(
-                chain_id, vals, commit, voting_power_needed, ignore,
-                count, count_all_signatures=count_all_signatures,
-                look_up_by_index=False, cache=cache)
+    _verify_per_signature(
+        commit.height, chain_id, vals, commit, voting_power_needed,
+        lambda c: c.block_id_flag != BLOCK_ID_FLAG_COMMIT,
+        lambda c: True,
+        count_all_signatures=count_all_signatures,
+        look_up_by_index=False, cache=cache)
 
 
 # ---------------------------------------------------------------------------
@@ -582,10 +563,11 @@ def _verify_commit_batch(
                 f"{commit_sig.signature.hex().upper()}") from e
         entries.append((idx, val.pub_key.address(), sign_bytes))
 
-    tallied = _walk_commit(
-        chain_id, vals, commit, voting_power_needed, ignore_sig,
-        count_sig, count_all_signatures, look_up_by_index, cache,
-        strict=False, handle=handle)
+    with tracing.span(tracing.CONSENSUS, "commit_walk"):
+        tallied = _walk_commit(
+            chain_id, vals, commit, voting_power_needed, ignore_sig,
+            count_sig, count_all_signatures, look_up_by_index, cache,
+            strict=False, handle=handle)
 
     if tallied <= voting_power_needed:
         raise NotEnoughVotingPowerError(tallied, voting_power_needed)
@@ -665,10 +647,11 @@ def _verify_commit_grouped(
                 val.pub_key.address(), sign_bytes))
         return None
 
-    tallied = _walk_commit(
-        chain_id, vals, commit, voting_power_needed, ignore_sig,
-        count_sig, count_all_signatures, look_up_by_index, cache,
-        strict=True, handle=handle)
+    with tracing.span(tracing.CONSENSUS, "commit_walk"):
+        tallied = _walk_commit(
+            chain_id, vals, commit, voting_power_needed, ignore_sig,
+            count_sig, count_all_signatures, look_up_by_index, cache,
+            strict=True, handle=handle)
 
     first_bad: Optional[int] = inline_bad
     for bv, entries in groups.values():

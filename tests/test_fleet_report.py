@@ -198,16 +198,30 @@ class TestTraceAnchorContract:
             spec = json.load(f)
         required = spec["methods"]["trace"]["result_required"]
         assert "anchors" in required and "node" in required
+        event_required = spec["methods"]["trace"]["event_required"]
+        assert {"id", "parent", "tid"} <= set(event_required)
         from cometbft_tpu.rpc import core
         old = tracing.set_recorder(
             tracing.Recorder(node_id="contract-probe"))
         try:
-            tracing.instant(tracing.CONSENSUS, "commit", height=1)
+            with tracing.span(tracing.CONSENSUS, "step:Commit",
+                              height=1):
+                tracing.instant(tracing.CONSENSUS, "commit")
             resp = run(core.routes(None)["trace"]())
         finally:
             tracing.set_recorder(old)
         for field in required:
             assert field in resp, field
+        # every event names itself, its parent and its thread, as
+        # int64 strings; the instant's parent is the span around it
+        outer, inner = resp["events"]
+        for ev in (outer, inner):
+            for field in event_required:
+                assert field in ev, field
+            assert int(ev["id"]) > 0 and int(ev["tid"]) > 0
+        assert outer["parent"] == "0"
+        assert inner["parent"] == outer["id"]
+        assert inner["height"] == "1"
         assert resp["node"] == "contract-probe"
         assert resp["anchors"], "at least the construction anchor"
         for pair in resp["anchors"]:

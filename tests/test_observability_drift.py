@@ -47,7 +47,7 @@ def _emitted(category: str) -> tuple[set, set]:
     package source for tracing calls.  F-string names are truncated
     at the first placeholder (``step:{...}`` -> ``step:``)."""
     call = re.compile(
-        r"tracing\.(instant|span|record_span)\(\s*"
+        r"tracing\.(instant|span|timed|record_span)\(\s*"
         r"tracing\.([A-Z0-9_]+)\s*,\s*[fF]?\"([^\"]+)\"", re.S)
     spans: set = set()
     instants: set = set()
@@ -81,6 +81,20 @@ class TestTraceReportNamePinning:
         for name in tr.CONSENSUS_SPAN_BUCKETS:
             assert name in spans or name.startswith("step:"), (
                 f"trace_report buckets {name!r} but nothing emits it")
+
+    def test_state_spans_all_bucketed(self):
+        """The executor and the block store own validate_block,
+        store_save_block and apply_block (consensus wraps them no
+        more): the report reads them from the state ring."""
+        tr = _load("trace_report")
+        spans, _ = _emitted("STATE")
+        assert spans == set(tr.STATE_SPAN_BUCKETS), (
+            f"emitted-only={sorted(spans - set(tr.STATE_SPAN_BUCKETS))}"
+            f" table-only={sorted(set(tr.STATE_SPAN_BUCKETS) - spans)}")
+        consensus, _ = _emitted("CONSENSUS")
+        assert not consensus & spans, (
+            "one span per boundary: a name the state ring has is "
+            "not opened again by consensus")
 
     def test_consensus_instants_all_marked(self):
         tr = _load("trace_report")

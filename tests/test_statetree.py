@@ -102,6 +102,46 @@ class TestVersionedTree:
         b = _commit_pairs(_tree(), 1, list(reversed(pairs)))
         assert a == b
 
+    @pytest.mark.parametrize("keys,changed", [(1, 1), (7, 2), (40, 5),
+                                              (40, 0)])
+    def test_state_root_counts_the_digests_it_takes(
+            self, monkeypatch, tmp_path, keys, changed):
+        """The state_root span's ``hashes`` is what
+        sync_root_hashes_per_height reads: it has to be the number of
+        digests working_root really took, leaves and inner nodes, so
+        that a root built another way moves it without being told."""
+        from cometbft_tpu.libs import tracing
+        from cometbft_tpu.statetree import tree as tree_mod
+
+        t = _tree()
+        _commit_pairs(t, 1, [(b"k%02d" % i, b"v") for i in range(keys)])
+        for i in range(changed):
+            t.set(b"k%02d" % i, b"w")
+
+        taken = []
+        real_inner, real_leaf = merkle.inner_hash, merkle.leaf_hash
+        monkeypatch.setattr(
+            merkle, "inner_hash",
+            lambda l, r: taken.append("inner") or real_inner(l, r))
+        monkeypatch.setattr(
+            merkle, "leaf_hash",
+            lambda it: taken.append("leaf") or real_leaf(it))
+        # the native batch hasher would take the leaf digests unseen
+        monkeypatch.setattr(tree_mod, "batched_hashes",
+                            lambda *a, **kw: None)
+        old = tracing.set_recorder(
+            tracing.Recorder(dump_dir=str(tmp_path)))
+        try:
+            t.working_root(2)
+            (ev,) = tracing.snapshot()
+        finally:
+            tracing.set_recorder(old)
+        assert ev["name"] == "state_root"
+        assert set(ev["attrs"]) == {"hashes"}
+        assert ev["attrs"]["hashes"] == len(taken)
+        assert taken.count("leaf") == changed
+        assert taken.count("inner") == keys - 1
+
     def test_reopen_recovers_exact_root(self, tmp_path):
         """Crash/restart: a new StateTree over the same db recovers
         the exact latest root, version, and per-version reads."""

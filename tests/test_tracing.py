@@ -6,6 +6,10 @@ Covers:
     category filters, ring-buffer bounding;
   * the disabled path as a true no-op (<1µs per span call — the
     always-on budget);
+  * causality: id/parent/tid on every event, parent and height
+    inherited through nested spans and asyncio.to_thread but not from
+    a parent that has closed; the span tree of one block-synced
+    height; per-tile kernel_execute spans of a tiled dispatch;
   * crash dumps: supervisor give-up and the nemesis safety-assertion
     failure leave parseable JSON records (the nemesis one names the
     conflicting-commit heights), rendered by tools/trace_report.py;
@@ -141,6 +145,398 @@ class TestRecorder:
         assert record["events"][0]["name"] == "commit"
 
 
+class TestCausality:
+    def test_nested_spans_record_parent_and_inherit_height(
+            self, recorder):
+        tracing.set_height(99)      # the last fallback only
+        with tracing.span(tracing.BLOCKSYNC, "sync_height",
+                          height=7) as outer:
+            with tracing.span(tracing.CONSENSUS, "commit_verify"):
+                tracing.instant(tracing.CRYPTO, "mark")
+                t0 = tracing.now_ns()
+                tracing.record_span(tracing.CRYPTO, "host_prep", t0)
+            with tracing.span(tracing.STATE, "apply_block",
+                              height=8):
+                pass
+        with tracing.span(tracing.CRYPTO, "alone"):
+            pass
+        ev = {e["name"]: e for e in tracing.snapshot()}
+        assert ev["sync_height"]["parent"] == 0
+        assert ev["sync_height"]["id"] == outer.id
+        assert ev["commit_verify"]["parent"] == outer.id
+        for name in ("mark", "host_prep"):
+            assert ev[name]["parent"] == ev["commit_verify"]["id"]
+        # a span that names no height takes its parent's; one that
+        # names its own keeps it; with no parent, the global one
+        for name in ("commit_verify", "mark", "host_prep"):
+            assert ev[name]["height"] == 7
+        assert ev["apply_block"]["height"] == 8
+        assert ev["alone"]["parent"] == 0
+        assert ev["alone"]["height"] == 99
+        ids = [e["id"] for e in ev.values()]
+        assert len(set(ids)) == len(ids) and all(i > 0 for i in ids)
+
+    def test_to_thread_keeps_the_parent(self, recorder):
+        import threading
+
+        def work():
+            with tracing.span(tracing.CRYPTO, "host_prep"):
+                pass
+            return threading.get_ident()
+
+        async def go():
+            with tracing.span(tracing.CRYPTO, "batch_verify",
+                              height=5) as outer:
+                return outer.id, await asyncio.to_thread(work)
+
+        outer_id, worker_tid = run(go())
+        ev = {e["name"]: e for e in tracing.snapshot()}
+        assert ev["host_prep"]["parent"] == outer_id
+        assert ev["host_prep"]["height"] == 5
+        assert ev["host_prep"]["tid"] == worker_tid
+        assert ev["batch_verify"]["tid"] == threading.get_ident()
+        assert worker_tid != threading.get_ident()
+
+    def test_a_closed_parent_is_no_parent(self, recorder):
+        """A long-lived task created inside a span copies the context
+        that held it; once the span has closed it parents nothing."""
+        async def go():
+            gate = asyncio.Event()
+
+            async def late():
+                await gate.wait()
+                with tracing.span(tracing.P2P, "late"):
+                    pass
+
+            async def early():
+                with tracing.span(tracing.P2P, "early"):
+                    pass
+
+            with tracing.span(tracing.CONSENSUS, "starter", height=3):
+                t_late = asyncio.get_running_loop().create_task(late())
+                await asyncio.get_running_loop().create_task(early())
+            gate.set()
+            await t_late
+
+        run(go())
+        ev = {e["name"]: e for e in tracing.snapshot()}
+        assert ev["early"]["parent"] == ev["starter"]["id"]
+        assert ev["early"]["height"] == 3
+        assert ev["late"]["parent"] == 0
+        assert ev["late"]["height"] == 0
+
+    def test_sibling_tasks_do_not_adopt_each_other(self, recorder):
+        async def go():
+            async def one(name):
+                with tracing.span(tracing.P2P, name):
+                    await asyncio.sleep(0.01)
+
+            await asyncio.gather(one("a"), one("b"))
+
+        run(go())
+        assert [e["parent"] for e in tracing.snapshot()] == [0, 0]
+
+    def test_id_parent_tid_in_snapshot_and_dump(self, recorder):
+        import threading
+        with tracing.span(tracing.ABCI, "consensus/finalize_block",
+                          height=2):
+            with tracing.span(tracing.ABCI, "state_root", hashes=3):
+                pass
+        snap = tracing.snapshot()
+        with open(tracing.dump(reason="fields")) as f:
+            dumped = json.load(f)["events"]
+        for events in (snap, dumped):
+            outer, inner = events
+            for e in events:
+                assert {"id", "parent", "tid"} <= set(e)
+                assert e["tid"] == threading.get_ident()
+            assert inner["parent"] == outer["id"] != 0
+            assert outer["parent"] == 0
+
+    def test_timed_span_lends_its_clock_readings(self, tmp_path):
+        """timed(): one pair of readings for the span and for the
+        metric at the same boundary — also with tracing off, where it
+        records nothing and parents nothing."""
+        for enabled in (True, False):
+            old = tracing.set_recorder(
+                Recorder(enabled=enabled, dump_dir=str(tmp_path)))
+            try:
+                with tracing.timed(tracing.CRYPTO, "host_prep",
+                                   batch=4) as sp:
+                    time.sleep(0.002)
+                    with tracing.span(tracing.CRYPTO, "inner"):
+                        pass
+                assert sp.seconds >= 0.002
+                evs = tracing.snapshot()
+                if not enabled:
+                    assert evs == []
+                    continue
+                outer = next(e for e in evs if e["name"] == "host_prep")
+                assert outer["dur_ns"] == sp.t1 - sp.t0
+                assert outer["dur_ns"] / 1e9 == sp.seconds
+                inner = next(e for e in evs if e["name"] == "inner")
+                assert inner["parent"] == outer["id"]
+            finally:
+                tracing.set_recorder(old)
+
+    def test_begin_under_end_spans_an_interval_in_pieces(
+            self, recorder):
+        """A pipelined tile: begun before its dispatch, ended after
+        its settle, the parent of what opens under() it in between
+        and of nothing else."""
+        sp = tracing.timed(tracing.CRYPTO, "kernel_execute",
+                           tile=0).begin()
+        with tracing.under(sp):
+            with tracing.span(tracing.CRYPTO, "launch"):
+                pass
+        with tracing.span(tracing.CRYPTO, "host_prep"):
+            pass
+        with tracing.under(sp):
+            with tracing.span(tracing.CRYPTO, "device_wait"):
+                pass
+        sp.end()
+        ev = {e["name"]: e for e in tracing.snapshot()}
+        assert ev["launch"]["parent"] == sp.id
+        assert ev["device_wait"]["parent"] == sp.id
+        assert ev["host_prep"]["parent"] == 0
+        ke = ev["kernel_execute"]
+        assert ke["id"] == sp.id
+        assert ke["ts_ns"] <= ev["launch"]["ts_ns"]
+        assert ke["ts_ns"] + ke["dur_ns"] >= \
+            ev["device_wait"]["ts_ns"] + ev["device_wait"]["dur_ns"]
+
+
+def _children(events):
+    out = {}
+    for e in events:
+        out.setdefault(e["parent"], []).append(e)
+    return out
+
+
+class TestBlocksyncSpanTree:
+    """A short block sync on the CPU (the benchmark's catch-up driver
+    at its tiniest: a fabricated 8-validator chain, one source peer,
+    the real BlocksyncReactor over localhost p2p) leaves, for every
+    height, one tree under its sync_height span."""
+
+    TINY = {"validators": 8, "chain_heights": 300, "chain_margin": 4,
+            "forged_height": 4, "prewarm_ops": 4, "warmup_ops": 8,
+            "quiet_ops": 4, "txs_per_block": 2, "tx_bytes": 64,
+            "kv_check_keys": 4}
+
+    def _sync(self, tmp_path):
+        import sys
+        if _ROOT not in sys.path:
+            sys.path.insert(0, _ROOT)
+        from benchmark.lib import loader
+        from benchmark.lib.compiles import CompileLog
+        from benchmark.lib.session import Ctx, Window
+
+        bench = loader.Bench(_ROOT)
+        cell = bench.cell("qa-175.catchup")
+        driver = bench.traffic(cell.driver)
+        ctx = Ctx(bench, cell, seed=11, seconds=0.3, trace=False,
+                  rehearsal=False, compiles=CompileLog(),
+                  t_start=time.monotonic())
+        ctx.work_dir = str(tmp_path)
+        ctx.overrides.update(self.TINY)
+
+        async def go():
+            ctx.configure_tracing()
+            state = await driver.set_up(ctx)
+            tracing.clear()
+            await driver.run(ctx, state, Window(
+                start=time.monotonic(), seconds=ctx.seconds))
+            events = tracing.snapshot()
+            await driver.tear_down(ctx, state)
+            return events
+
+        old = tracing.recorder()
+        try:
+            return run(go())
+        finally:
+            tracing.set_recorder(old)
+
+    def test_one_height_is_one_tree(self, tmp_path):
+        events = self._sync(tmp_path)
+        kids = _children(events)
+
+        def child(parent, name, **attrs):
+            found = [e for e in kids.get(parent["id"], ())
+                     if e["name"] == name and all(
+                         (e.get("attrs") or {}).get(k) == v
+                         for k, v in attrs.items())]
+            assert len(found) == 1, (parent["name"], name, found)
+            return found[0]
+
+        heights = [e for e in events if e["name"] == "sync_height"
+                   and e["attrs"]["outcome"] == "applied"]
+        assert len(heights) >= 3
+        top = heights[len(heights) // 2]
+        h = top["height"]
+        assert h > 2 and top["parent"] == 0
+        # only what something reads rides on the spans
+        assert set(top["attrs"]) == {"outcome"}
+
+        child(top, "part_set")
+        # the light verification of this height's commit (stops past
+        # 2/3 of 8) ...
+        light = child(top, "commit_verify")
+        child(light, "commit_walk")
+        assert child(light, "batch_verify")["attrs"]["batch"] == 6
+        # ... and the strict one of the block's LastCommit, whose
+        # request is the height before
+        validate = child(top, "validate_block")
+        strict = child(validate, "commit_verify")
+        assert strict["height"] == h - 1
+        child(strict, "commit_walk")
+        strict_seam = child(strict, "batch_verify")
+        assert strict_seam["height"] == h - 1
+        assert strict_seam["attrs"]["batch"] == 8
+
+        child(top, "store_save_block")
+        apply = child(top, "apply_block")
+        fin = child(apply, "consensus/finalize_block")
+        # two txs a block write two keys: two leaves hashed anew and
+        # every inner node above the leaves the tree then holds
+        assert child(fin, "state_root")["attrs"]["hashes"] > 2
+        child(apply, "save_finalize_response")
+        child(apply, "update_state")
+        child(child(apply, "app_commit"), "consensus/commit")
+        child(apply, "state_save")
+        child(apply, "fire_events")
+        # one span per boundary: no second apply_block, validate_block
+        # or block save around these
+        for name in ("apply_block", "validate_block",
+                     "store_save_block"):
+            assert sum(1 for e in events if e["name"] == name
+                       and e["height"] == h) == 1, name
+
+        # every span of the height, the LastCommit's subtree aside,
+        # carries the height and lies inside sync_height
+        def walk_tree(e):
+            yield e
+            for k in kids.get(e["id"], ()):
+                yield from walk_tree(k)
+
+        last_commit = {e["id"] for e in walk_tree(strict)}
+        end = top["ts_ns"] + top["dur_ns"]
+        for e in walk_tree(top):
+            assert e["height"] == (h - 1 if e["id"] in last_commit
+                                   else h), e
+            assert top["ts_ns"] <= e["ts_ns"] and \
+                e["ts_ns"] + e["dur_ns"] <= end, e
+        # the peers' sides are spans of their own, stamped with the
+        # height of the block they carry
+        for name in ("block_decode", "block_serve"):
+            wire = [e for e in events if e["name"] == name]
+            assert wire and all(
+                e["height"] > 0 and e["parent"] == 0
+                for e in wire), name
+        # apply_block has no time of its own to speak of
+        covered = sum(k["dur_ns"] for k in kids[apply["id"]])
+        assert covered > 0.5 * apply["dur_ns"]
+
+
+class TestTiledDispatchSpans:
+    def test_each_tile_runs_from_dispatch_to_mask(self, recorder,
+                                                  monkeypatch):
+        """A batch above one tile: one kernel_execute span per tile,
+        begun before the tile's dispatch (so before the next tile's
+        host_prep) and ended after its own settle, the four legs its
+        children.  The kernel is stubbed (its compile takes minutes on
+        a CPU); the dispatch path around it is the real one."""
+        import jax.numpy as jnp
+
+        from cometbft_tpu.crypto import ed25519
+        from cometbft_tpu.ops import ed25519_jax as ej
+
+        def stub(a8, r8, s8, k8):
+            return jnp.ones(a8.shape[0], dtype=bool)
+
+        monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
+        monkeypatch.setenv("COMETBFT_TPU_VERIFY_TILE", "64")
+        monkeypatch.setattr(ej, "_jit_verify_packed", stub)
+        monkeypatch.setattr(ej, "_jit_verify_packed_donated", stub)
+        priv = ed25519.gen_priv_key_from_secret(b"tiles")
+        pub = priv.pub_key().bytes()
+        items = [(pub, b"m%d" % i, priv.sign(b"m%d" % i))
+                 for i in range(150)]
+        with tracing.span(tracing.CRYPTO, "batch_verify", height=9,
+                          batch=len(items)) as seam:
+            ok, mask = ej.verify_batch(items)
+        assert ok and len(mask) == 150
+
+        events = tracing.snapshot()
+        kids = _children(events)
+        tiles = sorted((e for e in events
+                        if e["name"] == "kernel_execute"),
+                       key=lambda e: e["attrs"]["tile"])
+        preps = [e for e in events if e["name"] == "host_prep"]
+        assert [t["attrs"]["tile"] for t in tiles] == [0, 1, 2]
+        assert len(preps) == 3
+        for t in tiles:
+            assert t["parent"] == seam.id and t["height"] == 9
+            assert t["attrs"]["pipelined"] is True
+            assert t["attrs"]["batch"] == 50
+            assert t["attrs"]["bucket"] == 64
+            assert t["attrs"]["platform"] == "cpu"
+            legs = kids[t["id"]]
+            assert [e["name"] for e in legs] == \
+                ["h2d", "launch", "device_wait", "d2h"]
+            assert all(e["height"] == 9 for e in legs)
+            end = t["ts_ns"] + t["dur_ns"]
+            assert t["ts_ns"] <= legs[0]["ts_ns"]
+            assert legs[3]["ts_ns"] + legs[3]["dur_ns"] <= end
+        for i in (0, 1):
+            nxt = preps[i + 1]
+            # tile i is in flight before the next tile's prep begins
+            # and is settled only after that prep has ended
+            assert tiles[i]["ts_ns"] < nxt["ts_ns"]
+            assert tiles[i]["ts_ns"] + tiles[i]["dur_ns"] > \
+                nxt["ts_ns"] + nxt["dur_ns"]
+            assert nxt["parent"] == seam.id
+
+
+    def test_read_back_is_bare_with_the_recorder_off(self, tmp_path,
+                                                     monkeypatch):
+        """With the crypto category off the mask comes back by one
+        np.asarray, as before the legs were split: no wait of its own,
+        no span."""
+        import numpy as np
+
+        from cometbft_tpu.ops import ed25519_jax as ej
+
+        calls = []
+
+        class Dev:
+            def copy_to_host_async(self):
+                calls.append("copy_to_host_async")
+
+            def block_until_ready(self):
+                calls.append("block_until_ready")
+
+            def __array__(self, dtype=None, copy=None):
+                calls.append("asarray")
+                return np.ones(4, bool)
+
+        for categories, expected in (
+                (("consensus",), ["asarray"]),
+                (None, ["copy_to_host_async", "block_until_ready",
+                        "asarray"])):
+            old = tracing.set_recorder(Recorder(
+                categories=categories, dump_dir=str(tmp_path)))
+            try:
+                del calls[:]
+                assert ej._force(Dev()).all()
+                assert calls == expected
+                assert [e["name"] for e in tracing.snapshot()] == \
+                    (["device_wait", "d2h"] if categories is None
+                     else [])
+            finally:
+                tracing.set_recorder(old)
+
+
 class TestDisabledOverhead:
     def test_noop_span_under_1us(self, tmp_path):
         """The always-on budget: with tracing disabled, a span call
@@ -264,7 +660,7 @@ class TestTraceReport:
     def test_per_height_breakdown(self, recorder):
         base = tracing.now_ns()
         # height 4: propose step, proposal completes, crypto batch,
-        # abci finalize, save_block
+        # abci finalize, the block store's save
         tracing.record_span(tracing.CONSENSUS, "step:Propose",
                             base, base + 10_000_000, height=4)
         recorder.record_instant(tracing.CONSENSUS,
@@ -275,7 +671,7 @@ class TestTraceReport:
         tracing.record_span(tracing.ABCI, "consensus/finalize_block",
                             base + 6_000_000, base + 9_000_000,
                             height=4)
-        tracing.record_span(tracing.CONSENSUS, "save_block",
+        tracing.record_span(tracing.STATE, "store_save_block",
                             base + 9_000_000, base + 9_500_000,
                             height=4)
         mod = _load_trace_report()

@@ -21,11 +21,12 @@ timestamp falls into.  Buckets:
                collecting the proposal over p2p), falling back to the
                ``step:Propose`` span;
   * verify   — crypto ``batch_verify``/``kernel_execute``/``host_prep``
-               spans plus consensus ``validate_block``;
-  * execute  — abci call spans (the app's share);
-  * commit   — ``save_block`` plus the ``step:Commit`` span (fsync +
-               finalize path);
-  * pipeline — ``apply_block`` + ``barrier_wait``: the pipelined
+               spans plus the executor's ``validate_block``;
+  * execute  — abci call spans, ``<conn>/<method>`` (the app's share);
+  * commit   — the block store's ``store_save_block`` plus the
+               ``step:Commit`` span (fsync + finalize path);
+  * pipeline — the executor's ``apply_block`` + ``barrier_wait``: the
+               pipelined
                execute/commit overlapping the NEXT height, and the
                barrier stalls when it didn't finish in time.  Reported
                separately because pipelined work off the critical path
@@ -54,11 +55,26 @@ _VERIFY_NAMES = {"batch_verify", "kernel_execute", "host_prep",
 # this table against the names the instrumented modules actually
 # emit; "step:*" spans are matched by prefix)
 CONSENSUS_SPAN_BUCKETS = {
-    "validate_block": "verify",
-    "save_block": "commit",
     "step:Commit": "commit",
-    "apply_block": "pipeline",
     "barrier_wait": "pipeline",
+    # around the crypto spans the verify bucket already sums: known
+    # names, added to no bucket
+    "commit_verify": None,
+    "commit_walk": None,
+}
+
+# state span -> bucket (pinned the same way): the executor and the
+# block store own the spans of what consensus and blocksync call
+STATE_SPAN_BUCKETS = {
+    "validate_block": "verify",
+    "store_save_block": "commit",
+    "apply_block": "pipeline",
+    # the steps inside apply_block
+    "save_finalize_response": None,
+    "update_state": None,
+    "app_commit": None,
+    "state_save": None,
+    "fire_events": None,
 }
 
 # consensus instants counted per height (zero-duration markers)
@@ -156,8 +172,14 @@ def analyze(record: dict,
                                          a.get("kernel", "?")),
                         "bucket": a.get("bucket"),
                         "ms": dur / _MS})
-            elif cat == "abci":
+            elif cat == "abci" and "/" in name:
+                # the calls, "<conn>/<method>"; state_root nests
+                # inside consensus/finalize_block
                 row["execute_ms"] += dur / _MS
+            elif cat == "state":
+                bucket = STATE_SPAN_BUCKETS.get(name)
+                if bucket is not None:
+                    row[bucket + "_ms"] += dur / _MS
             elif cat == "p2p":
                 row["p2p_events"] += 1
                 row["p2p_bytes"] += _to_int(
