@@ -349,27 +349,43 @@ def _byte_cols(b8):
     return jnp.transpose(b8).astype(jnp.int32)
 
 
-def _verify_packed(a8, r8, s8, k8):
-    """The xla kernel behind the packed uint8 wire layout: inputs are
-    [m,32]/[m,64] uint8 host arrays (4x smaller transfers than the
-    int32 device layouts); unpacking runs on device."""
+# The packed wire: everything one dispatch sends to the device is ONE
+# [m, 192] uint8 buffer, a lane a row — A | R | S windows | k windows.
+# The host fills it in place (native ed25519_prep, or prep_arrays'
+# numpy path), one jax.device_put moves it, and the jitted entry
+# points below cut the four column ranges on the device.
+WIRE_LANE_BYTES = 192
+
+
+def wire_views(wire):
+    """(a_b [m,32], r_b [m,32], s_w8 [m,64], k_w8 [m,64]) of a wire
+    buffer, as views: on the host for what wants the four arrays
+    (parallel/mesh, tests), on the device inside the jitted kernels."""
+    return (wire[:, 0:32], wire[:, 32:64], wire[:, 64:128],
+            wire[:, 128:192])
+
+
+def _verify_packed(wire):
+    """The xla kernel behind the packed uint8 wire buffer (4x smaller
+    than the int32 device layouts); unpacking runs on device."""
+    a8, r8, s8, k8 = wire_views(wire)
     return _verify_kernel(a8, r8, _win_cols(s8), _win_cols(k8))
 
 
 _jit_verify_packed = jax.jit(_verify_packed)
-# the pipelined dispatch's TPU variant: per-tile input buffers are
-# never reused, so donating them caps device memory at two in-flight
-# tiles.  Separate executable cache key — TPU-only (see _launch).
-_jit_verify_packed_donated = jax.jit(_verify_packed,
-                                     donate_argnums=(0, 1, 2, 3))
+# the pipelined dispatch's TPU variant: a tile's wire buffer is never
+# reused, so donating it caps device memory at two in-flight tiles.
+# Separate executable cache key — TPU-only (see _launch).
+_jit_verify_packed_donated = jax.jit(_verify_packed, donate_argnums=0)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("kernel", "interpret", "block"))
-def _pallas_verify_packed(a8, r8, s8, k8, kernel="pallas",
-                          interpret=False, block=0):
-    """The pallas kernel behind the packed uint8 wire layout."""
+def _pallas_verify_packed(wire, kernel="pallas", interpret=False,
+                          block=0):
+    """The pallas kernel behind the packed uint8 wire buffer."""
     ep = _pallas_module(kernel)
+    a8, r8, s8, k8 = wire_views(wire)
     return ep.verify_cols(_byte_cols(a8), _byte_cols(r8),
                           _win_cols(s8), _win_cols(k8),
                           interpret=interpret, block=block or ep.BLOCK)
@@ -454,7 +470,7 @@ def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
         warm = (choice, m) in _SEEN_SHAPES
         with tracing.timed(tracing.CRYPTO, "host_prep", batch=hi - lo,
                            bucket=m, pipelined=True) as prep:
-            a_b, r_b, s_w8, k_w8, pre_bad = prep_arrays(chunk, m)
+            wire, pre_bad = prep_arrays(chunk, m)
         pad_bucket = str(m)
         hist.with_labels("host_prep", choice, pad_bucket,
                          "1" if warm else "0").observe(prep.seconds)
@@ -463,7 +479,7 @@ def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
                            batch=hi - lo, bucket=m, kernel=choice,
                            warm=warm, pipelined=True, tile=i).begin()
         with tracing.under(sp):
-            dev = _launch(a_b, r_b, s_w8, k_w8, choice=choice,
+            dev = _launch(wire, choice=choice,
                           part=_partitioner(m, choice), donate=donate)
         _SEEN_SHAPES.add((choice, m))
         if inflight is not None:
@@ -476,36 +492,59 @@ def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
     return bool(out.all()), out.tolist()
 
 
-def _launch(a_b, r_b, s_w8, k_w8, *, choice: str,
-            interpret: bool = False, block: int = 0, part=None,
-            donate: bool = False):
-    """Dispatch the selected kernel WITHOUT forcing the result: the
-    un-forced device array comes back (JAX async dispatch), and the
-    caller settles it with _force — the pipeline only after the next
-    tile is in flight.  Every dispatch and the warm-up go through
-    here, so a shape warmed is the executable the live path runs.
+def _with_frame_room(fn, *args, **kwargs):
+    """Call fn with room on the interpreter's frame stack.
 
-    Transfers use non-blocking ``jax.device_put``.  ``donate`` (the
-    pipelined dispatch on a TPU) runs the xla kernel's donated-argument
-    jit so each tile's input buffers free the moment the kernel
-    consumes them; donation changes the executable cache key, so on
-    CPU it would only force a second multi-minute XLA compile of the
-    same bucket."""
+    CPython 3.12 keeps a thread's Python frames in 16 KiB chunks and
+    frees a chunk the moment its first frame returns.  A loop whose own
+    frame ends a chunk therefore maps and unmaps a chunk for every call
+    it makes — ~9 us a call here, far more on the chip's sealed machine
+    — and tracing and lowering the ~43,000-equation Pallas kernel is
+    such loops at a hundred depths.  Whether one of them lands on a
+    boundary depends on every frame above it, so a shape's set-up read
+    19 s or 42 s (3 s clear of a boundary) on the stack depth of the
+    caller and on edits that moved a local variable (PERF.md, PR 26).
+    This function's frame asks for 1 MiB, which the interpreter rounds
+    up to a 2 MiB chunk: everything fn calls lives in that one chunk.
+    Mapping it costs ~11 us, so only a cold shape is called this way."""
+    return fn(*args, **kwargs)
+
+
+_with_frame_room.__code__ = _with_frame_room.__code__.replace(
+    co_stacksize=1 << 17)
+
+
+def _launch(wire, *, choice: str, interpret: bool = False,
+            block: int = 0, part=None, donate: bool = False):
+    """Dispatch the selected kernel on one wire buffer WITHOUT forcing
+    the result: the un-forced device array comes back (JAX async
+    dispatch), and the caller settles it with _force — the pipeline
+    only after the next tile is in flight.  Every dispatch and the
+    warm-up go through here, so a shape warmed is the executable the
+    live path runs.
+
+    A single-device dispatch is one non-blocking ``jax.device_put`` of
+    the whole buffer and one jitted call of one argument: a transfer
+    costs ~0.27 ms a call on a v5e whatever its size (PERF.md, PR 24
+    and 26).  ``donate`` (the pipelined dispatch on a TPU) runs the
+    xla kernel's donated-argument jit so each tile's buffer frees the
+    moment the kernel consumes it; donation changes the executable
+    cache key, so on CPU it would only force a second multi-minute XLA
+    compile of the same bucket."""
     if part is not None:
-        return part.dispatch(a_b, r_b, s_w8, k_w8)
+        return part.dispatch(*wire_views(wire))
     with tracing.span(tracing.CRYPTO, "h2d"):
-        da = jax.device_put(a_b)
-        dr = jax.device_put(r_b)
-        ds = jax.device_put(s_w8)
-        dk = jax.device_put(k_w8)
+        dw = jax.device_put(wire)
+    if choice.startswith("pallas"):
+        fn = functools.partial(_pallas_verify_packed, kernel=choice,
+                               interpret=interpret, block=block)
+    else:
+        fn = _jit_verify_packed_donated if donate else _jit_verify_packed
     with tracing.span(tracing.CRYPTO, "launch"):
-        if choice.startswith("pallas"):
-            return _pallas_verify_packed(
-                da, dr, ds, dk, kernel=choice, interpret=interpret,
-                block=block)
-        if donate:
-            return _jit_verify_packed_donated(da, dr, ds, dk)
-        return _jit_verify_packed(da, dr, ds, dk)
+        if (choice, wire.shape[0]) in _SEEN_SHAPES:
+            return fn(dw)
+        # a shape's first call traces, lowers and compiles inside it
+        return _with_frame_room(fn, dw)
 
 
 def _force(dev, sp=None) -> np.ndarray:
@@ -575,14 +614,14 @@ def _verify_chunk(items) -> np.ndarray:
     # crypto_kernel_dispatch_seconds
     with tracing.timed(tracing.CRYPTO, "host_prep", batch=n,
                        bucket=m) as prep:
-        a_b, r_b, s_win, k_win, pre_bad = prep_arrays(items, m)
+        wire, pre_bad = prep_arrays(items, m)
     # compile-vs-execute attribution: the first dispatch of a
     # (kernel, bucket) shape includes trace+compile (unless warmup()
     # or the persistent cache served it); warm dispatches are pure
     # execution
     with tracing.timed(tracing.CRYPTO, "kernel_execute", batch=n,
                        bucket=m, kernel=choice, warm=warm) as sp:
-        out = _dispatch(n, a_b, r_b, s_win, k_win, pre_bad, sp=sp)
+        out = _dispatch(n, wire, pre_bad, sp=sp)
     pad_bucket = str(m)
     hist.with_labels("host_prep", choice, pad_bucket,
                      "1" if warm else "0").observe(prep.seconds)
@@ -597,16 +636,30 @@ def _verify_chunk(items) -> np.ndarray:
     return out
 
 
+def _padding_wire(m: int) -> np.ndarray:
+    """A wire buffer of m padding lanes, which verify trivially:
+    0·B - identity - 0·A == identity."""
+    wire = np.zeros((m, WIRE_LANE_BYTES), np.uint8)
+    a_b, r_b, _, _ = wire_views(wire)
+    a_b[:] = np.frombuffer(_B_BYTES, np.uint8)
+    r_b[:] = np.frombuffer(_IDENTITY_BYTES, np.uint8)
+    return wire
+
+
 def prep_arrays(items, m: int):
     """The full host-side prep for a batch of (pub, msg, sig) items,
     padded to m lanes: length/canonical-S checks, k = SHA-512(R||A||msg)
-    mod L, 4-bit window split.  Returns (a_b [m,32]u8, r_b [m,32]u8,
-    s_w8 [m,64]u8, k_w8 [m,64]u8, pre_bad [m]bool) — the packed uint8
-    wire layout; the device transposes/casts to the kernels' int32
-    layouts, so the wire stays at 1 byte per element.  Uses the
-    one-pass C prep when the native module is built (the node builds
-    it at start), else the vectorized numpy path with a per-item
-    Python SHA-512 — and says so once, because that path is slow."""
+    mod L, 4-bit window split.  Returns (wire [m,192]u8, pre_bad
+    [m]bool): the one packed buffer a dispatch sends to the device, a
+    lane a row of A (32 bytes) | R (32) | S windows (64) | k windows
+    (64) — wire_views names the four column ranges; the device cuts
+    them, transposes and casts to the kernels' int32 layouts, so the
+    wire stays at 1 byte per element and one transfer.  Padding lanes
+    hold B, the identity and zero windows, and verify trivially.  Uses
+    the one-pass C prep when the native module is built (the node
+    builds it at start), else the vectorized numpy path with a per-item
+    Python SHA-512 — and says so once, because that path is slow.
+    Both fill the buffer in place: nothing is concatenated."""
     global _WARNED_NO_NATIVE
     from ..crypto._native_loader import load as _load_native
     native = _load_native(allow_build=False)
@@ -620,22 +673,15 @@ def prep_arrays(items, m: int):
         # the ENTIRE host prep in one C pass (length checks,
         # canonical-S, k = SHA-512(R||A||msg) mod L, window split),
         # threaded across cores with the GIL released
-        a_buf, r_buf, sw_buf, kw_buf, bad_buf = native.ed25519_prep(
+        wire_buf, bad_buf = native.ed25519_prep(
             items, m, _B_BYTES, _IDENTITY_BYTES)
-        a_b = np.frombuffer(a_buf, np.uint8).reshape(m, 32)
-        r_b = np.frombuffer(r_buf, np.uint8).reshape(m, 32)
-        s_w8 = np.frombuffer(sw_buf, np.uint8).reshape(m, 64)
-        k_w8 = np.frombuffer(kw_buf, np.uint8).reshape(m, 64)
+        wire = np.frombuffer(wire_buf, np.uint8).reshape(
+            m, WIRE_LANE_BYTES)
         pre_bad = np.frombuffer(bad_buf, np.uint8).astype(bool)
-        return a_b, r_b, s_w8, k_w8, pre_bad
+        return wire, pre_bad
 
-    a_b = np.zeros((m, 32), np.uint8)
-    r_b = np.zeros((m, 32), np.uint8)
-    s_raw = np.zeros((m, 32), np.uint8)
-    k_raw = np.zeros((m, 32), np.uint8)
-    # padding lanes verify trivially: 0·B - identity - 0·A == identity
-    a_b[:] = np.frombuffer(_B_BYTES, np.uint8)
-    r_b[:] = np.frombuffer(_IDENTITY_BYTES, np.uint8)
+    wire = _padding_wire(m)
+    a_b, r_b, s_w8, k_w8 = wire_views(wire)
     pre_bad = np.zeros(m, bool)
 
     # ---- host prep, vectorized (it sits inside the <5 ms e2e budget:
@@ -678,12 +724,12 @@ def prep_arrays(items, m: int):
             k = ref.sha512_mod_l(buf[:32], buf[32:64], buf[64:])
             k_g[j] = np.frombuffer(k.to_bytes(32, "little"),
                                    np.uint8)
-        keep = np.asarray(s_ok)
-        a_b[gi[keep]] = a_g[keep]
-        r_b[gi[keep]] = r_g[keep]
-        s_raw[gi[keep]] = s_g[keep]
-        k_raw[gi[keep]] = k_g[keep]
-    return a_b, r_b, _windows_u8(s_raw), _windows_u8(k_raw), pre_bad
+        keep = gi[s_ok]
+        a_b[keep] = a_g[s_ok]
+        r_b[keep] = r_g[s_ok]
+        s_w8[keep] = _windows_u8(s_g[s_ok])
+        k_w8[keep] = _windows_u8(k_g[s_ok])
+    return wire, pre_bad
 
 
 def _shard_min() -> int:
@@ -708,18 +754,17 @@ def _partitioner(m: int, choice: str, interpret: bool = False,
     return None
 
 
-def _dispatch(n: int, a_b, r_b, s_w8, k_w8, pre_bad, *,
-              kernel: str = "", interpret: bool = False,
-              block: int = 0, sp=None) -> np.ndarray:
-    """Run the selected kernel on prepped arrays and settle it.
+def _dispatch(n: int, wire, pre_bad, *, kernel: str = "",
+              interpret: bool = False, block: int = 0,
+              sp=None) -> np.ndarray:
+    """Run the selected kernel on a prepped wire buffer and settle it.
     kernel/interpret/block override the environment-driven choice
     (used by the interpret-mode Pallas parity tests, which exercise
     this exact path with a small block)."""
     choice = kernel or _kernel_choice()
-    part = _partitioner(a_b.shape[0], choice, interpret, block)
-    ok = _force(_launch(a_b, r_b, s_w8, k_w8, choice=choice,
-                        interpret=interpret, block=block, part=part),
-                sp)
+    part = _partitioner(wire.shape[0], choice, interpret, block)
+    ok = _force(_launch(wire, choice=choice, interpret=interpret,
+                        block=block, part=part), sp)
     ok = ok[:n].copy()
     ok[pre_bad[:n]] = False
     return ok
@@ -743,12 +788,10 @@ def warmup(n: int) -> None:
 @functools.lru_cache(maxsize=None)
 def _warmup_bucket(m: int, donate: bool) -> None:
     choice = _kernel_choice()
-    a = np.tile(np.frombuffer(_B_BYTES, np.uint8), (m, 1))
-    r = np.tile(np.frombuffer(_IDENTITY_BYTES, np.uint8), (m, 1))
-    z = np.zeros((m, _WINDOWS), np.uint8)
+    wire = _padding_wire(m)
     with tracing.span(tracing.CRYPTO, "kernel_compile", bucket=m,
                       kernel=choice) as sp:
-        _force(_launch(a, r, z, z, choice=choice,
+        _force(_launch(wire, choice=choice,
                        part=_partitioner(m, choice), donate=donate),
                sp)
     _SEEN_SHAPES.add((choice, m))

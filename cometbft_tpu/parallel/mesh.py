@@ -88,7 +88,13 @@ class PipelinePartitioner:
 
     ``dispatch`` returns the UN-forced device array (JAX async
     dispatch): the pipeline settles it with np.asarray only after the
-    next tile is in flight."""
+    next tile is in flight.
+
+    A path of its own: it shards each of the four arrays on the lane
+    axis, so it keeps the four-array signature and is fed the views of
+    the wire buffer (ops/ed25519_jax.wire_views).  The single-device
+    dispatch's one packed transfer (ops/ed25519_jax._launch) shares no
+    logic with it."""
 
     def __init__(self, ndev: int, kernel: str = "xla",
                  interpret: bool = False, block: int = 0):

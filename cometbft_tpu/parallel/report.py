@@ -107,9 +107,9 @@ def sharded_10k_report(ndev: int = NDEV, m: int = BUCKET,
             sig = sig[:32] + bytes(32)
         items.append((pub, msg, sig))
         golden.append(ref.verify(pub, msg, sig))
-    a_b, r_b, s_w8, k_w8, pre_bad = ej.prep_arrays(items, run_lanes)
+    wire, pre_bad = ej.prep_arrays(items, run_lanes)
     import numpy as _np
-    ok = _np.array(pmesh.verify_sharded(a_b, r_b, s_w8, k_w8,
+    ok = _np.array(pmesh.verify_sharded(*ej.wire_views(wire),
                                         ndev=ndev, kernel="xla"))
     ok = ok[:len(items)]
     ok[pre_bad[:len(items)]] = False

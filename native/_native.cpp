@@ -184,15 +184,18 @@ PyObject* ed25519_kscalars(PyObject*, PyObject* arg) {
     return out;
 }
 
-// ed25519_prep(items, m, b_bytes, identity_bytes) ->
-//   (a_b, r_b, s_w8, k_w8, pre_bad)
+// ed25519_prep(items, m, b_bytes, identity_bytes) -> (wire, pre_bad)
 // items: sequence of (pub, msg, sig) byte tuples; m: padded lane
-// count (>= len(items)).  Outputs are numpy-ready buffers in the
-// packed uint8 WIRE layout (1 byte per element — a quarter of the
-// int32 device layouts on the host->device transfer; the int32
-// transpose/cast runs on-device):
-//   a_b, r_b: [m, 32] uint8 (padding lanes = B / identity)
-//   s_w8, k_w8: [m, 64] uint8 4-bit windows, lane-major
+// count (>= len(items)).  Outputs are numpy-ready buffers:
+//   wire: [m, 192] uint8, ONE buffer that goes to the device in one
+//     transfer (1 byte per element — a quarter of the int32 device
+//     layouts; the int32 transpose/cast runs on-device).  A lane is a
+//     row of WIRE_LANE bytes:
+//       [0, 32)    A (padding lanes = B)
+//       [32, 64)   R (padding lanes = identity)
+//       [64, 128)  4-bit windows of S
+//       [128, 192) 4-bit windows of k
+//     (ops/ed25519_jax.wire_views names the same four column ranges)
 //   pre_bad: [m] uint8 (1 = malformed or non-canonical S)
 // This is the batch verifier's entire host prep: pointers are
 // extracted under the GIL (cheap), then the SHA-512 / window loop
@@ -200,6 +203,10 @@ PyObject* ed25519_kscalars(PyObject*, PyObject* arg) {
 // < 5 ms e2e at 10k sigs) leaves < 3 ms for all host work, and
 // single-threaded SHA-512 alone is ~9 ms at 10k.
 namespace prep {
+
+// one lane of the wire buffer: A | R | S windows | k windows
+constexpr Py_ssize_t WIRE_LANE = 192;
+constexpr Py_ssize_t WIRE_R = 32, WIRE_S = 64, WIRE_K = 128;
 
 struct ItemRef {
     const uint8_t* pub;
@@ -225,10 +232,10 @@ inline void write_windows(uint8_t* row, const uint8_t le[32]) {
 }
 
 inline void k_windows_from_digest(const uint8_t digest[64],
-                                  uint8_t* kw8, Py_ssize_t lane) {
+                                  uint8_t* wire, Py_ssize_t lane) {
     uint8_t k_le[32];
     sha512::reduce_mod_l(digest, k_le);
-    write_windows(kw8 + lane * 64, k_le);
+    write_windows(wire + lane * WIRE_LANE + WIRE_K, k_le);
 }
 
 #if COMETBFT_SHA512MB_X86
@@ -242,7 +249,7 @@ struct KGroup {
 };
 
 inline void flush_group(KGroup& g, std::vector<uint8_t>& scratch,
-                        uint8_t* kw8) {
+                        uint8_t* wire) {
     if (g.n == 0) return;
     size_t slot = g.nblocks * 128;
     scratch.assign(slot * 8, 0);
@@ -265,16 +272,16 @@ inline void flush_group(KGroup& g, std::vector<uint8_t>& scratch,
     uint8_t digests[8][64];
     sha512mb::hash8(base, g.nblocks, digests);
     for (int l = 0; l < g.n; l++)
-        k_windows_from_digest(digests[l], kw8, g.lane[l]);
+        k_windows_from_digest(digests[l], wire, g.lane[l]);
     g.n = 0;
 }
 #endif
 
 // phase 2 worker: lanes [lo, hi) — canonical-S, row copies, SHA-512
-// (8-way multi-buffer where AVX-512 is present), item-major windows
+// (8-way multi-buffer where AVX-512 is present), item-major windows,
+// each lane into its own row of the wire buffer
 void lanes(const ItemRef* refs, Py_ssize_t lo, Py_ssize_t hi,
-           uint8_t* a_p, uint8_t* r_p, uint8_t* sw8, uint8_t* kw8,
-           uint8_t* bad_p) {
+           uint8_t* wire, uint8_t* bad_p) {
 #if COMETBFT_SHA512MB_X86
     const bool use_mb = sha512mb::available();
     // groups keyed by block count (messages in one batch are nearly
@@ -298,9 +305,10 @@ void lanes(const ItemRef* refs, Py_ssize_t lo, Py_ssize_t hi,
             bad_p[i] = 1;
             continue;
         }
-        std::memcpy(a_p + i * 32, it.pub, 32);
-        std::memcpy(r_p + i * 32, it.sig, 32);
-        write_windows(sw8 + i * 64, s_le);
+        uint8_t* row = wire + i * WIRE_LANE;
+        std::memcpy(row, it.pub, 32);
+        std::memcpy(row + WIRE_R, it.sig, 32);
+        write_windows(row + WIRE_S, s_le);
 #if COMETBFT_SHA512MB_X86
         if (use_mb) {
             size_t nb = sha512mb::block_count(64 + it.msglen);
@@ -315,7 +323,7 @@ void lanes(const ItemRef* refs, Py_ssize_t lo, Py_ssize_t hi,
                 }
                 g->lane[g->n] = i;
                 g->item[g->n] = &it;
-                if (++g->n == 8) flush_group(*g, scratch, kw8);
+                if (++g->n == 8) flush_group(*g, scratch, wire);
                 continue;
             }
         }
@@ -328,10 +336,10 @@ void lanes(const ItemRef* refs, Py_ssize_t lo, Py_ssize_t hi,
         sha512::update(&c, it.msg, it.msglen);
         uint8_t digest[64];
         sha512::final(&c, digest);
-        k_windows_from_digest(digest, kw8, i);
+        k_windows_from_digest(digest, wire, i);
     }
 #if COMETBFT_SHA512MB_X86
-    for (auto& g : groups) flush_group(g, scratch, kw8);
+    for (auto& g : groups) flush_group(g, scratch, wire);
 #endif
 }
 
@@ -378,24 +386,15 @@ PyObject* ed25519_prep(PyObject*, PyObject* args) {
         PyErr_SetString(PyExc_ValueError, "m < len(items)");
         return nullptr;
     }
-    PyObject* a_out = PyBytes_FromStringAndSize(nullptr, m * 32);
-    PyObject* r_out = PyBytes_FromStringAndSize(nullptr, m * 32);
-    PyObject* sw_out = PyBytes_FromStringAndSize(
-        nullptr, Py_ssize_t(64) * m);
-    PyObject* kw_out = PyBytes_FromStringAndSize(
-        nullptr, Py_ssize_t(64) * m);
+    PyObject* wire_out = PyBytes_FromStringAndSize(
+        nullptr, prep::WIRE_LANE * m);
     PyObject* bad_out = PyBytes_FromStringAndSize(nullptr, m);
-    if (!a_out || !r_out || !sw_out || !kw_out || !bad_out) {
-        Py_XDECREF(a_out); Py_XDECREF(r_out); Py_XDECREF(sw_out);
-        Py_XDECREF(kw_out); Py_XDECREF(bad_out); Py_DECREF(fast);
+    if (!wire_out || !bad_out) {
+        Py_XDECREF(wire_out); Py_XDECREF(bad_out); Py_DECREF(fast);
         return nullptr;
     }
-    uint8_t* a_p = reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(a_out));
-    uint8_t* r_p = reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(r_out));
-    uint8_t* sw_p =
-        reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(sw_out));
-    uint8_t* kw_p =
-        reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(kw_out));
+    uint8_t* wire_p =
+        reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(wire_out));
     uint8_t* bad_p =
         reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(bad_out));
 
@@ -436,29 +435,27 @@ PyObject* ed25519_prep(PyObject*, PyObject* args) {
     }
 
     // phase 2 (GIL released): hash/window lanes, straight into the
-    // lane-major uint8 output buffers
+    // rows of the one wire buffer
     {
         const prep::ItemRef* refp = refs.data();
         Py_BEGIN_ALLOW_THREADS
         // padding defaults (windows of unwritten lanes must be zero)
-        std::memset(sw_p, 0, size_t(64) * size_t(m));
-        std::memset(kw_p, 0, size_t(64) * size_t(m));
+        std::memset(wire_p, 0, size_t(prep::WIRE_LANE) * size_t(m));
         for (Py_ssize_t i = 0; i < m; i++) {
-            std::memcpy(a_p + i * 32, b_bytes, 32);
-            std::memcpy(r_p + i * 32, id_bytes, 32);
+            uint8_t* row = wire_p + i * prep::WIRE_LANE;
+            std::memcpy(row, b_bytes, 32);
+            std::memcpy(row + prep::WIRE_R, id_bytes, 32);
             bad_p[i] = 0;
         }
         prep::run_threads(n, [&](Py_ssize_t lo, Py_ssize_t hi) {
-            prep::lanes(refp, lo, hi, a_p, r_p, sw_p, kw_p, bad_p);
+            prep::lanes(refp, lo, hi, wire_p, bad_p);
         });
         Py_END_ALLOW_THREADS
     }
     for (PyObject* fit : fits) Py_DECREF(fit);
     Py_DECREF(fast);
-    PyObject* out = PyTuple_Pack(5, a_out, r_out, sw_out, kw_out,
-                                 bad_out);
-    Py_DECREF(a_out); Py_DECREF(r_out); Py_DECREF(sw_out);
-    Py_DECREF(kw_out); Py_DECREF(bad_out);
+    PyObject* out = PyTuple_Pack(2, wire_out, bad_out);
+    Py_DECREF(wire_out); Py_DECREF(bad_out);
     return out;
 }
 
@@ -1027,7 +1024,7 @@ PyMethodDef kMethods[] = {
      "RLC batch verification of (pub, msg, sig) items (ZIP-215)"},
     {"ed25519_prep", ed25519_prep, METH_VARARGS,
      "full batch-verify host prep: (items, m, B, identity) -> "
-     "(a_b, r_b, s_win, k_win, pre_bad)"},
+     "(wire [m*192], pre_bad [m])"},
     {"ed25519_batch_verify_tile", ed25519_batch_verify_tile,
      METH_VARARGS,
      "per-tile RLC batch verification over packed blobs "

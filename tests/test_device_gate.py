@@ -204,9 +204,10 @@ class TestWarmupCoversTheDispatchShapes:
         from cometbft_tpu.ops import ed25519_jax as ej
         shapes = []
 
-        def fake_launch(a_b, r_b, s_w8, k_w8, **kw):
-            shapes.append((a_b.shape[0], kw["choice"]))
-            return np.ones(a_b.shape[0], bool)
+        def fake_launch(wire, **kw):
+            assert wire.shape[1] == ej.WIRE_LANE_BYTES
+            shapes.append((wire.shape[0], kw["choice"]))
+            return np.ones(wire.shape[0], bool)
 
         monkeypatch.setattr(ej, "_launch", fake_launch)
         monkeypatch.setattr(ej, "_force",

@@ -103,10 +103,10 @@ class TestEd25519Prep:
             [None, 42, (b"x" * 32, b"m", b"s" * 64),
              (b"short", b"m", b"s" * 64)],
             8, b"b" * 32, b"i" * 32)
-        a_b, r_b, s_win, k_win, bad = out
+        wire, bad = out
         assert bad[0] == 1 and bad[1] == 1 and bad[3] == 1
-        # s_win is lane-major uint8 since the packed-wire rewrite
-        assert len(a_b) == 8 * 32 and len(s_win) == 8 * 64
+        # one buffer, a lane a row of A | R | S windows | k windows
+        assert len(wire) == 8 * 192 and len(bad) == 8
 
 
 class TestSha512AndKScalars:
@@ -266,8 +266,10 @@ class TestPrepParityVariedLengths:
         finally:
             _native_loader._mod = saved_mod
             _native_loader._failed = saved_failed
-        for name, a, b in zip(("a_b", "r_b", "s_win", "k_win",
-                               "pre_bad"), native_out, python_out):
+        # the one packed buffer a device_put sends, byte for byte
+        for name, a, b in zip(("wire", "pre_bad"), native_out,
+                              python_out):
+            assert a.shape == b.shape and a.dtype == b.dtype, name
             assert np.array_equal(a, b), f"{name} differs"
 
 

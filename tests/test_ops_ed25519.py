@@ -162,6 +162,296 @@ class TestBatchVerifierDispatch:
         assert not ok and mask == [True, False, True]
 
 
+def _wire_items():
+    """40 fixed items (RFC 8032 signing is deterministic): valid,
+    forged, non-canonical S, short key, short signature."""
+    items = []
+    for i in range(40):
+        seed = bytes([i + 1]) * 32
+        msg = b"wire-%03d" % i + bytes(i * 7 % 150)
+        pub, sig = ref.public_key(seed), ref.sign(seed, msg)
+        if i % 7 == 3:          # forged: another R
+            sig = bytes([sig[0] ^ 1]) + sig[1:]
+        if i % 9 == 4:          # non-canonical S
+            s = int.from_bytes(sig[32:], "little") + ref.L
+            sig = sig[:32] + s.to_bytes(32, "little")
+        if i % 13 == 6:         # short key
+            pub = pub[:31]
+        if i == 20:             # short signature
+            sig = sig[:63]
+        items.append((pub, msg, sig))
+    return items
+
+
+def _four_arrays_item_by_item(items, m):
+    """What the four-array prep before the packed wire returned, one
+    item at a time from the golden model."""
+    a_b = np.tile(np.frombuffer(ej._B_BYTES, np.uint8), (m, 1))
+    r_b = np.tile(np.frombuffer(ej._IDENTITY_BYTES, np.uint8), (m, 1))
+    s_w8 = np.zeros((m, 64), np.uint8)
+    k_w8 = np.zeros((m, 64), np.uint8)
+    pre_bad = np.zeros(m, bool)
+
+    def windows(v):
+        return [(v >> (4 * w)) & 0xF for w in range(64)]
+
+    for i, (pub, msg, sig) in enumerate(items):
+        s = int.from_bytes(sig[32:], "little")
+        if len(pub) != 32 or len(sig) != 64 or s >= ref.L:
+            pre_bad[i] = True
+            continue
+        a_b[i] = np.frombuffer(pub, np.uint8)
+        r_b[i] = np.frombuffer(sig[:32], np.uint8)
+        s_w8[i] = windows(s)
+        k_w8[i] = windows(ref.sha512_mod_l(sig[:32], pub, msg))
+    return a_b, r_b, s_w8, k_w8, pre_bad
+
+
+@pytest.fixture(params=["native", "numpy"])
+def prep_path(request, monkeypatch):
+    """Both fillers of the wire buffer: the C pass and the numpy
+    fallback."""
+    from cometbft_tpu.crypto import _native_loader
+    if request.param == "native":
+        if _native_loader.load() is None:
+            pytest.skip("no compiler for the native module")
+    else:
+        monkeypatch.setenv("COMETBFT_TPU_NATIVE", "0")
+        monkeypatch.setattr(_native_loader, "_mod", None)
+    return request.param
+
+
+class TestPackedWire:
+    """One buffer a dispatch: prep_arrays fills [m, 192] uint8 in
+    place, a lane a row of A | R | S windows | k windows."""
+
+    # sha256 of each array the four-array prep_arrays of commit
+    # d3525d2 (PR 24) returned for _wire_items() padded to 64 lanes
+    PARENT_SHA256 = {
+        "a_b": "9dec0743cf10186b507730b5a0b0be87"
+               "e1e0f6ac1710d07af31f3d1c0de82f78",
+        "r_b": "c5f41624756ab2365e28c8b270f07dac"
+               "a4c8b190f2dbf731e8c9d8321ebf27cd",
+        "s_w8": "000cbf3761e86093f74765930373b237"
+                "56f54ad249af784dafeaa72a215ffdc2",
+        "k_w8": "317b84cac0fd92d29d6140a51bdf3624"
+                "4b6bed39c308831693f46dbc81e0d83c",
+        "pre_bad": "9a10fd2df8a1b85280124da22e92172e"
+                   "15cffb1750bc1e56af2ebd91394bd795",
+    }
+
+    def test_one_contiguous_buffer(self, prep_path):
+        wire, pre_bad = ej.prep_arrays(_wire_items(), 64)
+        assert wire.shape == (64, ej.WIRE_LANE_BYTES)
+        assert wire.dtype == np.uint8 and wire.flags.c_contiguous
+        assert pre_bad.shape == (64,) and pre_bad.dtype == bool
+        # the four arrays are views of it: nothing was concatenated
+        for view in ej.wire_views(wire):
+            assert np.shares_memory(view, wire)
+
+    def test_views_are_what_the_four_array_prep_returned(
+            self, prep_path):
+        import hashlib
+        items = _wire_items()
+        wire, pre_bad = ej.prep_arrays(items, 64)
+        got = dict(zip(("a_b", "r_b", "s_w8", "k_w8", "pre_bad"),
+                       ej.wire_views(wire) + (pre_bad,)))
+        want = dict(zip(got, _four_arrays_item_by_item(items, 64)))
+        for name, arr in got.items():
+            assert arr.shape == want[name].shape, name
+            assert np.array_equal(arr, want[name]), name
+            digest = hashlib.sha256(
+                np.ascontiguousarray(arr).tobytes()).hexdigest()
+            assert digest == self.PARENT_SHA256[name], name
+        assert np.flatnonzero(pre_bad).tolist() == \
+            [4, 6, 13, 19, 20, 22, 31, 32]
+
+    def test_padding_and_refused_lanes_hold_b_and_identity(
+            self, prep_path):
+        wire, pre_bad = ej.prep_arrays(_wire_items(), 64)
+        a_b, r_b, s_w8, k_w8 = ej.wire_views(wire)
+        idle = np.r_[np.flatnonzero(pre_bad), 40:64]
+        assert (a_b[idle] == np.frombuffer(ej._B_BYTES,
+                                           np.uint8)).all()
+        assert (r_b[idle] == np.frombuffer(ej._IDENTITY_BYTES,
+                                           np.uint8)).all()
+        assert not s_w8[idle].any() and not k_w8[idle].any()
+        assert np.array_equal(wire[40:], ej._padding_wire(24))
+        # forged lanes are not refused on the host: the kernel decides
+        assert not pre_bad[[3, 10, 17, 24, 38]].any()
+
+
+class TestOneTransferADispatch:
+    """Every single-device dispatch hands the runtime one buffer: a
+    transfer costs the same ~0.27 ms on a v5e whatever its size, so
+    the count is what is paid for (PERF.md, PR 26).  The kernels are
+    stubbed (their compile takes minutes on a CPU); prep, _launch and
+    the pipeline around them are the real ones."""
+
+    @pytest.fixture
+    def counted(self, monkeypatch):
+        import jax
+        puts, calls = [], []
+        real_put = jax.device_put
+
+        def device_put(x, *args, **kw):
+            puts.append(np.shape(x))
+            return real_put(x, *args, **kw)
+
+        def stub(*operands, **static):
+            calls.append(operands)
+            return jnp.ones(operands[0].shape[0], dtype=bool)
+
+        monkeypatch.setattr(ej.jax, "device_put", device_put)
+        # conftest's eight virtual devices would send 1,024 lanes to
+        # the mesh partitioner: one chip is what a cell runs on
+        monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1000000")
+        for name in ("_jit_verify_packed",
+                     "_jit_verify_packed_donated",
+                     "_pallas_verify_packed"):
+            monkeypatch.setattr(ej, name, stub)
+        return puts, calls
+
+    @staticmethod
+    def _commit(n):
+        pub, msg, sig = _sig()
+        return [(pub, msg, sig)] * n
+
+    @pytest.mark.parametrize("kernel", ["xla", "pallas"])
+    def test_verify_batch_of_175(self, counted, monkeypatch, kernel):
+        import jax
+        puts, calls = counted
+        monkeypatch.setenv("COMETBFT_TPU_KERNEL", kernel)
+        ok, mask = ej.verify_batch(self._commit(175))
+        assert ok and len(mask) == 175
+        assert puts == [(1024, ej.WIRE_LANE_BYTES)]
+        # and nothing rides in beside it: one operand, on the device
+        (operands,) = calls
+        assert len(operands) == 1
+        assert isinstance(operands[0], jax.Array)
+        assert operands[0].shape == (1024, ej.WIRE_LANE_BYTES)
+
+    def test_each_tile_of_the_pipelined_path(self, counted,
+                                             monkeypatch):
+        import jax
+        puts, calls = counted
+        monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
+        monkeypatch.setenv("COMETBFT_TPU_VERIFY_TILE", "64")
+        ok, mask = ej.verify_batch(self._commit(150))
+        assert ok and len(mask) == 150
+        assert puts == [(64, ej.WIRE_LANE_BYTES)] * 3
+        assert [len(operands) for operands in calls] == [1, 1, 1]
+        assert all(isinstance(operands[0], jax.Array)
+                   for operands in calls)
+
+    def test_warmup_sends_the_shape_the_live_path_sends(
+            self, counted, monkeypatch):
+        puts, _ = counted
+        monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
+        ej._warmup_bucket.cache_clear()
+        try:
+            ej.warmup(175)
+        finally:
+            ej._warmup_bucket.cache_clear()
+        assert puts == [(1024, ej.WIRE_LANE_BYTES)]
+
+
+class TestFrameRoomForColdShapes:
+    """A shape's first call traces and lowers ~43,000 equations in
+    Python; _launch makes it on a frame-stack chunk of its own so the
+    set-up time does not depend on where the caller's stack happens to
+    cross one of CPython's 16 KiB chunks (PERF.md, PR 26)."""
+
+    @staticmethod
+    def _loop_at_depth(depth, call, n=20000):
+        import time
+
+        def leaf(a, b):
+            return a
+
+        def hot():
+            t0 = time.perf_counter()
+            for _ in range(n):
+                leaf(1, 2)
+            return time.perf_counter() - t0
+
+        def rec(d):
+            return call(hot) if d == 0 else rec(d - 1)
+
+        return rec(depth)
+
+    def test_a_loop_on_a_chunk_boundary_is_spared(self):
+        plain = sorted((self._loop_at_depth(d, lambda f: f()), d)
+                       for d in range(300))
+        median = plain[len(plain) // 2][0]
+        worst_s, worst_depth = plain[-1]
+        if worst_s < 20 * median:
+            pytest.skip("this interpreter shows no chunk-boundary "
+                        "cliff to be spared")
+        roomy = self._loop_at_depth(worst_depth, ej._with_frame_room)
+        assert roomy < worst_s / 10, (worst_depth, worst_s, roomy)
+
+    def test_only_a_cold_shape_is_called_with_room(self, monkeypatch):
+        roomy, direct = [], []
+        monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
+        monkeypatch.setattr(
+            ej, "_jit_verify_packed",
+            lambda dw: direct.append(dw.shape) or
+            jnp.ones(dw.shape[0], dtype=bool))
+        real = ej._with_frame_room
+        monkeypatch.setattr(
+            ej, "_with_frame_room",
+            lambda fn, *a, **kw: roomy.append(a[0].shape) or
+            real(fn, *a, **kw))
+        monkeypatch.setattr(ej, "_SEEN_SHAPES", set())
+        items = [_sig()] * 3
+        for _ in range(3):
+            ok, _ = ej.verify_batch(items)
+            assert ok
+        assert roomy == [(64, ej.WIRE_LANE_BYTES)]
+        assert direct == [(64, ej.WIRE_LANE_BYTES)] * 3
+
+    def test_passes_arguments_and_result_through(self):
+        assert ej._with_frame_room(
+            lambda a, b=0, *, c=0: (a, b, c), 1, 2, c=3) == (1, 2, 3)
+        with pytest.raises(ZeroDivisionError):
+            ej._with_frame_room(lambda: 1 // 0)
+
+
+class TestPackedEntryPoints:
+    pytestmark = pytest.mark.slow  # cold kernel compile (60-270s on 1 CPU)
+
+    """The jitted functions of one wire argument give the golden
+    model's verdicts, lane for lane."""
+
+    @pytest.mark.parametrize("kernel", ["xla", "pallas"])
+    def test_forged_non_canonical_and_short_key(self, kernel):
+        import jax
+        items = _wire_items()[:8]
+        # 3: forged R, 4: non-canonical S, 6: short key
+        golden = [len(p) == 32 and ref.verify(p, m, s)
+                  for p, m, s in items]
+        assert golden == [True, True, True, False, False, True,
+                          False, True]
+        wire, pre_bad = ej.prep_arrays(items, 8)
+        dw = jax.device_put(wire)
+        if kernel == "xla":
+            ok = ej._jit_verify_packed(dw)
+        else:
+            ok = ej._pallas_verify_packed(dw, kernel="pallas",
+                                          interpret=True, block=8)
+        ok = np.asarray(ok)
+        # refused lanes run as padding lanes, which verify trivially;
+        # the host's pre_bad is what fails them
+        assert ok[pre_bad].all()
+        assert (ok & ~pre_bad).tolist() == golden
+        # and the same through the dispatch every caller takes
+        mask = ej._dispatch(8, wire, pre_bad, kernel=kernel,
+                            interpret=kernel == "pallas",
+                            block=8 if kernel == "pallas" else 0)
+        assert mask.tolist() == golden
+
+
 class TestShardedTally:
     pytestmark = pytest.mark.slow  # cold kernel compile (60-270s on 1 CPU)
 
@@ -200,10 +490,9 @@ def _pallas_verify_items(items, block=8, kernel="pallas"):
     the emulated kernel stays tractable."""
     n = len(items)
     m = -(-n // block) * block
-    a_b, r_b, s_win, k_win, pre_bad = ej.prep_arrays(items, m)
-    return ej._dispatch(n, a_b, r_b, s_win, k_win, pre_bad,
-                        kernel=kernel, interpret=True,
-                        block=block).tolist()
+    wire, pre_bad = ej.prep_arrays(items, m)
+    return ej._dispatch(n, wire, pre_bad, kernel=kernel,
+                        interpret=True, block=block).tolist()
 
 
 class TestPallasKernel:

@@ -451,8 +451,8 @@ class TestTiledDispatchSpans:
         from cometbft_tpu.crypto import ed25519
         from cometbft_tpu.ops import ed25519_jax as ej
 
-        def stub(a8, r8, s8, k8):
-            return jnp.ones(a8.shape[0], dtype=bool)
+        def stub(wire):
+            return jnp.ones(wire.shape[0], dtype=bool)
 
         monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
         monkeypatch.setenv("COMETBFT_TPU_VERIFY_TILE", "64")

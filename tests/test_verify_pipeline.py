@@ -513,8 +513,8 @@ for i in range(130):
     items.append((ref.public_key(seed), m, ref.sign(seed, m)))
 pub, m, s = items[65]
 items[65] = (pub, m, s[:6] + bytes([s[6] ^ 1]) + s[7:])
-a_b, r_b, s_w8, k_w8, pre_bad = ej.prep_arrays(items, 130)
-ok = pmesh.verify_sharded(a_b, r_b, s_w8, k_w8, ndev=4)
+wire, pre_bad = ej.prep_arrays(items, 130)
+ok = pmesh.verify_sharded(*ej.wire_views(wire), ndev=4)
 assert not ok[65] and ok[:65].all() and ok[66:].all()
 ok2, mask = ej.verify_batch(items)       # tiled pipeline, sharded
 assert not ok2 and mask.count(False) == 1 and not mask[65]
@@ -557,8 +557,8 @@ class TestPallasUnderShardMap:
             items.append((ref.public_key(seed), msg, sig))
             golden.append(ref.verify(*items[-1]))
         assert golden.count(False) == 3
-        a_b, r_b, s_w8, k_w8, pre_bad = ej.prep_arrays(items, 16)
-        ok = pmesh.verify_sharded(a_b, r_b, s_w8, k_w8, ndev=2,
+        wire, pre_bad = ej.prep_arrays(items, 16)
+        ok = pmesh.verify_sharded(*ej.wire_views(wire), ndev=2,
                                   kernel="pallas", interpret=True,
                                   block=8)
         assert ok.tolist() == golden
@@ -567,8 +567,8 @@ class TestPallasUnderShardMap:
         monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1")
         part = ej._partitioner(16, "pallas", True, 8)
         assert part is not None and part.ndev == 8
-        mask = ej._dispatch(16, a_b, r_b, s_w8, k_w8, pre_bad,
-                            kernel="pallas", interpret=True, block=8)
+        mask = ej._dispatch(16, wire, pre_bad, kernel="pallas",
+                            interpret=True, block=8)
         assert mask.tolist() == golden
 
 
