@@ -286,8 +286,15 @@ class GuardedTpuBatchVerifier(BatchVerifier):
                                   batch=len(self._items),
                                   backend="tpu"):
                     from ..ops.ed25519_jax import verify_batch
-                    out = verify_batch([(pk.bytes(), m, s)
-                                        for pk, m, s in self._items])
+                    with tracing.span(tracing.CRYPTO, "item_handover"):
+                        raw = [(pk.bytes(), m, s)
+                               for pk, m, s in self._items]
+                    out = verify_batch(raw)
+                    # freeing 6,667 triples is 1 ms on a v5e's host
+                    # (PERF.md, PR 27): inside the seam's span, where
+                    # it was while the list was an argument only
+                    with tracing.span(tracing.CRYPTO, "item_release"):
+                        del raw
             except Exception as e:  # noqa: BLE001 — fall back below
                 latch = not _is_transient_kernel_error(e)
                 br.record_failure(latch=latch)

@@ -373,10 +373,6 @@ def _verify_packed(wire):
 
 
 _jit_verify_packed = jax.jit(_verify_packed)
-# the pipelined dispatch's TPU variant: a tile's wire buffer is never
-# reused, so donating it caps device memory at two in-flight tiles.
-# Separate executable cache key — TPU-only (see _launch).
-_jit_verify_packed_donated = jax.jit(_verify_packed, donate_argnums=0)
 
 
 @functools.partial(jax.jit,
@@ -434,7 +430,6 @@ def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
 
     n = len(items)
     choice = _kernel_choice()
-    donate = device.probe().is_tpu
     hist = _dispatch_histogram()
     out = np.zeros(n, bool)
     plan = tile_plan(n, tile)
@@ -480,7 +475,7 @@ def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
                            warm=warm, pipelined=True, tile=i).begin()
         with tracing.under(sp):
             dev = _launch(wire, choice=choice,
-                          part=_partitioner(m, choice), donate=donate)
+                          part=_partitioner(m, choice))
         _SEEN_SHAPES.add((choice, m))
         if inflight is not None:
             phase_s += settle(inflight, prep_inside=prep.seconds)
@@ -489,7 +484,8 @@ def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
     wall = (tracing.now_ns() - t_run0) / 1e9
     if wall > 0:
         overlap_histogram().observe(phase_s / wall)
-    return bool(out.all()), out.tolist()
+    with tracing.span(tracing.CRYPTO, "mask_handback"):
+        return bool(out.all()), out.tolist()
 
 
 def _with_frame_room(fn, *args, **kwargs):
@@ -515,7 +511,7 @@ _with_frame_room.__code__ = _with_frame_room.__code__.replace(
 
 
 def _launch(wire, *, choice: str, interpret: bool = False,
-            block: int = 0, part=None, donate: bool = False):
+            block: int = 0, part=None):
     """Dispatch the selected kernel on one wire buffer WITHOUT forcing
     the result: the un-forced device array comes back (JAX async
     dispatch), and the caller settles it with _force — the pipeline
@@ -526,11 +522,9 @@ def _launch(wire, *, choice: str, interpret: bool = False,
     A single-device dispatch is one non-blocking ``jax.device_put`` of
     the whole buffer and one jitted call of one argument: a transfer
     costs ~0.27 ms a call on a v5e whatever its size (PERF.md, PR 24
-    and 26).  ``donate`` (the pipelined dispatch on a TPU) runs the
-    xla kernel's donated-argument jit so each tile's buffer frees the
-    moment the kernel consumes it; donation changes the executable
-    cache key, so on CPU it would only force a second multi-minute XLA
-    compile of the same bucket."""
+    and 26).  No buffer is donated: a tile's wire is 786 KB at the
+    4,096 bucket, at most two tiles are in flight, and the device's
+    peak stayed 4.2 MB at 10,000 validators (PERF.md, PR 27)."""
     if part is not None:
         return part.dispatch(*wire_views(wire))
     with tracing.span(tracing.CRYPTO, "h2d"):
@@ -539,7 +533,7 @@ def _launch(wire, *, choice: str, interpret: bool = False,
         fn = functools.partial(_pallas_verify_packed, kernel=choice,
                                interpret=interpret, block=block)
     else:
-        fn = _jit_verify_packed_donated if donate else _jit_verify_packed
+        fn = _jit_verify_packed
     with tracing.span(tracing.CRYPTO, "launch"):
         if (choice, wire.shape[0]) in _SEEN_SHAPES:
             return fn(dw)
@@ -778,22 +772,20 @@ def warmup(n: int) -> None:
     tile = _bucket(tile_size())
     choice = _kernel_choice()
     if n <= tile:
-        _warmup_bucket(_padded(n, choice), False)
+        _warmup_bucket(_padded(n, choice))
         return
-    donate = device.probe().is_tpu
     for lo, hi in tile_plan(n, tile):
-        _warmup_bucket(_padded(hi - lo, choice), donate)
+        _warmup_bucket(_padded(hi - lo, choice))
 
 
 @functools.lru_cache(maxsize=None)
-def _warmup_bucket(m: int, donate: bool) -> None:
+def _warmup_bucket(m: int) -> None:
     choice = _kernel_choice()
     wire = _padding_wire(m)
     with tracing.span(tracing.CRYPTO, "kernel_compile", bucket=m,
                       kernel=choice) as sp:
         _force(_launch(wire, choice=choice,
-                       part=_partitioner(m, choice), donate=donate),
-               sp)
+                       part=_partitioner(m, choice)), sp)
     _SEEN_SHAPES.add((choice, m))
 
 

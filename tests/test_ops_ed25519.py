@@ -306,9 +306,7 @@ class TestOneTransferADispatch:
         # conftest's eight virtual devices would send 1,024 lanes to
         # the mesh partitioner: one chip is what a cell runs on
         monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1000000")
-        for name in ("_jit_verify_packed",
-                     "_jit_verify_packed_donated",
-                     "_pallas_verify_packed"):
+        for name in ("_jit_verify_packed", "_pallas_verify_packed"):
             monkeypatch.setattr(ej, name, stub)
         return puts, calls
 

@@ -457,7 +457,6 @@ class TestTiledDispatchSpans:
         monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
         monkeypatch.setenv("COMETBFT_TPU_VERIFY_TILE", "64")
         monkeypatch.setattr(ej, "_jit_verify_packed", stub)
-        monkeypatch.setattr(ej, "_jit_verify_packed_donated", stub)
         priv = ed25519.gen_priv_key_from_secret(b"tiles")
         pub = priv.pub_key().bytes()
         items = [(pub, b"m%d" % i, priv.sign(b"m%d" % i))
@@ -497,6 +496,56 @@ class TestTiledDispatchSpans:
                 nxt["ts_ns"] + nxt["dur_ns"]
             assert nxt["parent"] == seam.id
 
+    def test_the_seam_names_what_it_does_outside_the_tiles(
+            self, recorder, monkeypatch):
+        """Through the verifier the seam hands out: the items'
+        hand-over comes before the first tile's host_prep, the mask's
+        hand-back and the items' release after the last tile's
+        settle, all children of batch_verify (what
+        benchmark/layers/seam_outside_tiles_ms reads is made of
+        them)."""
+        import jax.numpy as jnp
+
+        from cometbft_tpu.crypto import batch as crypto_batch
+        from cometbft_tpu.crypto import ed25519
+        from cometbft_tpu.ops import ed25519_jax as ej
+
+        monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
+        monkeypatch.setenv("COMETBFT_TPU_VERIFY_TILE", "64")
+        monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1000000")
+        monkeypatch.setattr(
+            ej, "_jit_verify_packed",
+            lambda wire: jnp.ones(wire.shape[0], dtype=bool))
+        monkeypatch.setattr(crypto_batch, "_backend", "tpu")
+        crypto_batch.reset_tpu_breaker()
+        priv = ed25519.gen_priv_key_from_secret(b"seam")
+        pub = priv.pub_key()
+        bv = crypto_batch.create_batch_verifier(pub)
+        for i in range(150):
+            bv.add(pub, b"m%d" % i, priv.sign(b"m%d" % i))
+        try:
+            ok, mask = bv.verify()
+        finally:
+            crypto_batch.reset_tpu_breaker()
+        assert ok and len(mask) == 150
+
+        events = tracing.snapshot()
+        (seam,) = [e for e in events if e["name"] == "batch_verify"]
+        assert seam["attrs"]["backend"] == "tpu"
+        kids = sorted(_children(events)[seam["id"]],
+                      key=lambda e: e["ts_ns"])
+        assert [e["name"] for e in kids] == [
+            "item_handover", "host_prep", "kernel_execute",
+            "host_prep", "kernel_execute", "host_prep",
+            "kernel_execute", "mask_handback", "item_release"]
+        ends = [e["ts_ns"] + e["dur_ns"] for e in kids]
+        assert ends[0] <= kids[1]["ts_ns"]
+        assert max(ends[:-2]) <= kids[-2]["ts_ns"]
+        assert ends[-2] <= kids[-1]["ts_ns"]
+        assert ends[-1] <= seam["ts_ns"] + seam["dur_ns"]
+        # a span where the time is, not one a signature
+        assert sum(1 for e in events if e["name"] in (
+            "item_handover", "mask_handback", "item_release")) == 3
 
     def test_read_back_is_bare_with_the_recorder_off(self, tmp_path,
                                                      monkeypatch):
