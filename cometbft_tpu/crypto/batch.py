@@ -13,13 +13,21 @@ TPU-native addition: a process-global backend selector, set with
   * "auto" — "tpu" when ops/device.py finds a TPU, else "cpu";
              resolved once per process, blocking, and logged.
 
+The device verifier does not wait for ``verify()``: the moment
+``add()`` has filled one pipeline tile (4,096 lanes) it dispatches
+that tile, on the adding thread, and the kernel runs while the caller
+goes on adding; ``verify()`` dispatches the remainder and settles
+(GuardedTpuBatchVerifier).  A batch below one tile is one dispatch
+from ``verify()``, as ever.
+
 Every verifier this module hands out also answers ``verify_async()``
 (keys.BatchVerifier): an awaitable verdict future whose work runs on
 the shared verification staging worker (crypto/pipeline.py) — the
 Traced/Guarded wrappers keep their synchronous semantics because the
-wrapped ``verify()`` is exactly what executes off-loop, and large
-ed25519 CPU batches additionally pipeline pad-bucket tiles inside it
-(overlapped host_prep / GIL-free kernel, per-tile reject bisection).
+wrapped ``verify()`` is exactly what executes off-loop (tiles that
+``add()`` launched are settled there), and large ed25519 CPU batches
+additionally pipeline pad-bucket tiles inside it (overlapped
+host_prep / GIL-free kernel, per-tile reject bisection).
 """
 from __future__ import annotations
 
@@ -31,6 +39,7 @@ from typing import Optional
 from ..libs import tracing
 from . import ed25519
 from .keys import BatchVerifier, PubKey
+from .pipeline import tile_size
 
 # ---------------------------------------------------------------------
 # metrics v2: batch-verify latency distribution, labeled by backend and
@@ -251,64 +260,169 @@ def _is_transient_kernel_error(e: BaseException) -> bool:
     return any(m in s for m in _TRANSIENT_MARKERS)
 
 
+_NEVER = float("inf")
+
+
 class GuardedTpuBatchVerifier(BatchVerifier):
-    """TPU batch verifier behind the process-global circuit breaker.
+    """TPU batch verifier behind the process-global circuit breaker,
+    which starts before its caller has finished adding.
+
+    add() appends and compares a length; when the items not yet
+    dispatched fill one pipeline tile (the pad bucket of
+    crypto/pipeline.tile_size, 4,096 lanes) and the breaker is closed,
+    exactly that many go to the device then and there
+    (ops/ed25519_jax.TilePipeline.feed), so the kernel runs while the
+    caller is still walking its commit.  verify() feeds the remainder
+    at the same shape and settles: a 10,000-validator commit waits
+    for one kernel, not two.  A batch that never fills a tile —
+    every validator set below 4,096 — is untouched: verify() makes
+    the one dispatch it always made.  What the verifier does depends
+    on the number of items added and on nothing else.
+
+    A verifier dropped with a tile in flight (the walk raised, the
+    tally fell short) leaves nothing behind: the device finishes a
+    kernel nobody reads, no span of it records, the breaker hears
+    nothing.  The breaker is asked for a probe only by verify(),
+    which always reports back; add() dispatches under a closed
+    breaker alone.
 
     verify() attempts the device kernel only while the breaker admits
-    it; a dispatch failure records against the breaker (latched open
-    for non-transient faults, so the failing kernel is attempted at
-    most once per process), is logged with its type and message, and
-    the SAME batch falls back to the CPU verifier — callers always
-    get a verdict."""
+    it; a dispatch failure, in add() or in verify(), records against
+    the breaker (latched open for non-transient faults, so the failing
+    kernel is attempted at most once per process), is logged with its
+    type and message, and the SAME batch, whole, falls back to the CPU
+    verifier — callers always get a verdict.
+
+    The batch_verify span opens when the verifier first does work on
+    the device path — the first tile fed from add(), else verify() —
+    and ends with the verdict; crypto_batch_verify_seconds observes
+    the same two clock readings.  A span opened from add() has the
+    span that was open when the verifier was made as its parent
+    (commit_verify), not the walk it overlaps."""
 
     def __init__(self, breaker=None):
         self._breaker = breaker if breaker is not None else tpu_breaker()
         self._items: list[tuple[PubKey, bytes, bytes]] = []
+        self._tile = pad_bucket(tile_size())
+        self._feed_at = self._tile  # len(_items) at which add() feeds
+        self._fed = 0               # items handed to the pipeline
+        self._pipe = None           # ops TilePipeline, once a tile is fed
+        self._span = None           # batch_verify, once begun
+        self._eager_failed = False
+        self._creator = tracing.current()
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         if pub_key.type() != ed25519.KEY_TYPE:
             raise TypeError("GuardedTpuBatchVerifier requires ed25519 keys")
         if len(sig) != 64:
             raise ValueError("malformed signature")
-        self._items.append((pub_key, bytes(msg), bytes(sig)))
+        items = self._items
+        items.append((pub_key, bytes(msg), bytes(sig)))
+        if len(items) >= self._feed_at:
+            self._feed_eagerly()
 
     def __len__(self) -> int:
         return len(self._items)
 
+    def _feed_eagerly(self) -> None:
+        """One tile's worth of items is waiting: dispatch it, unless
+        the breaker is anything but closed (then verify() decides,
+        with the whole batch).  A failure is verify()'s failure, met
+        early: recorded here, and the whole batch goes to the CPU
+        verifier."""
+        from ..libs.breaker import CLOSED
+        if self._breaker.state != CLOSED:
+            self._feed_at = _NEVER
+            return
+        try:
+            self._feed(eager=True)
+        except Exception as e:  # noqa: BLE001 — verify() falls back
+            self._eager_failed = True
+            self._device_failed(e)
+        else:
+            self._feed_at += self._tile
+
+    def _feed(self, eager: bool = False) -> None:
+        """Hand the next (at most) tile of items to the pipeline."""
+        if self._pipe is None:
+            with tracing.under(self._creator):
+                self._begin_span()
+            from ..ops.ed25519_jax import TilePipeline
+            self._pipe = TilePipeline(self._tile)
+        lo, self._fed = self._fed, min(self._fed + self._tile,
+                                       len(self._items))
+        self._hand_over(lo, self._fed,
+                        lambda raw: self._pipe.feed(raw, eager=eager))
+
+    def _hand_over(self, lo: int, hi: int, run):
+        """run(items[lo:hi] as raw triples), under the seam's span.
+        The triples are freed here, with an explicit ``del``, so that
+        their release (1 ms for 6,667 on a v5e's host: PERF.md, PR 27)
+        lies inside the span, and for a tile inside its flight."""
+        with tracing.under(self._span):
+            with tracing.span(tracing.CRYPTO, "item_handover"):
+                raw = [(pk.bytes(), m, s)
+                       for pk, m, s in self._items[lo:hi]]
+            out = run(raw)
+            with tracing.span(tracing.CRYPTO, "item_release"):
+                del raw
+        return out
+
+    def _begin_span(self) -> None:
+        self._span = tracing.timed(tracing.CRYPTO, "batch_verify",
+                                   backend="tpu").begin()
+
+    def _end_span(self, **attrs) -> None:
+        self._span.note(batch=len(self._items), **attrs)
+        self._span.end()
+
+    def _drop_pipeline(self) -> None:
+        """Forget what add() dispatched; whatever runs next takes the
+        whole batch and add() feeds no more."""
+        self._pipe = self._span = None
+        self._fed = 0
+        self._feed_at = _NEVER
+
+    def _device_failed(self, e: BaseException) -> None:
+        br = self._breaker
+        latch = not _is_transient_kernel_error(e)
+        br.record_failure(latch=latch)
+        from ..libs.log import new_logger
+        new_logger("crypto").error(
+            "TPU batch verify failed; falling back to the CPU "
+            "verifier", error=f"{type(e).__name__}: {e}",
+            batch=len(self._items), breaker=br.state,
+            latched=latch, exc_info=True)
+        self._end_span(error=type(e).__name__)
+        self._drop_pipeline()
+
+    def _verify_on_device(self):
+        if self._pipe is not None:
+            while self._fed < len(self._items):
+                self._feed()
+            with tracing.under(self._span):
+                return self._pipe.finish()
+        self._begin_span()
+        from ..ops.ed25519_jax import verify_batch
+        return self._hand_over(0, len(self._items), verify_batch)
+
     def verify(self):
         br = self._breaker
-        attempted_tpu = False
-        if br.allow():
+        attempted_tpu = self._eager_failed
+        if not attempted_tpu and br.allow():
             attempted_tpu = True
-            t0 = time.perf_counter()
             try:
-                with tracing.span(tracing.CRYPTO, "batch_verify",
-                                  batch=len(self._items),
-                                  backend="tpu"):
-                    from ..ops.ed25519_jax import verify_batch
-                    with tracing.span(tracing.CRYPTO, "item_handover"):
-                        raw = [(pk.bytes(), m, s)
-                               for pk, m, s in self._items]
-                    out = verify_batch(raw)
-                    # freeing 6,667 triples is 1 ms on a v5e's host
-                    # (PERF.md, PR 27): inside the seam's span, where
-                    # it was while the list was an argument only
-                    with tracing.span(tracing.CRYPTO, "item_release"):
-                        del raw
+                out = self._verify_on_device()
             except Exception as e:  # noqa: BLE001 — fall back below
-                latch = not _is_transient_kernel_error(e)
-                br.record_failure(latch=latch)
-                from ..libs.log import new_logger
-                new_logger("crypto").error(
-                    "TPU batch verify failed; falling back to the CPU "
-                    "verifier", error=f"{type(e).__name__}: {e}",
-                    batch=len(self._items), breaker=br.state,
-                    latched=latch, exc_info=True)
+                self._device_failed(e)
             else:
                 br.record_success()
+                self._end_span()
                 _observe_verify("tpu", len(self._items),
-                                time.perf_counter() - t0)
+                                self._span.seconds)
+                self._drop_pipeline()
                 return out
+        self._drop_pipeline()
         t0 = time.perf_counter()
         with tracing.span(tracing.CRYPTO, "batch_verify",
                           batch=len(self._items), backend="cpu",

@@ -408,6 +408,14 @@ def under(sp):
     return _Under(sp)
 
 
+def current():
+    """The innermost span open in this context, or None: what a span
+    begun later, once the block that is open then has closed, names
+    as its parent through :func:`under`."""
+    p = _CURRENT.get()
+    return None if p is None or p.closed else p
+
+
 def instant(category: str, name: str, height: int = 0,
             **attrs) -> None:
     """Record a zero-duration point event."""

@@ -393,11 +393,14 @@ def verify_batch(
     """Verify [(pub, msg, sig), ...] on the default JAX device.
 
     Batches above one pipeline tile (crypto/pipeline.tile_size,
-    default 4096 — a pad-bucket shape) run as an overlapped tile
-    pipeline: while tile i executes under JAX's async dispatch, the
-    host preps tile i+1 (decompress staging, sign-bytes packing,
-    padding), so host work stops serializing with the kernel.  Smaller
-    batches keep the monolithic single-bucket dispatch.
+    default 4096 — a pad-bucket shape) are fed to a TilePipeline in
+    the balanced chunks of crypto/pipeline.tile_plan (10,000 as three
+    ~3,334-lane tiles), all at the tile's bucket: while tile i
+    executes under JAX's async dispatch, the host preps tile i+1.
+    Smaller batches keep the monolithic single-bucket dispatch.  A
+    caller that has its items one at a time does not wait for the
+    whole list: crypto/batch.GuardedTpuBatchVerifier feeds the same
+    pipeline a full tile at a time from ``add()``.
 
     Returns (all_valid, per_sig_mask) — the reference BatchVerifier.Verify
     contract (crypto/crypto.go:47).
@@ -405,19 +408,35 @@ def verify_batch(
     n = len(items)
     if n == 0:
         return True, []
-    from ..crypto.pipeline import tile_size
+    from ..crypto.pipeline import tile_plan, tile_size
     tile = _bucket(tile_size())
     if n <= tile:
         out = np.zeros(n, bool)
         out[:] = _verify_chunk(items)
         return bool(out.all()), out.tolist()
-    return _verify_pipelined(items, tile)
+    pipe = TilePipeline(tile)
+    for lo, hi in tile_plan(n, tile):
+        pipe.feed(items[lo:hi])
+    return pipe.finish()
 
 
-def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
-    """Tiled, overlapped dispatch: host_prep of tile i+1 runs while
-    tile i's kernel executes (JAX async dispatch — the jitted call
-    returns a device future; _force at settle time blocks).
+class TilePipeline:
+    """The tiled, overlapped dispatch, fed a tile at a time.
+
+    ``feed(chunk)`` preps the chunk (host_prep), launches it (JAX
+    async dispatch — the jitted call returns a device future, and the
+    mask's copy to the host is queued behind the kernel at once) and
+    only then settles the tile launched before it: at most two tiles
+    are in flight, and the host_prep of tile i+1 runs while tile i's
+    kernel executes.  ``finish()`` settles what is left and hands
+    back (all_valid, mask) in feed order.  The one implementation of
+    prep, launch, settle, pre_bad and mask assembly above one tile:
+    verify_batch feeds it a planned list, the seam's verifier feeds
+    it from ``add()`` while its caller is still walking (``eager``),
+    so that a tile which finished meanwhile settles without a wait.
+
+    Every chunk, the last and shortest too, dispatches at the ONE
+    shape of the tile's bucket: warmup() warms exactly that.
     Multi-chip meshes pre-partition ONCE per process
     (parallel/mesh.pipeline_partitioner) so per-tile dispatch pays no
     mesh/sharding re-resolution.
@@ -425,67 +444,86 @@ def _verify_pipelined(items, tile: int) -> tuple[bool, list[bool]]:
     A tile's kernel_execute span runs from the instant before its
     _launch to the end of its settle (h2d and launch at its start,
     device_wait and d2h at its end); the next tile's host_prep is a
-    sibling that overlaps it — the overlap the pipeline exists for."""
-    from ..crypto.pipeline import overlap_histogram, tile_plan
+    sibling that overlaps it — the overlap the pipeline exists for.
+    A span records when it ends: a pipeline dropped with a tile in
+    flight (its caller raised first) records nothing for that tile
+    and raises nothing later."""
 
-    n = len(items)
-    choice = _kernel_choice()
-    hist = _dispatch_histogram()
-    out = np.zeros(n, bool)
-    plan = tile_plan(n, tile)
-    t_run0 = tracing.now_ns()
-    phase_s = 0.0
-    inflight = None         # (lo, hi, m, warm, pre_bad, dev, span)
+    def __init__(self, tile: int):
+        self._choice = _kernel_choice()
+        self._m = _padded(tile, self._choice)
+        self._hist = _dispatch_histogram()
+        self._masks: list[np.ndarray] = []
+        self._inflight = None       # (n, warm, pre_bad, dev, span)
+        self._tiles = 0
+        self._t_run0 = tracing.now_ns()
+        self._phase_s = 0.0
 
-    def settle(inflight, prep_inside: float):
-        lo, hi, m, warm, pre_bad, dev, sp = inflight
+    def feed(self, chunk, eager: bool = False) -> None:
+        """Dispatch one chunk of at most a tile of (pub, msg, sig)
+        items.  ``eager`` marks a tile fed before its batch was
+        complete (the span's attribute, which
+        benchmark/layers/eager_tiles_per_commit counts)."""
+        choice, m, n = self._choice, self._m, len(chunk)
+        warm = (choice, m) in _SEEN_SHAPES
+        with tracing.timed(tracing.CRYPTO, "host_prep", batch=n,
+                           bucket=m, pipelined=True) as prep:
+            wire, pre_bad = prep_arrays(chunk, m)
+        pad_bucket = str(m)
+        self._hist.with_labels("host_prep", choice, pad_bucket,
+                               "1" if warm else "0").observe(prep.seconds)
+        self._phase_s += prep.seconds
+        attrs = {"eager": True} if eager else {}
+        sp = tracing.timed(tracing.CRYPTO, "kernel_execute", batch=n,
+                           bucket=m, kernel=choice, warm=warm,
+                           pipelined=True, tile=self._tiles,
+                           **attrs).begin()
+        with tracing.under(sp):
+            dev = _launch(wire, choice=choice,
+                          part=_partitioner(m, choice))
+        dev.copy_to_host_async()
+        _SEEN_SHAPES.add((choice, m))
+        self._tiles += 1
+        if self._inflight is not None:
+            self._settle(prep_inside=prep.seconds)
+        self._inflight = (n, warm, pre_bad, dev, sp)
+
+    def _settle(self, prep_inside: float) -> None:
+        n, warm, pre_bad, dev, sp = self._inflight
+        self._inflight = None
         with tracing.under(sp):
             ok = _force(dev, sp)
         sp.end()
         # dispatch -> settled: the window the device (or the XLA
         # runtime thread) owned the tile, i.e. what host_prep of the
         # NEXT tile overlapped with
-        pad_bucket = str(m)
-        hist.with_labels("kernel_execute", choice, pad_bucket,
-                         "1" if warm else "0").observe(sp.seconds)
-        ok = ok[:hi - lo].copy()
-        ok[pre_bad[:hi - lo]] = False
-        out[lo:hi] = ok
+        choice, pad_bucket = self._choice, str(self._m)
+        self._hist.with_labels("kernel_execute", choice, pad_bucket,
+                               "1" if warm else "0").observe(sp.seconds)
+        ok = ok[:n].copy()
+        ok[pre_bad[:n]] = False
+        self._masks.append(ok)
         # the overlap-ratio kernel phase subtracts the NEXT tile's
         # host_prep, which by construction sits inside this envelope
         # (stage(i+1) runs between dispatch(i) and settle(i)) — else
         # a pipeline whose device did nothing until the force would
         # still read ~2.0 "overlap"; what remains above the contained
         # prep is execution the async dispatch genuinely hid
-        return max(0.0, sp.seconds - prep_inside)
+        self._phase_s += max(0.0, sp.seconds - prep_inside)
 
-    for i, (lo, hi) in enumerate(plan):
-        chunk = items[lo:hi]
-        m = _padded(hi - lo, choice)
-        warm = (choice, m) in _SEEN_SHAPES
-        with tracing.timed(tracing.CRYPTO, "host_prep", batch=hi - lo,
-                           bucket=m, pipelined=True) as prep:
-            wire, pre_bad = prep_arrays(chunk, m)
-        pad_bucket = str(m)
-        hist.with_labels("host_prep", choice, pad_bucket,
-                         "1" if warm else "0").observe(prep.seconds)
-        phase_s += prep.seconds
-        sp = tracing.timed(tracing.CRYPTO, "kernel_execute",
-                           batch=hi - lo, bucket=m, kernel=choice,
-                           warm=warm, pipelined=True, tile=i).begin()
-        with tracing.under(sp):
-            dev = _launch(wire, choice=choice,
-                          part=_partitioner(m, choice))
-        _SEEN_SHAPES.add((choice, m))
-        if inflight is not None:
-            phase_s += settle(inflight, prep_inside=prep.seconds)
-        inflight = (lo, hi, m, warm, pre_bad, dev, sp)
-    phase_s += settle(inflight, prep_inside=0.0)
-    wall = (tracing.now_ns() - t_run0) / 1e9
-    if wall > 0:
-        overlap_histogram().observe(phase_s / wall)
-    with tracing.span(tracing.CRYPTO, "mask_handback"):
-        return bool(out.all()), out.tolist()
+    def finish(self) -> tuple[bool, list[bool]]:
+        """Settle the last tile; (all_valid, mask) over everything
+        fed, in feed order."""
+        from ..crypto.pipeline import overlap_histogram
+        if self._inflight is not None:
+            self._settle(prep_inside=0.0)
+        wall = (tracing.now_ns() - self._t_run0) / 1e9
+        if wall > 0:
+            overlap_histogram().observe(self._phase_s / wall)
+        with tracing.span(tracing.CRYPTO, "mask_handback"):
+            out = np.concatenate(self._masks) if self._masks \
+                else np.zeros(0, bool)
+            return bool(out.all()), out.tolist()
 
 
 def _with_frame_room(fn, *args, **kwargs):
@@ -765,17 +803,13 @@ def _dispatch(n: int, wire, pre_bad, *, kernel: str = "",
 
 
 def warmup(n: int) -> None:
-    """Pre-compile every shape verify_batch dispatches at for a batch
-    of n signatures: the bucket covering n, or the tile buckets of the
-    pipelined plan above one tile."""
-    from ..crypto.pipeline import tile_plan, tile_size
+    """Pre-compile the shape a batch of n signatures dispatches at:
+    the bucket covering n, or above one tile the tile's bucket — the
+    one shape of every TilePipeline chunk, planned by verify_batch
+    or fed from the seam's ``add()`` (6,667 signatures: 4,096)."""
+    from ..crypto.pipeline import tile_size
     tile = _bucket(tile_size())
-    choice = _kernel_choice()
-    if n <= tile:
-        _warmup_bucket(_padded(n, choice))
-        return
-    for lo, hi in tile_plan(n, tile):
-        _warmup_bucket(_padded(hi - lo, choice))
+    _warmup_bucket(_padded(min(n, tile), _kernel_choice()))
 
 
 @functools.lru_cache(maxsize=None)

@@ -1,15 +1,21 @@
 """Commit verification through the TILED device path, verdict by
 verdict, with a golden kernel at the wire (tests/wire_golden.py) in
-place of the compiled ones: walk -> BatchVerifier seam -> verify_batch
--> _verify_pipelined (prep_arrays, tiles, pre_bad, mask assembly) ->
-the index a refusal names.
+place of the compiled ones: walk -> BatchVerifier seam, whose add()
+feeds a full tile to ops TilePipeline the moment it has one ->
+prep_arrays, tiles, pre_bad, mask assembly -> the index a refusal
+names.
 
 verify_commit_light is held to the plain reference of configuration
 valset-10k (benchmark/reference/commit_light.py), verify_commit to its
 strict twin below, on sets of 150 and 200 validators at a 64-lane
-tile (two to four tiles a commit).  One more test pins the plan at
-the real size, 10,000 validators, without a kernel.
+tile (two to four tiles a commit), and at the edges of the streamed
+path: a batch of exactly one tile, one more, exactly two; a verifier
+dropped with a tile in flight; a kernel that fails under add(); an
+open breaker; verify_async().  One more test pins the plan at the
+real size, 10,000 validators, without a kernel.
 """
+import asyncio
+import dataclasses
 import functools
 import os
 import sys
@@ -28,8 +34,10 @@ from benchmark.reference import commit_light, fixtures  # noqa: E402
 from cometbft_tpu.crypto import _ed25519_ref as ref  # noqa: E402
 from cometbft_tpu.crypto import batch as crypto_batch  # noqa: E402
 from cometbft_tpu.crypto.pipeline import tile_plan  # noqa: E402
+from cometbft_tpu.libs import tracing  # noqa: E402
 from cometbft_tpu.ops import ed25519_jax as ej  # noqa: E402
 from cometbft_tpu.types import validation  # noqa: E402
+from cometbft_tpu.types.commit import Commit, CommitSig  # noqa: E402
 from cometbft_tpu.types.validator_set import (  # noqa: E402
     Validator, ValidatorSet,
 )
@@ -189,6 +197,227 @@ def test_verdict_is_the_references(device_path, n, powers, function,
     assert device_path == [TILE] * len(plan)
 
 
+def verdict(function, vset, bid, height, commit):
+    """(verdict, index or tally) as commit_light's references give."""
+    try:
+        getattr(validation, function)(CHAIN_ID, vset, bid, height,
+                                      commit)
+        return commit_light.ACCEPTED, None
+    except validation.NotEnoughVotingPowerError as e:
+        return commit_light.NOT_ENOUGH_POWER, e.got
+    except validation.VerificationError as e:
+        head = str(e).split(":")[0]
+        assert head.startswith("wrong signature (#"), str(e)
+        return (commit_light.WRONG_SIGNATURE,
+                int(head[len("wrong signature (#"):-1]))
+
+
+def variant(n: int, changed=None):
+    """(vset, block id, height, a fresh copy of signed_set(n)'s honest
+    commit with the CommitSigs of ``changed`` {index: f(CommitSig)}
+    replaced)."""
+    vset, bid, height, sigs, commit = signed_set(n, "equal")
+    css = [dataclasses.replace(cs, signature=sig)
+           for cs, sig in zip(commit.signatures, sigs)]
+    for i, change in (changed or {}).items():
+        css[i] = change(css[i])
+    return vset, bid, height, Commit(
+        height=commit.height, round=commit.round,
+        block_id=commit.block_id, signatures=css)
+
+
+def resigned(how):
+    return lambda cs: dataclasses.replace(cs,
+                                          signature=how(cs.signature))
+
+
+@pytest.fixture
+def recorder(tmp_path):
+    old = tracing.set_recorder(
+        tracing.Recorder(buffer_size=65536, dump_dir=str(tmp_path)))
+    yield tracing.recorder()
+    tracing.set_recorder(old)
+
+
+@pytest.fixture
+def adds(monkeypatch):
+    """Counts BatchVerifier.add calls: [n]."""
+    count = [0]
+    add = crypto_batch.GuardedTpuBatchVerifier.add
+
+    def counted(self, *item):
+        count[0] += 1
+        return add(self, *item)
+
+    monkeypatch.setattr(crypto_batch.GuardedTpuBatchVerifier, "add",
+                        counted)
+    return count
+
+
+@pytest.mark.parametrize("spoiled", [None, 0, -1])
+@pytest.mark.parametrize("n,tiles", [(TILE, 1), (TILE + 1, 2),
+                                     (2 * TILE, 2)])
+def test_a_batch_at_the_edge_of_a_tile(device_path, n, tiles, spoiled):
+    """Exactly one tile (fed by the last add(), nothing left for
+    verify() but the settle), one lane more (a remainder of one, at
+    the tile's shape), exactly two tiles (both fed from add(), empty
+    remainder)."""
+    at = None if spoiled is None else spoiled % n
+    vset, bid, height, commit = variant(
+        n, {} if at is None else {at: resigned(forged)})
+    assert verdict("verify_commit", vset, bid, height, commit) == (
+        (commit_light.ACCEPTED, None) if at is None
+        else (commit_light.WRONG_SIGNATURE, at))
+    assert device_path == [TILE] * tiles
+
+
+def test_the_first_tile_is_on_its_way_before_the_walk_ends(
+        device_path, adds, monkeypatch):
+    """150 validators of equal power, light: 101 signatures go as two
+    dispatches of 64 lanes, the first made by the 64th add() of 101;
+    never another shape."""
+    seen = []
+    golden = ej._jit_verify_packed
+    for name in KERNELS:
+        monkeypatch.setattr(
+            ej, name, lambda wire, **static:
+            seen.append(adds[0]) or golden(wire, **static))
+    vset, bid, height, commit = variant(150)
+    assert light_stop(vset) == 101
+    assert verdict("verify_commit_light", vset, bid, height,
+                   commit) == (commit_light.ACCEPTED, None)
+    assert device_path == [TILE, TILE]
+    assert seen == [TILE, 101] and adds[0] == 101
+
+
+def test_a_short_tally_drops_the_tile_in_flight(device_path, recorder):
+    """60 of 150 absent: the walk adds 90 signatures (a tile goes out
+    at the 64th), then the tally is short.  NotEnoughVotingPowerError
+    comes before any mask is read; of the dropped tile no span
+    records and the breaker hears nothing; the next verification, on
+    a verifier of its own, is right."""
+    absent = {i: lambda cs: CommitSig.absent() for i in range(90, 150)}
+    vset, bid, height, commit = variant(150, absent)
+    assert verdict("verify_commit", vset, bid, height, commit) == (
+        commit_light.NOT_ENOUGH_POWER, 900)
+    assert device_path == [TILE]
+    names = {e["name"] for e in tracing.snapshot()}
+    assert "commit_walk" in names and "host_prep" in names
+    assert not names & {"batch_verify", "kernel_execute",
+                        "device_wait", "d2h", "mask_handback"}
+    br = crypto_batch.tpu_breaker()
+    assert br.state == "closed" and br._failures == 0
+
+    vset, bid, height, commit = variant(150, {70: resigned(forged)})
+    assert verdict("verify_commit", vset, bid, height, commit) == (
+        commit_light.WRONG_SIGNATURE, 70)
+    assert device_path == [TILE] * 4
+    (seam,) = [e for e in tracing.snapshot()
+               if e["name"] == "batch_verify"]
+    assert seam["attrs"] == {"backend": "tpu", "batch": 150}
+
+
+def test_a_walk_that_raises_after_a_tile_was_fed(device_path, recorder):
+    """A signature of 63 bytes at index 100: add() refuses it, the
+    walk raises as it always did, the tile fed at the 64th add is
+    dropped without a trace."""
+    vset, bid, height, commit = variant(
+        150, {100: resigned(lambda sig: sig[:63])})
+    assert verdict("verify_commit", vset, bid, height, commit) == (
+        commit_light.WRONG_SIGNATURE, 100)
+    assert device_path == [TILE]
+    assert not {e["name"] for e in tracing.snapshot()} & {
+        "batch_verify", "kernel_execute"}
+    assert crypto_batch.tpu_breaker().state == "closed"
+    vset, bid, height, commit = variant(150)
+    assert verdict("verify_commit", vset, bid, height, commit) == (
+        commit_light.ACCEPTED, None)
+
+
+@pytest.mark.parametrize("explodes_at", [0, 1, 2])
+def test_a_kernel_that_raises_sends_the_whole_batch_to_the_cpu(
+        device_path, recorder, monkeypatch, crypto_log, explodes_at):
+    """The kernel fails under the first add()-fed tile, the second,
+    or the remainder verify() feeds: each time the failure is
+    recorded once against the breaker (latched: no transient shape),
+    logged, and the WHOLE batch of 150 is judged by the CPU verifier
+    with fallback=True: same verdict, same index."""
+    golden = ej._jit_verify_packed
+    calls = []
+
+    def exploding(wire, **static):
+        calls.append(wire.shape[0])
+        if len(calls) == explodes_at + 1:
+            raise RuntimeError("Mosaic lowering failed on this tile")
+        return golden(wire, **static)
+
+    for name in KERNELS:
+        monkeypatch.setattr(ej, name, exploding)
+    vset, bid, height, commit = variant(150, {140: resigned(forged)})
+    try:
+        assert verdict("verify_commit", vset, bid, height, commit) \
+            == (commit_light.WRONG_SIGNATURE, 140)
+        assert len(calls) == explodes_at + 1
+        assert crypto_batch.tpu_breaker().state == "latched_open"
+        seams = [e["attrs"] for e in tracing.snapshot()
+                 if e["name"] == "batch_verify"]
+        # the device's span ends where it failed, with what had
+        # been added by then
+        assert seams == [
+            {"backend": "tpu", "batch": (TILE, 2 * TILE, 150)[
+                explodes_at], "error": "RuntimeError"},
+            {"backend": "cpu", "batch": 150, "fallback": True}]
+        errors = [r for r in crypto_log if r.levelname == "ERROR"]
+        assert len(errors) == 1
+        assert "Mosaic lowering failed" in errors[0].getMessage()
+        # the breaker is open: the next batch dispatches nothing
+        vset, bid, height, commit = variant(150)
+        assert verdict("verify_commit", vset, bid, height, commit) \
+            == (commit_light.ACCEPTED, None)
+        assert len(calls) == explodes_at + 1
+    finally:
+        crypto_batch.reset_tpu_breaker()
+
+
+def test_an_open_breaker_means_no_dispatch_from_add(device_path,
+                                                    recorder):
+    crypto_batch.tpu_breaker().record_failure(latch=True)
+    try:
+        vset, bid, height, commit = variant(150, {5: resigned(forged)})
+        assert verdict("verify_commit", vset, bid, height, commit) == (
+            commit_light.WRONG_SIGNATURE, 5)
+        assert device_path == []
+        (seam,) = [e["attrs"] for e in tracing.snapshot()
+                   if e["name"] == "batch_verify"]
+        assert seam == {"backend": "cpu", "batch": 150,
+                        "fallback": False}
+    finally:
+        crypto_batch.reset_tpu_breaker()
+
+
+def test_verify_async_settles_what_add_dispatched(device_path):
+    """add() on the event loop's thread feeds two tiles; the staging
+    worker that runs verify() feeds the third and settles all."""
+    from cometbft_tpu.crypto import pipeline
+    vset, _, _, commit = variant(150, {130: resigned(forged)})
+
+    async def go():
+        bv = crypto_batch.create_batch_verifier(
+            vset.validators[0].pub_key)
+        for i, v in enumerate(vset.validators):
+            bv.add(v.pub_key, commit.vote_sign_bytes(CHAIN_ID, i),
+                   commit.signatures[i].signature)
+        assert device_path == [TILE, TILE]
+        return await bv.verify_async()
+
+    try:
+        ok, mask = asyncio.run(go())
+    finally:
+        pipeline.reset_workers()
+    assert not ok and mask == [i != 130 for i in range(150)]
+    assert device_path == [TILE] * 3
+
+
 def test_the_reference_judges_power_before_signatures():
     vset, _, _, sigs, commit = signed_set(150, "equal")
     lanes = [(v.pub_key.bytes(), commit.vote_sign_bytes(CHAIN_ID, i),
@@ -225,15 +454,22 @@ def test_golden_kernel_agrees_with_the_golden_model_on_edge_lanes():
 
 
 def test_the_plan_at_10000_validators(monkeypatch):
-    """valset-10k: a light verification takes 6,667 signatures, planned
-    as two balanced tiles that both pad to the 4,096 bucket, and
-    warm-up warms that one shape."""
+    """valset-10k: a light verification takes 6,667 signatures.  The
+    seam's verifier streams them as 4,096 (fed from add()) + 2,571
+    (fed by verify()); verify_batch, handed the whole list, plans two
+    balanced tiles.  Either way every chunk dispatches at the 4,096
+    bucket, the pipeline's one shape, and warm-up warms that one."""
     monkeypatch.delenv("COMETBFT_TPU_VERIFY_TILE", raising=False)
     monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1000000")
     assert 10000 * 10 * 2 // 3 // 10 + 1 == 6667
     plan = tile_plan(6667, 4096)
     assert plan == [(0, 3334), (3334, 6667)]
+    bv = crypto_batch.GuardedTpuBatchVerifier()
+    assert (bv._tile, bv._feed_at) == (4096, 4096)
+    assert 6667 - bv._tile == 2571
     for kernel in ("pallas", "xla"):
+        monkeypatch.setenv("COMETBFT_TPU_KERNEL", kernel)
+        assert ej.TilePipeline(bv._tile)._m == 4096
         assert [ej._padded(hi - lo, kernel) for lo, hi in plan] == \
             [4096, 4096]
     warmed = []

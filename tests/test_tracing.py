@@ -305,6 +305,34 @@ class TestCausality:
         assert ke["ts_ns"] + ke["dur_ns"] >= \
             ev["device_wait"]["ts_ns"] + ev["device_wait"]["dur_ns"]
 
+    def test_current_names_the_open_span_for_a_later_child(
+            self, recorder):
+        """A span begun after the block that was open at some
+        earlier moment has closed (the seam's batch_verify, begun
+        inside the walk) still names what was open then as its
+        parent: current() remembers it, under() lends it."""
+        assert tracing.current() is None
+        assert tracing.under(tracing.current()).__enter__() is not None
+        with tracing.span(tracing.CONSENSUS, "commit_verify",
+                          height=4) as request:
+            creator = tracing.current()
+            assert creator is request
+            with tracing.span(tracing.CONSENSUS, "commit_walk") as walk:
+                assert tracing.current() is walk
+                with tracing.under(creator):
+                    late = tracing.timed(tracing.CRYPTO,
+                                         "batch_verify").begin()
+                assert tracing.current() is walk
+            late.end()
+        assert tracing.current() is None
+        ev = {e["name"]: e for e in tracing.snapshot()}
+        assert ev["batch_verify"]["parent"] == request.id
+        assert ev["batch_verify"]["height"] == 4
+        assert ev["commit_walk"]["parent"] == request.id
+        # closed: no parent for anyone any more
+        with tracing.under(creator):
+            assert tracing.current() is None
+
 
 def _children(events):
     out = {}
@@ -496,14 +524,13 @@ class TestTiledDispatchSpans:
                 nxt["ts_ns"] + nxt["dur_ns"]
             assert nxt["parent"] == seam.id
 
-    def test_the_seam_names_what_it_does_outside_the_tiles(
-            self, recorder, monkeypatch):
-        """Through the verifier the seam hands out: the items'
-        hand-over comes before the first tile's host_prep, the mask's
-        hand-back and the items' release after the last tile's
-        settle, all children of batch_verify (what
-        benchmark/layers/seam_outside_tiles_ms reads is made of
-        them)."""
+    @pytest.fixture
+    def seam(self, recorder, monkeypatch):
+        """The seam's device path with a stubbed kernel at a 64-lane
+        tile: verifier(n) walks n adds under commit_verify /
+        commit_walk as types/validation does and returns the
+        verifier, the two spans still to be closed by the caller's
+        ``with``."""
         import jax.numpy as jnp
 
         from cometbft_tpu.crypto import batch as crypto_batch
@@ -520,32 +547,95 @@ class TestTiledDispatchSpans:
         crypto_batch.reset_tpu_breaker()
         priv = ed25519.gen_priv_key_from_secret(b"seam")
         pub = priv.pub_key()
-        bv = crypto_batch.create_batch_verifier(pub)
-        for i in range(150):
-            bv.add(pub, b"m%d" % i, priv.sign(b"m%d" % i))
-        try:
-            ok, mask = bv.verify()
-        finally:
-            crypto_batch.reset_tpu_breaker()
-        assert ok and len(mask) == 150
 
+        def verify(n):
+            with tracing.span(tracing.CONSENSUS, "commit_verify",
+                              height=9) as request:
+                bv = crypto_batch.create_batch_verifier(pub)
+                with tracing.span(tracing.CONSENSUS, "commit_walk"):
+                    for i in range(n):
+                        bv.add(pub, b"m%d" % i, priv.sign(b"m%d" % i))
+                ok, mask = bv.verify()
+            assert ok and len(mask) == n
+            return request
+
+        yield verify
+        crypto_batch.reset_tpu_breaker()
+
+    def test_a_streamed_batch_opens_its_span_at_the_first_feed(
+            self, seam):
+        """150 items at a 64-lane tile: add() feeds tiles 0 and 1
+        inside the walk, verify() the 22 left.  batch_verify opens at
+        the first feed, a child of the span open when the verifier
+        was made (commit_verify) and not of the walk it overlaps;
+        under it, a tile: item_handover, host_prep, kernel_execute
+        (eager on the fed tiles only), item_release inside the tile's
+        flight; mask_handback last (what
+        benchmark/layers/seam_outside_tiles_ms and
+        eager_tiles_per_commit read is made of them)."""
+        request = seam(150)
         events = tracing.snapshot()
-        (seam,) = [e for e in events if e["name"] == "batch_verify"]
-        assert seam["attrs"]["backend"] == "tpu"
-        kids = sorted(_children(events)[seam["id"]],
+        (bv,) = [e for e in events if e["name"] == "batch_verify"]
+        (walk,) = [e for e in events if e["name"] == "commit_walk"]
+        assert bv["parent"] == request.id == walk["parent"]
+        assert bv["height"] == 9
+        assert bv["attrs"] == {"backend": "tpu", "batch": 150}
+        walk_end = walk["ts_ns"] + walk["dur_ns"]
+        assert walk["ts_ns"] < bv["ts_ns"] < walk_end
+        assert bv["ts_ns"] + bv["dur_ns"] > walk_end
+        kids = sorted(_children(events)[bv["id"]],
                       key=lambda e: e["ts_ns"])
         assert [e["name"] for e in kids] == [
             "item_handover", "host_prep", "kernel_execute",
-            "host_prep", "kernel_execute", "host_prep",
-            "kernel_execute", "mask_handback", "item_release"]
+            "item_release"] * 3 + ["mask_handback"]
+        tiles = [e for e in kids if e["name"] == "kernel_execute"]
+        for i, t in enumerate(tiles):
+            a = t["attrs"]
+            assert a["pipelined"] is True and a["tile"] == i
+            assert a["bucket"] == 64 and "warm" in a
+            assert a["batch"] == (64, 64, 22)[i]
+            assert a.get("eager") == (True, True, None)[i]
+            # fed from the walk, or after it
+            assert (t["ts_ns"] < walk_end) == (i < 2)
+        # the first span of the batch is the first tile's hand-over,
+        # and each tile's items are freed while its kernel is out
+        assert kids[0]["ts_ns"] >= bv["ts_ns"]
+        for i in range(3):
+            hand, prep, tile, release = kids[4 * i:4 * i + 4]
+            assert hand["ts_ns"] + hand["dur_ns"] <= prep["ts_ns"]
+            assert prep["ts_ns"] + prep["dur_ns"] <= tile["ts_ns"]
+            assert tile["ts_ns"] < release["ts_ns"] < \
+                tile["ts_ns"] + tile["dur_ns"]
         ends = [e["ts_ns"] + e["dur_ns"] for e in kids]
-        assert ends[0] <= kids[1]["ts_ns"]
-        assert max(ends[:-2]) <= kids[-2]["ts_ns"]
-        assert ends[-2] <= kids[-1]["ts_ns"]
-        assert ends[-1] <= seam["ts_ns"] + seam["dur_ns"]
+        assert max(ends[:-1]) <= kids[-1]["ts_ns"]
+        assert ends[-1] <= bv["ts_ns"] + bv["dur_ns"]
         # a span where the time is, not one a signature
         assert sum(1 for e in events if e["name"] in (
-            "item_handover", "mask_handback", "item_release")) == 3
+            "item_handover", "mask_handback", "item_release")) == 7
+
+    def test_an_untiled_batch_keeps_its_tree(self, seam):
+        """Below one tile nothing is fed from add(): batch_verify
+        opens in verify(), after the walk, with the one dispatch's
+        spans as before."""
+        request = seam(60)
+        events = tracing.snapshot()
+        (bv,) = [e for e in events if e["name"] == "batch_verify"]
+        (walk,) = [e for e in events if e["name"] == "commit_walk"]
+        assert bv["parent"] == request.id
+        assert bv["attrs"] == {"backend": "tpu", "batch": 60}
+        assert walk["ts_ns"] + walk["dur_ns"] <= bv["ts_ns"]
+        kids = sorted(_children(events)[bv["id"]],
+                      key=lambda e: e["ts_ns"])
+        assert [e["name"] for e in kids] == [
+            "item_handover", "host_prep", "kernel_execute",
+            "item_release"]
+        assert "pipelined" not in kids[2]["attrs"]
+        assert "eager" not in kids[2]["attrs"]
+        assert [e["name"] for e in _children(events)[kids[2]["id"]]] \
+            == ["h2d", "launch", "device_wait", "d2h"]
+        ends = [e["ts_ns"] + e["dur_ns"] for e in kids]
+        assert all(a <= b["ts_ns"] for a, b in zip(ends, kids[1:]))
+        assert ends[-1] <= bv["ts_ns"] + bv["dur_ns"]
 
     def test_read_back_is_bare_with_the_recorder_off(self, tmp_path,
                                                      monkeypatch):
