@@ -39,42 +39,19 @@ from typing import Optional
 from ..libs import tracing
 from . import ed25519
 from .keys import BatchVerifier, PubKey
-from .pipeline import tile_size
+from .pipeline import bucket as pad_bucket, tile_bucket
 
 # ---------------------------------------------------------------------
 # metrics v2: batch-verify latency distribution, labeled by backend and
 # pad bucket.  Registered lazily on the process-global registry
 # (libs.metrics.DEFAULT) because verifiers are created deep in the
 # verification paths with no node context; the node's /metrics merges
-# DEFAULT in.  The pad buckets mirror ops/ed25519_jax._BUCKETS — the
-# power-of-two-ish shapes the kernel compiles once per — so CPU and
-# TPU observations of the same batch size share a label value.
-
-PAD_BUCKETS = (64, 1024, 4096, 10240, 16384)
+# DEFAULT in.  The label value is ``pad_bucket(n)``, which is
+# crypto/pipeline.bucket — the one ladder the device dispatch pads to —
+# so CPU and TPU observations of the same batch size share a label
+# value, refined buckets included.
 
 _VERIFY_HIST = None
-_PAD_BUCKET_FN = None
-
-
-def register_pad_bucket_fn(fn) -> None:
-    """ops/ed25519_jax registers its live _bucket on import so label
-    values track measured bucket refinement (the kernel ladder can
-    grow finer buckets at runtime; this module must not import the
-    jax stack at process start to find out)."""
-    global _PAD_BUCKET_FN
-    _PAD_BUCKET_FN = fn
-
-
-def pad_bucket(n: int) -> int:
-    """The padded lane count a batch of n signatures dispatches at
-    (mirrors ops/ed25519_jax._bucket; asserted equal in
-    tests/test_metrics_contract.py)."""
-    if _PAD_BUCKET_FN is not None:
-        return _PAD_BUCKET_FN(n)
-    for b in PAD_BUCKETS:
-        if n <= b:
-            return b
-    return PAD_BUCKETS[-1]
 
 
 def verify_seconds_histogram():
@@ -268,8 +245,8 @@ class GuardedTpuBatchVerifier(BatchVerifier):
     which starts before its caller has finished adding.
 
     add() appends and compares a length; when the items not yet
-    dispatched fill one pipeline tile (the pad bucket of
-    crypto/pipeline.tile_size, 4,096 lanes) and the breaker is closed,
+    dispatched fill one pipeline tile (crypto/pipeline.tile_bucket,
+    4,096 lanes) and the breaker is closed,
     exactly that many go to the device then and there
     (ops/ed25519_jax.TilePipeline.feed), so the kernel runs while the
     caller is still walking its commit.  verify() feeds the remainder
@@ -303,7 +280,7 @@ class GuardedTpuBatchVerifier(BatchVerifier):
     def __init__(self, breaker=None):
         self._breaker = breaker if breaker is not None else tpu_breaker()
         self._items: list[tuple[PubKey, bytes, bytes]] = []
-        self._tile = pad_bucket(tile_size())
+        self._tile = tile_bucket()
         self._feed_at = self._tile  # len(_items) at which add() feeds
         self._fed = 0               # items handed to the pipeline
         self._pipe = None           # ops TilePipeline, once a tile is fed

@@ -227,7 +227,7 @@ class CpuBatchVerifier(BatchVerifier):
                 try:
                     from . import pipeline
                     if not self._monolithic and \
-                            n > pipeline.tile_size():
+                            n > pipeline.TILE:
                         return pipeline.verify_items_pipelined(
                             native, raw, self._verify_one)
                     if self._batch_holds(native, raw):

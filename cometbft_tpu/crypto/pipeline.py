@@ -6,7 +6,7 @@ was 452 ms e2e against 116 ms device-only, and on the CPU backend a
 10k native batch blocks whatever thread dispatches it for ~170 ms.
 This module makes verification a pipeline instead of a blocking call:
 
-  * the batch splits into pad-bucket tiles (default 4096 lanes, the
+  * the batch splits into pad-bucket tiles (4096 lanes, the
     kernel ladder's mid bucket — small enough that one bad signature
     bisects inside its own tile, large enough that the Pippenger MSM
     keeps most of its batch efficiency);
@@ -41,7 +41,6 @@ concurrently).
 """
 from __future__ import annotations
 
-import os
 import secrets
 import struct
 import time
@@ -52,21 +51,36 @@ from ..libs.workers import SupervisedWorker
 from .keys import bisect_bad
 
 # ---------------------------------------------------------------------
-# tile geometry
+# the geometry of a dispatch: the pad-bucket ladder and the tile.  This
+# module is its one owner and imports no JAX, so that crypto/batch (the
+# seam) and ops/ed25519_jax (the device dispatch) both read it here and
+# a ``cpu`` node never loads the jax stack to label a histogram.
 
-_DEFAULT_TILE = 4096
+BASE_BUCKETS = (64, 1024, 4096, 10240, 16384)
+# THE live ladder, one list object for the life of the process: the
+# tuner (ops/ed25519_jax._tune_record) inserts refined buckets and
+# test hooks write it, always in place — nothing rebinds it
+BUCKETS = list(BASE_BUCKETS)
+# the pipeline tile in lanes: a pad-bucket shape, so CPU tiles and TPU
+# tiles label the same histogram buckets
+TILE = 4096
 
 
-def tile_size() -> int:
-    """Pipeline tile in lanes (COMETBFT_TPU_VERIFY_TILE overrides).
-    4096 is a pad-bucket shape (ops/ed25519_jax._BASE_BUCKETS), so
-    CPU tiles and TPU tiles label the same histogram buckets."""
-    try:
-        t = int(os.environ.get("COMETBFT_TPU_VERIFY_TILE",
-                               str(_DEFAULT_TILE)))
-    except ValueError:
-        return _DEFAULT_TILE
-    return t if t >= 64 else _DEFAULT_TILE
+def bucket(n: int) -> int:
+    """The padded lane count a batch of n signatures dispatches at:
+    the shape the kernel compiles once for, and the ``pad_bucket``
+    label value of the verify and dispatch histograms."""
+    for b in BUCKETS:
+        if n <= b:
+            return b
+    return BUCKETS[-1]
+
+
+def tile_bucket() -> int:
+    """The tile's shape: the one lane count every chunk of the device
+    pipeline dispatches at, and the number of items at which the
+    seam's verifier feeds a tile."""
+    return bucket(TILE)
 
 
 def tile_plan(n: int, tile: Optional[int] = None) -> list:
@@ -77,7 +91,7 @@ def tile_plan(n: int, tile: Optional[int] = None) -> list:
     the signed-digit MSM's per-tile bucket sweep amortizes best when
     no tile is small (measured ~3% fewer point adds at the 10k
     shape)."""
-    t = tile or tile_size()
+    t = tile or TILE
     if n <= 0:
         return []
     ntiles = -(-n // t)
@@ -141,10 +155,14 @@ _OVERLAP_HIST = None
 _TILE_REJECTS = None
 
 
-def _dispatch_histogram():
-    """The SAME family ops/ed25519_jax registers (the registry dedupes
-    by name) — declared here too because this module must not import
-    the jax stack to label CPU tiles."""
+def dispatch_histogram():
+    """metrics v2: host_prep vs kernel_execute latency split per pad
+    bucket, on the process-global registry (the dispatchers have no
+    node context; /metrics merges DEFAULT in).  One family for the
+    native tiles here (kernel label "native") and the device
+    dispatches of ops/ed25519_jax.  ``warm`` separates first-dispatch
+    compiles from steady-state execution so the execute distribution
+    is not polluted by one-off trace+compile."""
     global _DISPATCH_HIST
     if _DISPATCH_HIST is None:
         from ..libs import metrics as libmetrics
@@ -237,11 +255,11 @@ def verify_items_pipelined(
     n = len(items)
     if n == 0:
         return True, []
-    t = tile or tile_size()
+    t = tile or TILE
     pad_bucket = str(t)
     plan = tile_plan(n, t)
     mask = [True] * n
-    hist = _dispatch_histogram()
+    hist = dispatch_histogram()
     worker = _kernel_worker()
     has_tile_kernel = hasattr(native, "ed25519_batch_verify_tile")
     can_stage = hasattr(native, "ed25519_stage_pubs")
@@ -322,6 +340,7 @@ def verify_items_pipelined(
     return all(mask), mask
 
 
-__all__ = ["tile_size", "tile_plan", "verify_items_pipelined",
-           "submit", "run_off_loop", "overlap_histogram",
+__all__ = ["BASE_BUCKETS", "BUCKETS", "TILE", "bucket", "tile_bucket",
+           "tile_plan", "verify_items_pipelined", "submit",
+           "run_off_loop", "dispatch_histogram", "overlap_histogram",
            "reset_workers"]

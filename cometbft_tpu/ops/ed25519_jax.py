@@ -30,41 +30,20 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..crypto import _ed25519_ref as ref
+from ..crypto.pipeline import (
+    BASE_BUCKETS as _BASE_BUCKETS, BUCKETS as _BUCKETS, bucket as _bucket,
+    dispatch_histogram, overlap_histogram, tile_bucket, tile_plan,
+)
 from ..libs import tracing
-from . import device
+from . import device, field
 
 # (kernel choice, bucket) shapes already dispatched in this process —
 # the first dispatch of a shape pays tracing/compilation, so the
 # flight-recorder span carries warm=False for it
 _SEEN_SHAPES: set[tuple[str, int]] = set()
 
-_DISPATCH_HIST = None
 _WARNED_NO_NATIVE = False
-
-
-def _dispatch_histogram():
-    """metrics v2: host_prep vs kernel_execute latency split per pad
-    bucket, on the process-global registry (the chunk dispatcher has
-    no node context; /metrics merges DEFAULT in).  ``warm`` separates
-    first-dispatch compiles from steady-state execution so the
-    execute distribution is not polluted by one-off trace+compile."""
-    global _DISPATCH_HIST
-    if _DISPATCH_HIST is None:
-        from ..libs import metrics as libmetrics
-        _DISPATCH_HIST = libmetrics.DEFAULT.histogram(
-            "crypto", "kernel_dispatch_seconds",
-            "ed25519 kernel dispatch phases (host_prep / "
-            "kernel_execute) in seconds, by kernel, pad bucket and "
-            "warm-shape flag.",
-            labels=("phase", "kernel", "pad_bucket", "warm"),
-            buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
-                     0.1, 0.25, 0.5, 1.0, 5.0, 30.0, 120.0))
-    return _DISPATCH_HIST
-
-
-from . import field
-from ..crypto import _ed25519_ref as ref
-from ..crypto.keys import BatchVerifier, PubKey
 
 L = ref.L
 
@@ -242,18 +221,13 @@ _jit_verify = jax.jit(_verify_kernel)
 
 # --- host orchestration -----------------------------------------------------
 
-_BASE_BUCKETS = (64, 1024, 4096, 10240, 16384)
-_BUCKETS = list(_BASE_BUCKETS)
 _IDENTITY_BYTES = bytes([1] + [0] * 31)     # compressed identity (y=1)
 _B_BYTES = ref.compress(ref.B)
 
-
-def _bucket(n: int) -> int:
-    for b in _BUCKETS:
-        if n <= b:
-            return b
-    return _BUCKETS[-1]
-
+# The pad-bucket ladder, the tile and the tile's shape are
+# crypto/pipeline's (BUCKETS, TILE, tile_bucket): _BUCKETS here is a
+# second name for THE live list, written in place only (the tuner
+# below, reset_bucket_tuning, the benchmark's rehearsal).
 
 # --- measured pad-bucket refinement -----------------------------------------
 # The base buckets have a 16x gap at the bottom (64 -> 1024): a 100-sig
@@ -276,14 +250,11 @@ _REFINED_COUNTER = None
 
 def reset_bucket_tuning() -> None:
     """Test hook: drop refined buckets and samples."""
-    global _BUCKETS
-    _BUCKETS = list(_BASE_BUCKETS)
+    _BUCKETS[:] = _BASE_BUCKETS
     _tune_samples.clear()
 
 
 def _tune_record(n: int, m: int, prep_s: float, exec_s: float) -> None:
-    if os.environ.get("COMETBFT_TPU_BUCKET_TUNE", "1") == "0":
-        return
     samples = _tune_samples.setdefault(m, [])
     samples.append((n, prep_s, exec_s))
     if len(samples) > _TUNE_WINDOW:
@@ -375,12 +346,10 @@ def _verify_packed(wire):
 _jit_verify_packed = jax.jit(_verify_packed)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("kernel", "interpret", "block"))
-def _pallas_verify_packed(wire, kernel="pallas", interpret=False,
-                          block=0):
+@functools.partial(jax.jit, static_argnames=("interpret", "block"))
+def _pallas_verify_packed(wire, interpret=False, block=0):
     """The pallas kernel behind the packed uint8 wire buffer."""
-    ep = _pallas_module(kernel)
+    from . import ed25519_pallas as ep
     a8, r8, s8, k8 = wire_views(wire)
     return ep.verify_cols(_byte_cols(a8), _byte_cols(r8),
                           _win_cols(s8), _win_cols(k8),
@@ -392,8 +361,8 @@ def verify_batch(
 ) -> tuple[bool, list[bool]]:
     """Verify [(pub, msg, sig), ...] on the default JAX device.
 
-    Batches above one pipeline tile (crypto/pipeline.tile_size,
-    default 4096 — a pad-bucket shape) are fed to a TilePipeline in
+    Batches above one pipeline tile (crypto/pipeline.tile_bucket,
+    4096 lanes) are fed to a TilePipeline in
     the balanced chunks of crypto/pipeline.tile_plan (10,000 as three
     ~3,334-lane tiles), all at the tile's bucket: while tile i
     executes under JAX's async dispatch, the host preps tile i+1.
@@ -408,8 +377,7 @@ def verify_batch(
     n = len(items)
     if n == 0:
         return True, []
-    from ..crypto.pipeline import tile_plan, tile_size
-    tile = _bucket(tile_size())
+    tile = tile_bucket()
     if n <= tile:
         out = np.zeros(n, bool)
         out[:] = _verify_chunk(items)
@@ -452,7 +420,7 @@ class TilePipeline:
     def __init__(self, tile: int):
         self._choice = _kernel_choice()
         self._m = _padded(tile, self._choice)
-        self._hist = _dispatch_histogram()
+        self._hist = dispatch_histogram()
         self._masks: list[np.ndarray] = []
         self._inflight = None       # (n, warm, pre_bad, dev, span)
         self._tiles = 0
@@ -514,7 +482,6 @@ class TilePipeline:
     def finish(self) -> tuple[bool, list[bool]]:
         """Settle the last tile; (all_valid, mask) over everything
         fed, in feed order."""
-        from ..crypto.pipeline import overlap_histogram
         if self._inflight is not None:
             self._settle(prep_inside=0.0)
         wall = (tracing.now_ns() - self._t_run0) / 1e9
@@ -567,8 +534,8 @@ def _launch(wire, *, choice: str, interpret: bool = False,
         return part.dispatch(*wire_views(wire))
     with tracing.span(tracing.CRYPTO, "h2d"):
         dw = jax.device_put(wire)
-    if choice.startswith("pallas"):
-        fn = functools.partial(_pallas_verify_packed, kernel=choice,
+    if choice == "pallas":
+        fn = functools.partial(_pallas_verify_packed,
                                interpret=interpret, block=block)
     else:
         fn = _jit_verify_packed
@@ -604,35 +571,28 @@ def _force(dev, sp=None) -> np.ndarray:
 
 
 def _kernel_choice() -> str:
-    """'pallas' (fused Mosaic 24-limb kernel; TPU), 'pallas8' (the
-    first-generation 32x8-bit kernel) or 'xla' (portable).
+    """'pallas' (the fused Mosaic 24-limb kernel) on a TPU, 'xla'
+    (portable) on the CPU devices the kernel tests run on, where the
+    Pallas kernel would run interpreted: ops/device.py decides, and
+    ``auto`` is the only value a node runs with.
 
-    COMETBFT_TPU_KERNEL=pallas|pallas8|xla overrides; auto picks
-    pallas on a TPU (ops/device.py) and xla on the CPU devices the
-    kernel tests run on, where the pallas path would run
-    interpreted."""
+    COMETBFT_TPU_KERNEL=pallas|xla is how tests, chip_smoke.py and
+    the benchmark's rehearsal get the Pallas kernel on a CPU (they
+    then pass ``interpret=`` to _launch); it is no setting for
+    operators."""
     choice = os.environ.get("COMETBFT_TPU_KERNEL", "auto").lower()
-    if choice in ("pallas", "pallas8", "xla"):
+    if choice in ("pallas", "xla"):
         return choice
     return "pallas" if device.probe().is_tpu else "xla"
 
 
-def _pallas_module(choice: str):
-    """The Pallas kernel module for a 'pallas*' choice ('pallas' is
-    the 24-limb kernel, 'pallas8' the first-generation byte kernel)."""
-    if choice == "pallas8":
-        from . import ed25519_pallas8 as ep8
-        return ep8
-    from . import ed25519_pallas as ep
-    return ep
-
-
 def _padded(n: int, choice: str) -> int:
     """The lane count a chunk of n signatures dispatches at: its pad
-    bucket, and at least one grid block for the Pallas kernels."""
+    bucket, and at least one grid block for the Pallas kernel."""
     m = _bucket(n)
-    if choice.startswith("pallas"):
-        m = max(m, _pallas_module(choice).BLOCK)
+    if choice == "pallas":
+        from . import ed25519_pallas as ep
+        m = max(m, ep.BLOCK)
     return m
 
 
@@ -641,7 +601,7 @@ def _verify_chunk(items) -> np.ndarray:
     choice = _kernel_choice()
     m = _padded(n, choice)
     warm = (choice, m) in _SEEN_SHAPES
-    hist = _dispatch_histogram()
+    hist = dispatch_histogram()
     # each phase's one pair of clock readings feeds its span and
     # crypto_kernel_dispatch_seconds
     with tracing.timed(tracing.CRYPTO, "host_prep", batch=n,
@@ -764,22 +724,21 @@ def prep_arrays(items, m: int):
     return wire, pre_bad
 
 
-def _shard_min() -> int:
-    """Smallest padded batch that auto-shards over a multi-device
-    mesh.  Small batches stay single-device — the collective + copy
-    overhead dwarfs the kernel there."""
-    return int(os.environ.get("COMETBFT_TPU_SHARD_MIN", "1024"))
+# Smallest padded batch that shards over a multi-device mesh.  Small
+# batches stay single-device — the collective + copy overhead dwarfs
+# the kernel there.
+SHARD_MIN = 1024
 
 
 def _partitioner(m: int, choice: str, interpret: bool = False,
                  block: int = 0):
     """Multi-chip: when more than one JAX device is visible and the
-    padded batch is at least COMETBFT_TPU_SHARD_MIN lanes, the batch
+    padded batch is at least SHARD_MIN lanes, the batch
     shards data-parallel over the full device mesh
     (parallel/mesh.py; SURVEY §2.11).  Returns that mesh's
     partitioner, or None for a single-device dispatch."""
     ndev = device.probe().count
-    if ndev > 1 and m >= _shard_min():
+    if ndev > 1 and m >= SHARD_MIN:
         from ..parallel import mesh as pmesh
         return pmesh.pipeline_partitioner(ndev, choice, interpret,
                                           block)
@@ -807,9 +766,7 @@ def warmup(n: int) -> None:
     the bucket covering n, or above one tile the tile's bucket — the
     one shape of every TilePipeline chunk, planned by verify_batch
     or fed from the seam's ``add()`` (6,667 signatures: 4,096)."""
-    from ..crypto.pipeline import tile_size
-    tile = _bucket(tile_size())
-    _warmup_bucket(_padded(min(n, tile), _kernel_choice()))
+    _warmup_bucket(_padded(min(n, tile_bucket()), _kernel_choice()))
 
 
 @functools.lru_cache(maxsize=None)
@@ -821,31 +778,3 @@ def _warmup_bucket(m: int) -> None:
         _force(_launch(wire, choice=choice,
                        part=_partitioner(m, choice)), sp)
     _SEEN_SHAPES.add((choice, m))
-
-
-class TpuBatchVerifier(BatchVerifier):
-    """BatchVerifier backed by the XLA kernel (reference contract:
-    crypto/crypto.go:47-55; created via crypto/batch.py dispatch)."""
-
-    def __init__(self):
-        self._items: list[tuple[bytes, bytes, bytes]] = []
-
-    def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
-        if pub_key.type() != "ed25519":
-            raise TypeError("TpuBatchVerifier requires ed25519 keys")
-        if len(sig) != 64:
-            raise ValueError("malformed signature")
-        self._items.append((pub_key.bytes(), bytes(msg), bytes(sig)))
-
-    def __len__(self) -> int:
-        return len(self._items)
-
-    def verify(self) -> tuple[bool, Sequence[bool]]:
-        return verify_batch(self._items)
-
-
-# keep crypto/batch.pad_bucket in lockstep with the live (possibly
-# measurement-refined) bucket ladder — both label the same histograms
-from ..crypto import batch as _crypto_batch  # noqa: E402
-
-_crypto_batch.register_pad_bucket_fn(_bucket)

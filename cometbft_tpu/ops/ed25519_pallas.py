@@ -9,8 +9,8 @@ test — all in VMEM.
 Second-generation field arithmetic (the r3 cost model's prescription,
 KERNEL_NOTES.md): 24 balanced limbs in an (11, 11, 10)-bit cycle
 (ops/field24.py has the schedule rationale and the int32 bounds
-analysis).  The limb convolution drops from 1024 slab MACs (32x8-bit
-kernel, kept as ed25519_pallas8.py behind COMETBFT_TPU_KERNEL=pallas8)
+analysis).  The limb convolution drops from the 1024 slab MACs of
+the first-generation 32x8-bit kernel (removed; KERNEL_NOTES.md)
 to 576, and the off-grid x2 corrections are separable by residue
 class, so each of the 24 slab MACs just picks one of three pre-scaled
 copies of the multiplier.
@@ -24,7 +24,7 @@ the resting fixed point, re-derived in tests/test_field24.py) is a
 1.474e9 conv accumulator and 1.744e9 carry pre-scale — both < 2^31.
 This removes ~60% of the input carry passes (~10% of kernel ops).
 
-Inputs are identical to the byte kernel: [32, B] byte columns for
+Inputs are what the host prep makes: [32, B] byte columns for
 A and R, [64, B] nibble windows for s and k — the host prep and the
 dispatch are unchanged; bytes convert to limbs in VMEM.
 """

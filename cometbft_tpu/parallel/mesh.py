@@ -47,9 +47,8 @@ def _sharded_verify_fn(ndev: int, kernel: str, interpret: bool,
     configuration — the jit itself caches per shape."""
     mesh = make_mesh(ndev)
     from ..ops.ed25519_jax import _byte_cols, _win_cols
-    if kernel.startswith("pallas"):
-        from ..ops.ed25519_jax import _pallas_module
-        ep = _pallas_module(kernel)
+    if kernel == "pallas":
+        from ..ops import ed25519_pallas as ep
 
         def body(a, r, s, k):
             return ep.verify_cols(
@@ -72,7 +71,7 @@ def _sharded_verify_fn(ndev: int, kernel: str, interpret: bool,
         # the varying-axes check cannot type a pallas_call body (its
         # out_shape and in-kernel constants carry no mesh axes); the
         # body is lane-parallel with no collective for it to guard
-        check_vma=not kernel.startswith("pallas"),
+        check_vma=kernel != "pallas",
     )
     return jax.jit(shard)
 
@@ -99,9 +98,9 @@ class PipelinePartitioner:
     def __init__(self, ndev: int, kernel: str = "xla",
                  interpret: bool = False, block: int = 0):
         from jax.sharding import NamedSharding
-        if kernel.startswith("pallas"):
-            from ..ops.ed25519_jax import _pallas_module
-            block = block or _pallas_module(kernel).BLOCK
+        if kernel == "pallas":
+            from ..ops import ed25519_pallas as ep
+            block = block or ep.BLOCK
         else:
             interpret, block = False, 0     # ignored by the xla body
         self.ndev = ndev

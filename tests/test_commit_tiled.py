@@ -33,6 +33,7 @@ if ROOT not in sys.path:
 from benchmark.reference import commit_light, fixtures  # noqa: E402
 from cometbft_tpu.crypto import _ed25519_ref as ref  # noqa: E402
 from cometbft_tpu.crypto import batch as crypto_batch  # noqa: E402
+from cometbft_tpu.crypto import pipeline as crypto_pipeline  # noqa: E402
 from cometbft_tpu.crypto.pipeline import tile_plan  # noqa: E402
 from cometbft_tpu.libs import tracing  # noqa: E402
 from cometbft_tpu.ops import ed25519_jax as ej  # noqa: E402
@@ -59,11 +60,11 @@ def device_path(monkeypatch):
         dispatched.append(wire.shape[0])
         return jnp.asarray(wire_golden.verify_wire(wire))
 
-    monkeypatch.setenv("COMETBFT_TPU_VERIFY_TILE", str(TILE))
+    monkeypatch.setattr(crypto_pipeline, "TILE", TILE)
     monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
     # conftest's eight virtual devices would send a tile to the mesh
     # partitioner: one chip is what the cell runs on
-    monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1000000")
+    monkeypatch.setattr(ej, "SHARD_MIN", 1000000)
     for name in KERNELS:
         monkeypatch.setattr(ej, name, golden)
     monkeypatch.setattr(ej, "_SEEN_SHAPES", set(ej._SEEN_SHAPES))
@@ -459,8 +460,7 @@ def test_the_plan_at_10000_validators(monkeypatch):
     (fed by verify()); verify_batch, handed the whole list, plans two
     balanced tiles.  Either way every chunk dispatches at the 4,096
     bucket, the pipeline's one shape, and warm-up warms that one."""
-    monkeypatch.delenv("COMETBFT_TPU_VERIFY_TILE", raising=False)
-    monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1000000")
+    monkeypatch.setattr(ej, "SHARD_MIN", 1000000)
     assert 10000 * 10 * 2 // 3 // 10 + 1 == 6667
     plan = tile_plan(6667, 4096)
     assert plan == [(0, 3334), (3334, 6667)]

@@ -5,7 +5,9 @@ and loudly; the compile cache is placed from outside or at one fixed
 path; the measurement commands refuse to run without a TPU.  All on
 the CPU: the gate is told what to report where a TPU is needed.
 """
+import ast
 import os
+import re
 import subprocess
 import sys
 
@@ -143,6 +145,63 @@ class TestCpuNodeNeverImportsJax:
             "assert 'jax' not in sys.modules, 'jax was imported'\n",
             COMETBFT_TPU_CRYPTO_BACKEND="cpu")
         assert p.returncode == 0, p.stderr[-2000:]
+
+
+def _imports(path: str):
+    """Every module a file imports, anywhere in it (function bodies
+    too), as absolute dotted names; ``from m import a`` yields m and
+    m.a."""
+    package = path.split("/")[:-1]
+    with open(os.path.join(REPO, path)) as f:
+        tree = ast.parse(f.read())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = package[:len(package) - node.level + 1] \
+                if node.level else []
+            mod = ".".join(base + ([node.module] if node.module else []))
+            yield mod
+            yield from (f"{mod}.{a.name}" for a in node.names)
+
+
+class TestTheArrowsPointOneWay:
+    """crypto/batch (the seam) reaches ops/ed25519_jax (the device
+    dispatch) lazily; both read the geometry of a dispatch in
+    crypto/pipeline, which is jax-free and imports neither."""
+
+    @pytest.mark.parametrize("path, forbidden", [
+        ("cometbft_tpu/ops/ed25519_jax.py",
+         ("cometbft_tpu.crypto.batch",)),
+        ("cometbft_tpu/crypto/pipeline.py",
+         ("cometbft_tpu.crypto.batch", "cometbft_tpu.ops", "jax")),
+    ])
+    def test_no_import_upward(self, path, forbidden):
+        found = sorted(set(_imports(path)))
+        assert "cometbft_tpu.libs.tracing" in found     # it resolves
+        upward = [m for m in found for f in forbidden
+                  if m == f or m.startswith(f + ".")]
+        assert upward == []
+
+
+class TestEnvironmentSwitches:
+    # the whole list; it only ratchets down (ROADMAP D6)
+    NAMES = {"COMETBFT_TPU_" + n for n in (
+        "CRYPTO_BACKEND", "KERNEL", "NATIVE", "DUMP_DIR",
+        "BREAKER_RESET_S", "MSM_THREADS")}
+
+    def test_the_program_reads_these_and_no_other(self):
+        found = set()
+        for top in ("cometbft_tpu", "native"):
+            for root, dirs, files in os.walk(os.path.join(REPO, top)):
+                dirs[:] = [d for d in dirs if d != "__pycache__"]
+                for name in files:
+                    if not name.endswith((".py", ".cpp", ".hpp")):
+                        continue
+                    with open(os.path.join(root, name)) as f:
+                        found.update(re.findall(
+                            r"COMETBFT_TPU_[A-Z0-9_]+", f.read()))
+        assert found == self.NAMES
 
 
 class TestMeasurementCommandsRefuseWithoutAChip:

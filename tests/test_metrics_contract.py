@@ -334,16 +334,42 @@ class TestCardinalityGuard:
     def test_default_cap_is_sane(self):
         assert 512 <= _CHILDREN_MAX <= 16384
 
-    def test_pad_bucket_matches_kernel_buckets(self):
-        """crypto/batch.pad_bucket mirrors ops/ed25519_jax._bucket so
-        CPU and TPU observations share label values."""
+    def test_one_ladder(self):
+        """crypto/batch.pad_bucket and ops/ed25519_jax._bucket are one
+        function over one list (crypto/pipeline), so CPU and TPU
+        observations share label values whatever writes the ladder —
+        in place: the benchmark's rehearsal, the tuner, the reset."""
         from cometbft_tpu.crypto import batch as crypto_batch
-        from cometbft_tpu.ops import ed25519_jax
-        assert tuple(crypto_batch.PAD_BUCKETS) == \
-            tuple(ed25519_jax._BUCKETS)
-        for n in (1, 63, 64, 65, 1024, 5000, 10**6):
-            assert crypto_batch.pad_bucket(n) == \
-                ed25519_jax._bucket(n)
+        from cometbft_tpu.crypto import pipeline
+        from cometbft_tpu.ops import ed25519_jax as ej
+        sizes = (1, 16, 17, 63, 64, 65, 100, 1024, 4096, 5000, 10**6)
+
+        def agreed():
+            assert ej._BUCKETS is pipeline.BUCKETS
+            seam = [crypto_batch.pad_bucket(n) for n in sizes]
+            assert seam == [ej._bucket(n) for n in sizes]
+            tile = crypto_batch.GuardedTpuBatchVerifier(object())._tile
+            assert tile == ej.tile_bucket()
+            return seam, tile
+
+        ej.reset_bucket_tuning()
+        try:
+            base = agreed()
+            assert base == ([64, 64, 64, 64, 64, 1024, 1024, 1024,
+                             4096, 10240, 16384], 4096)
+            ej._BUCKETS[:] = [16]       # benchmark/lib/rehearsal.py
+            assert agreed() == ([16] * len(sizes), 16)
+            ej.reset_bucket_tuning()
+            assert agreed() == base
+            for _ in range(ej._TUNE_MIN_SAMPLES):
+                ej._tune_record(100, 1024, 0.001, 0.010)
+            seam, tile = agreed()
+            assert seam[sizes.index(100)] == 128 and tile == 4096
+            ej.reset_bucket_tuning()
+            assert agreed() == base
+            assert ej._BUCKETS == list(ej._BASE_BUCKETS)
+        finally:
+            ej.reset_bucket_tuning()
 
 
 # ---------------------------------------------------------------------

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from cometbft_tpu.crypto import _ed25519_ref as ref
+from cometbft_tpu.crypto import pipeline as crypto_pipeline
 from cometbft_tpu.ops import ed25519_jax as ej
 from cometbft_tpu.ops import field
 
@@ -150,16 +151,24 @@ class TestBatchVerifierDispatch:
         ok, mask = bv.verify()
         assert ok and all(mask) and len(mask) == 5
 
-    def test_tpu_verifier_flags_bad_sig(self):
-        from cometbft_tpu.crypto import ed25519
+    def test_tpu_verifier_flags_bad_sig(self, monkeypatch):
+        """The verifier the seam serves under backend ``tpu``; a
+        kernel fault would fall back to the CPU and still answer
+        right, so the breaker must have stayed closed."""
+        from cometbft_tpu.crypto import batch, ed25519
+        monkeypatch.setattr(batch, "_backend", "tpu")
+        batch.reset_tpu_breaker()
         priv = ed25519.gen_priv_key()
         pub = priv.pub_key()
-        bv = ej.TpuBatchVerifier()
+        bv = batch.create_batch_verifier(pub)
+        assert type(bv) is batch.GuardedTpuBatchVerifier
         bv.add(pub, b"a", priv.sign(b"a"))
         bv.add(pub, b"b", priv.sign(b"x"))   # wrong message
         bv.add(pub, b"c", priv.sign(b"c"))
         ok, mask = bv.verify()
         assert not ok and mask == [True, False, True]
+        assert batch.tpu_breaker().state == "closed"
+        batch.reset_tpu_breaker()
 
 
 def _wire_items():
@@ -305,7 +314,7 @@ class TestOneTransferADispatch:
         monkeypatch.setattr(ej.jax, "device_put", device_put)
         # conftest's eight virtual devices would send 1,024 lanes to
         # the mesh partitioner: one chip is what a cell runs on
-        monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1000000")
+        monkeypatch.setattr(ej, "SHARD_MIN", 1000000)
         for name in ("_jit_verify_packed", "_pallas_verify_packed"):
             monkeypatch.setattr(ej, name, stub)
         return puts, calls
@@ -334,7 +343,7 @@ class TestOneTransferADispatch:
         import jax
         puts, calls = counted
         monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
-        monkeypatch.setenv("COMETBFT_TPU_VERIFY_TILE", "64")
+        monkeypatch.setattr(crypto_pipeline, "TILE", 64)
         ok, mask = ej.verify_batch(self._commit(150))
         assert ok and len(mask) == 150
         assert puts == [(64, ej.WIRE_LANE_BYTES)] * 3
@@ -436,8 +445,7 @@ class TestPackedEntryPoints:
         if kernel == "xla":
             ok = ej._jit_verify_packed(dw)
         else:
-            ok = ej._pallas_verify_packed(dw, kernel="pallas",
-                                          interpret=True, block=8)
+            ok = ej._pallas_verify_packed(dw, interpret=True, block=8)
         ok = np.asarray(ok)
         # refused lanes run as padding lanes, which verify trivially;
         # the host's pre_bad is what fails them
@@ -482,14 +490,14 @@ class TestShardedTally:
         assert int(count) == sum(golden)
 
 
-def _pallas_verify_items(items, block=8, kernel="pallas"):
-    """Run a Pallas kernel in interpret mode through the production
+def _pallas_verify_items(items, block=8):
+    """Run the Pallas kernel in interpret mode through the production
     prep + dispatch path (ops/ed25519_jax.py), with a small block so
     the emulated kernel stays tractable."""
     n = len(items)
     m = -(-n // block) * block
     wire, pre_bad = ej.prep_arrays(items, m)
-    return ej._dispatch(n, wire, pre_bad, kernel=kernel,
+    return ej._dispatch(n, wire, pre_bad, kernel="pallas",
                         interpret=True, block=block).tolist()
 
 
@@ -587,7 +595,7 @@ class TestMultiChipDispatch:
         path a node runs, not a dryrun-only seam."""
         import jax
         assert len(jax.devices()) == 8, "conftest mesh missing"
-        monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1")
+        monkeypatch.setattr(ej, "SHARD_MIN", 1)
         monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
         items, golden = [], []
         for i in range(12):
@@ -625,18 +633,3 @@ class TestPallasMultiBlock:
             golden.append(ref.verify(pub, msg, sig))
         assert _pallas_verify_items(items, block=8) == golden
         assert golden[3] is False and golden[11] is False
-
-
-class TestPallas8Fallback:
-    pytestmark = pytest.mark.slow  # cold kernel compile (60-270s on 1 CPU)
-
-    """The first-generation 32x8-bit kernel stays correct behind
-    COMETBFT_TPU_KERNEL=pallas8 (one smoke case; its full parity
-    history is r3's suite — the 24-limb kernel above inherits it)."""
-
-    def test_valid_and_corrupted(self):
-        pub, msg, sig = _sig()
-        bad = sig[:10] + bytes([sig[10] ^ 0xFF]) + sig[11:]
-        assert _pallas_verify_items(
-            [(pub, msg, sig), (pub, msg, bad)],
-            kernel="pallas8") == [True, False]

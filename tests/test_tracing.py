@@ -477,13 +477,14 @@ class TestTiledDispatchSpans:
         import jax.numpy as jnp
 
         from cometbft_tpu.crypto import ed25519
+        from cometbft_tpu.crypto import pipeline as crypto_pipeline
         from cometbft_tpu.ops import ed25519_jax as ej
 
         def stub(wire):
             return jnp.ones(wire.shape[0], dtype=bool)
 
         monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
-        monkeypatch.setenv("COMETBFT_TPU_VERIFY_TILE", "64")
+        monkeypatch.setattr(crypto_pipeline, "TILE", 64)
         monkeypatch.setattr(ej, "_jit_verify_packed", stub)
         priv = ed25519.gen_priv_key_from_secret(b"tiles")
         pub = priv.pub_key().bytes()
@@ -535,11 +536,12 @@ class TestTiledDispatchSpans:
 
         from cometbft_tpu.crypto import batch as crypto_batch
         from cometbft_tpu.crypto import ed25519
+        from cometbft_tpu.crypto import pipeline as crypto_pipeline
         from cometbft_tpu.ops import ed25519_jax as ej
 
         monkeypatch.setenv("COMETBFT_TPU_KERNEL", "xla")
-        monkeypatch.setenv("COMETBFT_TPU_VERIFY_TILE", "64")
-        monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1000000")
+        monkeypatch.setattr(crypto_pipeline, "TILE", 64)
+        monkeypatch.setattr(ej, "SHARD_MIN", 1000000)
         monkeypatch.setattr(
             ej, "_jit_verify_packed",
             lambda wire: jnp.ones(wire.shape[0], dtype=bool))

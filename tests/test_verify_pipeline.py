@@ -206,11 +206,12 @@ class TestTiledParityFuzz:
         assert not ok_t and not ok_m
         assert [i for i, v in enumerate(mask_t) if not v] == bad_idx
 
-    def test_random_fuzz_matches_monolithic_bisection(self):
+    def test_random_fuzz_matches_monolithic_bisection(self, monkeypatch):
         """Random bad positions: the per-tile bisection's mask must
         equal the monolithic path's mask (CpuBatchVerifier pipelined
         vs monolithic=True) — the attribution contract."""
         import random
+        monkeypatch.setattr(cpipe, "TILE", 64)  # monolithic ignores it
         rng = random.Random(1400)
         for trial in range(3):
             n = rng.randrange(130, 200)
@@ -227,15 +228,7 @@ class TestTiledParityFuzz:
                     v.add(ed25519.Ed25519PubKey(pub), m, s)
                 return v
 
-            old = os.environ.get("COMETBFT_TPU_VERIFY_TILE")
-            os.environ["COMETBFT_TPU_VERIFY_TILE"] = "64"
-            try:
-                ok_t, mask_t = bv(False).verify()
-            finally:
-                if old is None:
-                    os.environ.pop("COMETBFT_TPU_VERIFY_TILE", None)
-                else:
-                    os.environ["COMETBFT_TPU_VERIFY_TILE"] = old
+            ok_t, mask_t = bv(False).verify()
             ok_m, mask_m = bv(True).verify()
             assert ok_t == ok_m is False
             assert mask_t == mask_m
@@ -498,12 +491,13 @@ class TestPipelinePartitioner:
         code = r"""
 import os
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["COMETBFT_TPU_SHARD_MIN"] = "32"
-os.environ["COMETBFT_TPU_VERIFY_TILE"] = "64"
 import secrets
 from cometbft_tpu.crypto import _ed25519_ref as ref
+from cometbft_tpu.crypto import pipeline
 from cometbft_tpu.ops import ed25519_jax as ej
 from cometbft_tpu.parallel import mesh as pmesh
+ej.SHARD_MIN = 32
+pipeline.TILE = 64
 import jax
 assert len(jax.devices()) == 4, jax.devices()
 items = []
@@ -535,7 +529,7 @@ print("PARITY_OK")
 class TestPallasUnderShardMap:
     def test_two_virtual_devices_interpret_mode(self, monkeypatch):
         """The kernel `auto` picks on a TPU, sharded: on a host with
-        several chips every commit of >= COMETBFT_TPU_SHARD_MIN lanes
+        several chips every commit of >= ej.SHARD_MIN lanes
         takes shard_map around pl.pallas_call.  JAX's varying-axes
         check refuses that wrapping (no vma on the kernel's
         out_shape), so the mesh turns the check off for the Pallas
@@ -564,7 +558,7 @@ class TestPallasUnderShardMap:
         assert ok.tolist() == golden
         # and through the production selection: with the shard floor
         # lowered, _dispatch itself picks the mesh (all 8 devices)
-        monkeypatch.setenv("COMETBFT_TPU_SHARD_MIN", "1")
+        monkeypatch.setattr(ej, "SHARD_MIN", 1)
         part = ej._partitioner(16, "pallas", True, 8)
         assert part is not None and part.ndev == 8
         mask = ej._dispatch(16, wire, pre_bad, kernel="pallas",

@@ -61,7 +61,7 @@ def collect_catalog() -> list[dict]:
     from cometbft_tpu.types import validation as types_validation
     crypto_batch.verify_seconds_histogram()
     crypto_batch.tpu_breaker()
-    ed25519_jax._dispatch_histogram()
+    crypto_pipeline.dispatch_histogram()
     ed25519_jax._refine_counter()
     signature_cache._metrics()
     bls12381._agg_pk_metrics()

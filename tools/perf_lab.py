@@ -7,7 +7,7 @@ the hot primitives the node's latency decomposes into (the same
 decomposition the flight recorder attributes per height):
 
   * ``batch_verify_cpu_pad*``  — CPU ed25519 batch verification at the
-    kernel pad-bucket batch shapes (crypto/batch.py PAD_BUCKETS);
+    kernel pad-bucket batch shapes (crypto/pipeline.py BASE_BUCKETS);
   * ``merkle_root_1024``       — the block-hash primitive;
   * ``vote_sign_bytes``        — canonical vote encoding (every sign
     and every verify path builds these bytes);
@@ -779,8 +779,8 @@ def bench_ed25519_pipelined_dispatch(fast: bool):
     piped = _cpu_bv(items, monolithic=False)
     mono = _cpu_bv(items, monolithic=True)
 
-    hist = cpipe._dispatch_histogram()
-    tile = str(cpipe.tile_size())
+    hist = cpipe.dispatch_histogram()
+    tile = str(cpipe.TILE)
     prep = hist.with_labels("host_prep", "native", tile, "1")
     execu = hist.with_labels("kernel_execute", "native", tile, "1")
     prep0, exec0 = prep._sum, execu._sum
