@@ -59,27 +59,40 @@ def vote_sign_bytes(chain_id: str, type_: int, height: int, round_: int,
         _canonical_vote(chain_id, type_, height, round_, bid, ts))
 
 
-def _split_canonical_vote_desc():
-    """CANONICAL_VOTE split at the timestamp field.  Split descriptors
-    (not dict filtering) because timestamp is always=True — encoding
-    the full descriptor with the field unset would still emit an empty
-    timestamp submessage into the wrong half."""
+_MASK64 = (1 << 64) - 1
+
+
+def _vote_splice_parts():
+    """What vote_sign_bytes_template needs beside a vote's own fields:
+    CANONICAL_VOTE's descriptor cut before and after the timestamp
+    field (split descriptors, not dict filtering: timestamp is
+    always=True, so the full descriptor with the field unset would
+    still emit an empty submessage into the wrong half), and the two
+    varint tables of make(ts).  A varint is cut 14 bits at a time:
+    more[c] is chunk c as two bytes with both continuation bits set,
+    last[c] the final chunk (one byte below 128, else two)."""
     from ..wire.proto import Msg
     fields = pb.CANONICAL_VOTE.fields
-    if [f.name for f in fields] != \
-            ["type", "height", "round", "block_id", "timestamp",
-             "chain_id"]:
+    vote_ok = [f.name for f in fields] == [
+        "type", "height", "round", "block_id", "timestamp", "chain_id"]
+    ts_ok = fields[4].tag == b"\x2a" and [
+        (f.name, f.tag, f.kind) for f in pb.TIMESTAMP.fields] == [
+        ("seconds", b"\x08", "int64"), ("nanos", b"\x10", "int32")]
+    if not (vote_ok and ts_ok):
         # explicit (not assert): must fail fast even under python -O —
         # a drifted descriptor would otherwise emit wrong sign bytes
-        raise ValueError("CANONICAL_VOTE field layout drifted; "
-                         "fix the template split")
+        raise ValueError("CANONICAL_VOTE / Timestamp field layout "
+                         "drifted; fix the template splice")
     pre = Msg(pb.CANONICAL_VOTE.name + ".pre", *fields[:4])
-    ts = Msg(pb.CANONICAL_VOTE.name + ".ts", fields[4])
     suf = Msg(pb.CANONICAL_VOTE.name + ".suf", fields[5])
-    return pre, ts, suf
+    more = tuple(bytes((c & 0x7F | 0x80, c >> 7 | 0x80))
+                 for c in range(1 << 14))
+    last = tuple(bytes((c,)) if c < 0x80 else bytes((c & 0x7F | 0x80, c >> 7))
+                 for c in range(1 << 14))
+    return pre, suf, more, last
 
 
-_CV_SPLIT = None
+_VOTE_SPLICE = None
 
 
 def vote_sign_bytes_template(chain_id: str, type_: int, height: int,
@@ -87,25 +100,57 @@ def vote_sign_bytes_template(chain_id: str, type_: int, height: int,
     """Returns make(ts) -> the same bytes as vote_sign_bytes for that
     timestamp.  Canonical proto fields marshal in field-number order
     (type=1, height=2, round=3, block_id=4, timestamp=5, chain_id=6),
-    so everything except the timestamp field marshals ONCE and each
-    vote splices its own timestamp between the two halves — a commit's
-    votes share every signed field but the timestamp (~20 us -> ~2 us
-    per signature; parity with vote_sign_bytes pinned by tests)."""
-    global _CV_SPLIT
-    if _CV_SPLIT is None:
-        _CV_SPLIT = _split_canonical_vote_desc()
-    pre_desc, ts_desc, suf_desc = _CV_SPLIT
+    so everything except the timestamp field marshals ONCE, through
+    the generic encoder, and each vote splices its own timestamp
+    between the two halves — a commit's votes share every signed field
+    but the timestamp.
+
+    make(ts) writes the timestamp field itself, with no dict and no
+    descriptor: tag 0x2a, length, 0x08 + varint(seconds) when seconds
+    is not 0, 0x10 + varint(nanos) when nanos is not 0 (both 0: an
+    empty submessage, the field is `always`), negatives as proto's
+    ten-byte two's-complement varint.  The field's body is at most 22
+    bytes, so its own length is one byte, and the outer length prefix
+    (two bytes from a 128-byte body on) is one of 23 heads made here.
+    One path for every int64 seconds and int32 nanos; parity with
+    vote_sign_bytes is pinned by tests/test_types.py.  Measured (CPU
+    sandbox, PR 30): 2.9 us a call through the generic encoder, 0.7 us
+    spliced."""
+    global _VOTE_SPLICE
+    if _VOTE_SPLICE is None:
+        _VOTE_SPLICE = _vote_splice_parts()
+    pre_desc, suf_desc, more, last = _VOTE_SPLICE
     from ..wire.proto import encode, encode_uvarint
     d = _canonical_vote(chain_id, type_, height, round_, bid,
                         Timestamp(0, 0))
     d.pop("timestamp")
     pre = encode(pre_desc, d)
     suf = encode(suf_desc, d)
+    fixed = len(pre) + 2 + len(suf)     # + the field's tag and length
+    heads = tuple(encode_uvarint(fixed + n) + pre + b"\x2a" + bytes((n,))
+                  for n in range(23))   # 2 x (tag + ten-byte varint) at most
 
     def make(ts: Timestamp) -> bytes:
-        mid = encode(ts_desc, {"timestamp": ts.to_proto()})
-        body_len = len(pre) + len(mid) + len(suf)
-        return encode_uvarint(body_len) + pre + mid + suf
+        # the two fields are written out one after the other, not in
+        # a loop over (tag, value): +0.1 us a call (CPU sandbox)
+        seconds, nanos = ts
+        parts = []
+        if seconds:
+            seconds &= _MASK64
+            parts.append(b"\x08")
+            while seconds > 0x3FFF:
+                parts.append(more[seconds & 0x3FFF])
+                seconds >>= 14
+            parts.append(last[seconds])
+        if nanos:
+            nanos &= _MASK64
+            parts.append(b"\x10")
+            while nanos > 0x3FFF:
+                parts.append(more[nanos & 0x3FFF])
+                nanos >>= 14
+            parts.append(last[nanos])
+        field = b"".join(parts)
+        return heads[len(field)] + field + suf
 
     return make
 

@@ -124,30 +124,40 @@ class Commit:
             signature=cs.signature,
         )
 
-    def vote_sign_bytes(self, chain_id: str, val_idx: int) -> bytes:
-        """Canonical signed bytes of validator val_idx's vote.
+    def vote_sign_bytes_maker(self, chain_id: str, cs: CommitSig):
+        """make(ts) -> the canonical signed bytes of a vote of this
+        commit with cs's block-id flag and timestamp ts.
 
         A commit's votes share every signed field except the
         timestamp (and the block-id variant selected by the flag), so
         the canonical marshal runs once per (chain id, flag) and each
-        vote splices its timestamp — ~10x cheaper on the verification
-        hot loops (byte-for-byte parity with the Vote.sign_bytes path
-        is pinned in tests/test_types.py).  The memo assumes commits
-        are not mutated in place after first use (nothing does; tests
-        that rebuild signatures replace whole CommitSig objects, and
-        the timestamp/flag are part of the lookup).
-
-        Reference: block.go VoteSignBytes (:921)."""
-        cs = self.signatures[val_idx]
+        vote splices its timestamp
+        (canonical.vote_sign_bytes_template).  A loop over the
+        signatures takes the maker once a flag and calls it a vote
+        (validation._walk_commit).  The memo assumes commits are not
+        mutated in place after first use (nothing does; tests that
+        rebuild signatures replace whole CommitSig objects, and the
+        timestamp/flag are part of the lookup)."""
         tmpls = self.__dict__.setdefault("_vsb_tmpls", {})
         key = (chain_id, cs.block_id_flag)
         make = tmpls.get(key)
         if make is None:
-            make = canonical.vote_sign_bytes_template(
+            make = tmpls[key] = canonical.vote_sign_bytes_template(
                 chain_id, canonical.PRECOMMIT_TYPE, self.height,
                 self.round, cs.block_id(self.block_id))
-            tmpls[key] = make
-        return make(cs.timestamp)
+        return make
+
+    def vote_sign_bytes(self, chain_id: str, val_idx: int) -> bytes:
+        """Canonical signed bytes of validator val_idx's vote, spliced
+        from the commit's template (vote_sign_bytes_maker): 1.1 us a
+        call where Vote.sign_bytes of get_vote(val_idx) takes about 9
+        and this method took 3.0 through the generic encoder (CPU
+        sandbox, PR 30); byte-for-byte parity with the Vote path is
+        pinned in tests/test_types.py.
+
+        Reference: block.go VoteSignBytes (:921)."""
+        cs = self.signatures[val_idx]
+        return self.vote_sign_bytes_maker(chain_id, cs)(cs.timestamp)
 
     def validate_basic(self) -> None:
         if self.height < 0:

@@ -483,6 +483,9 @@ def _walk_commit(
     UNCONDITIONAL on every path — see the comment at the raise.
     """
     seen_vals: dict[int, int] = {}
+    # block-id flag -> make(ts): the commit's sign-bytes template,
+    # taken once a flag, not once a signature
+    makers: dict[int, Callable] = {}
     tallied = 0
     for idx, commit_sig in enumerate(commit.signatures):
         if ignore_sig(commit_sig):
@@ -514,7 +517,11 @@ def _walk_commit(
             raise VerificationError(
                 f"validator {val} has a nil PubKey at index {idx}")
 
-        vote_sign_bytes = commit.vote_sign_bytes(chain_id, idx)
+        make = makers.get(commit_sig.block_id_flag)
+        if make is None:
+            make = makers[commit_sig.block_id_flag] = \
+                commit.vote_sign_bytes_maker(chain_id, commit_sig)
+        vote_sign_bytes = make(commit_sig.timestamp)
 
         cache_hit = False
         if cache is not None:

@@ -2,6 +2,8 @@
 commit construction + verification (single and batch CPU paths), header
 hashing, part sets, evidence round-trips.
 """
+import random
+
 import pytest
 
 from cometbft_tpu.crypto import batch as crypto_batch
@@ -11,7 +13,7 @@ from cometbft_tpu.types.block import (
     Block, ConsensusVersion, Data, Header, make_block,
 )
 from cometbft_tpu.types.block_id import BlockID
-from cometbft_tpu.types.commit import Commit, CommitSig
+from cometbft_tpu.types.commit import Commit, CommitError, CommitSig
 from cometbft_tpu.types.evidence import DuplicateVoteEvidence
 from cometbft_tpu.types.part_set import PartSet, PartSetHeader
 from cometbft_tpu.types.signature_cache import SignatureCache
@@ -325,3 +327,201 @@ class TestVoteSignBytesTemplate:
                 want = commit.get_vote(i).sign_bytes(chain)
                 got = commit.vote_sign_bytes(chain, i)
                 assert got == want, (chain, i)
+
+
+    _SECONDS = (0, 1, 127, 128, 2**31, 2**35, 2**62, -1, -2**40)
+    _NANOS = (0, 1, 127, 128, 16_383, 16_384, 999_999_999, -1)
+    _CHAINS = ("", "chain-009", "c" * 30, "c" * 50)
+    _BID = BlockID(hash=b"\x9a" * 32,
+                   part_set_header=PartSetHeader(3, b"\xbc" * 32))
+
+    @staticmethod
+    def _commit(flag, round_, ts):
+        return Commit(
+            height=42, round=round_, block_id=TestVoteSignBytesTemplate._BID,
+            signatures=[CommitSig(block_id_flag=flag,
+                                  validator_address=b"\x07" * 20,
+                                  timestamp=ts, signature=b"\x01" * 64)])
+
+    @pytest.mark.parametrize("round_", (0, 3))
+    @pytest.mark.parametrize("chain", _CHAINS,
+                             ids=[str(len(c)) for c in _CHAINS])
+    @pytest.mark.parametrize("flag",
+                             (BLOCK_ID_FLAG_COMMIT, BLOCK_ID_FLAG_NIL))
+    @pytest.mark.parametrize("nanos", _NANOS)
+    @pytest.mark.parametrize("seconds", _SECONDS)
+    def test_splice_parity(self, seconds, nanos, flag, chain, round_):
+        """The dedicated timestamp encoder against the generic one
+        (Vote.sign_bytes -> canonical.vote_sign_bytes ->
+        wire.proto.encode), where each varint changes length, the
+        field is empty, a value is negative (ten-byte varint) and the
+        body's own length prefix goes from one byte to two."""
+        commit = self._commit(flag, round_, Timestamp(seconds, nanos))
+        want = commit.get_vote(0).sign_bytes(chain)
+        got = commit.vote_sign_bytes(chain, 0)
+        assert type(got) is bytes and got == want
+        make = commit.vote_sign_bytes_maker(chain, commit.signatures[0])
+        assert make(Timestamp(seconds, nanos)) == want
+
+    def test_splice_parity_grid_crosses_the_two_byte_prefix(self):
+        """The grid above holds bodies of exactly 127 and 128 bytes
+        (the outer uvarint goes to two bytes there) and every
+        timestamp-field length from empty to 2 x (tag + ten bytes)."""
+        from cometbft_tpu.wire.proto import decode_uvarint
+
+        def body_len(ts, chain):
+            sb = self._commit(BLOCK_ID_FLAG_COMMIT, 0,
+                              ts).vote_sign_bytes(chain, 0)
+            n, pos = decode_uvarint(sb, 0)
+            assert pos + n == len(sb) and pos == (1 if n < 128 else 2)
+            return n
+
+        bodies, fields = set(), set()
+        for chain in self._CHAINS:
+            empty = body_len(Timestamp(0, 0), chain)
+            for seconds in self._SECONDS:
+                for nanos in self._NANOS:
+                    n = body_len(Timestamp(seconds, nanos), chain)
+                    bodies.add(n)
+                    fields.add(n - empty)
+        assert {127, 128} <= bodies
+        assert min(fields) == 0 and max(fields) == 22
+
+    def test_splice_parity_seeded_sweep(self):
+        """2,000 seeded timestamps over the whole int64 x int32 range
+        and the live range (seconds ~1.7e9, nanos < 1e9)."""
+        rng = random.Random(30)
+        commit = self._commit(BLOCK_ID_FLAG_COMMIT, 1, Timestamp(0, 0))
+        make = commit.vote_sign_bytes_maker("sweep", commit.signatures[0])
+        for i in range(2000):
+            if i % 2:
+                ts = Timestamp(rng.randrange(-2**63, 2**63),
+                               rng.randrange(-2**31, 2**31))
+            else:
+                ts = Timestamp(1_700_000_000 + rng.randrange(10**6),
+                               rng.randrange(10**9))
+            assert make(ts) == canonical.vote_sign_bytes(
+                "sweep", canonical.PRECOMMIT_TYPE, 42, 1, self._BID,
+                ts), ts
+
+    def test_unknown_flag_still_raises_every_call(self):
+        """An unknown block-id flag is refused by CommitSig.block_id
+        before a template is made, and is never memoised."""
+        commit = self._commit(9, 0, Timestamp(1, 1))
+        for _ in range(2):
+            with pytest.raises(CommitError, match="unknown BlockIDFlag"):
+                commit.vote_sign_bytes("c", 0)
+        assert not commit.__dict__.get("_vsb_tmpls")
+
+
+class _RecordingVerifier(crypto_batch.BatchVerifier):
+    """The CPU verifier, keeping what the walk hands it."""
+
+    def __init__(self):
+        self.inner = ed25519.CpuBatchVerifier()
+        self.triples = []
+
+    def add(self, pub_key, msg, sig):
+        self.triples.append((pub_key.bytes(), msg, sig))
+        self.inner.add(pub_key, msg, sig)
+
+    def verify(self):
+        return self.inner.verify()
+
+
+class TestWalkDecidesTheSame:
+    """What _walk_commit hands the batch verifier, against the plain
+    reference: Vote.sign_bytes of get_vote(idx), index by index."""
+
+    CHAIN = "walk-chain-1k"
+    N = 1000
+
+    @pytest.fixture(scope="class")
+    def signed(self):
+        rng = random.Random(3030)
+        privs = [ed25519.gen_priv_key_from_secret(b"walk-%d" % i)
+                 for i in range(self.N)]
+        vset = ValidatorSet([Validator.new(p.pub_key(), rng.randrange(1, 50))
+                             for p in privs])
+        by_addr = {p.pub_key().address(): p for p in privs}
+        bid = BlockID(hash=b"\x12" * 32,
+                      part_set_header=PartSetHeader(1, b"\x34" * 32))
+        commit = Commit(height=77, round=2, block_id=bid, signatures=[])
+        for i, val in enumerate(vset.validators):
+            roll = rng.random()
+            if roll < 0.03:
+                commit.signatures.append(CommitSig.absent())
+                continue
+            flag = BLOCK_ID_FLAG_NIL if roll < 0.08 else BLOCK_ID_FLAG_COMMIT
+            commit.signatures.append(CommitSig(
+                block_id_flag=flag, validator_address=val.address,
+                timestamp=Timestamp(1_700_000_000 + rng.randrange(3),
+                                    rng.randrange(10**9))))
+            commit.signatures[i].signature = by_addr[val.address].sign(
+                commit.get_vote(i).sign_bytes(self.CHAIN))
+        return vset, bid, commit
+
+    def _reference(self, vset, commit, light):
+        """(pub, msg, sig) in walk order; light stops past 2/3."""
+        needed = vset.total_voting_power() * 2 // 3
+        out, tallied = [], 0
+        for i, cs in enumerate(commit.signatures):
+            if cs.block_id_flag == BLOCK_ID_FLAG_ABSENT or (
+                    light and cs.block_id_flag != BLOCK_ID_FLAG_COMMIT):
+                continue
+            out.append((vset.validators[i].pub_key.bytes(),
+                        commit.get_vote(i).sign_bytes(self.CHAIN),
+                        cs.signature))
+            tallied += vset.validators[i].voting_power
+            if light and tallied > needed:
+                break
+        return out
+
+    def _verify(self, monkeypatch, light, vset, bid, commit):
+        made = []
+
+        def create(_pub_key):
+            made.append(_RecordingVerifier())
+            return made[-1]
+
+        monkeypatch.setattr(crypto_batch, "create_batch_verifier", create)
+        verify = verify_commit_light if light else verify_commit
+        try:
+            verify(self.CHAIN, vset, bid, commit.height, commit)
+        finally:
+            assert len(made) == 1
+        return made[0].triples
+
+    @pytest.mark.parametrize("light", (True, False),
+                             ids=("verify_commit_light", "verify_commit"))
+    def test_triples_equal_the_reference(self, monkeypatch, signed, light):
+        vset, bid, commit = signed
+        want = self._reference(vset, commit, light)
+        got = self._verify(monkeypatch, light, vset, bid, commit)
+        assert len(got) == len(want)
+        assert got == want
+        assert all(type(msg) is bytes for _, msg, _ in got)
+        if light:
+            assert len(got) < sum(
+                cs.block_id_flag == BLOCK_ID_FLAG_COMMIT
+                for cs in commit.signatures)     # the early exit held
+
+    @pytest.mark.parametrize("light", (True, False),
+                             ids=("verify_commit_light", "verify_commit"))
+    def test_lowest_forged_index_is_named(self, monkeypatch, signed, light):
+        vset, bid, commit = signed
+        commits = [i for i, cs in enumerate(commit.signatures)
+                   if cs.block_id_flag == BLOCK_ID_FLAG_COMMIT]
+        low, high = commits[40], commits[300]
+        forged = Commit(height=commit.height, round=commit.round,
+                        block_id=bid, signatures=list(commit.signatures))
+        for i in (high, low):
+            cs = commit.signatures[i]
+            forged.signatures[i] = CommitSig(
+                block_id_flag=cs.block_id_flag,
+                validator_address=cs.validator_address,
+                timestamp=cs.timestamp,
+                signature=cs.signature[:-1] + bytes([cs.signature[-1] ^ 1]))
+        with pytest.raises(VerificationError,
+                           match=rf"wrong signature \(#{low}\)"):
+            self._verify(monkeypatch, light, vset, bid, forged)

@@ -6,9 +6,10 @@ the CanonicalVoteExtension schema.
 import pytest
 
 from cometbft_tpu.types.block_id import BlockID
+from cometbft_tpu.types.commit import Commit, CommitSig
 from cometbft_tpu.types.part_set import PartSetHeader
 from cometbft_tpu.types.timestamp import Timestamp
-from cometbft_tpu.types.vote import Vote
+from cometbft_tpu.types.vote import BLOCK_ID_FLAG_NIL, Vote
 from cometbft_tpu.types import canonical
 from cometbft_tpu.wire import pb, encode, decode, marshal_delimited
 
@@ -82,6 +83,46 @@ class TestVoteSignBytesGoldenVectors:
         b = _vote_sign_bytes("test_chain_id", height=1, round=1,
                              extension=b"extension")
         assert a == b
+
+
+# the inputs of the vectors above: (chain id, Vote fields)
+_VECTOR_INPUTS = [
+    ("", {}),
+    ("", {"height": 1, "round": 1, "type": canonical.PRECOMMIT_TYPE}),
+    ("", {"height": 1, "round": 1, "type": canonical.PREVOTE_TYPE}),
+    ("", {"height": 1, "round": 1}),
+    ("test_chain_id", {"height": 1, "round": 1}),
+]
+
+
+class TestVoteSignBytesVectorsSpliced:
+    """The same inputs through the commit's spliced template
+    (canonical.vote_sign_bytes_template, a dedicated timestamp
+    encoder) must give the bytes the vectors pin for Vote.sign_bytes."""
+
+    @pytest.mark.parametrize("chain_id,kw", _VECTOR_INPUTS)
+    def test_template(self, chain_id, kw):
+        v = Vote(**kw)
+        make = canonical.vote_sign_bytes_template(
+            chain_id, v.type, v.height, v.round, v.block_id)
+        got = make(v.timestamp)
+        assert type(got) is bytes and got == v.sign_bytes(chain_id)
+
+    @pytest.mark.parametrize("chain_id,kw", _VECTOR_INPUTS)
+    def test_one_signature_commit(self, chain_id, kw):
+        """A Commit's votes are precommits: the vector's height, round,
+        nil block id and Go-zero timestamp under that type (the
+        precommit vector is then the pinned bytes themselves)."""
+        v = Vote(**{**kw, "type": canonical.PRECOMMIT_TYPE})
+        commit = Commit(
+            height=v.height, round=v.round,
+            signatures=[CommitSig(block_id_flag=BLOCK_ID_FLAG_NIL,
+                                  validator_address=b"\x01" * 20,
+                                  timestamp=v.timestamp,
+                                  signature=b"\x02" * 64)])
+        got = commit.vote_sign_bytes(chain_id, 0)
+        assert type(got) is bytes and got == v.sign_bytes(chain_id)
+        assert got == commit.get_vote(0).sign_bytes(chain_id)
 
 
 class TestRoundTrip:
