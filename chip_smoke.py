@@ -18,8 +18,9 @@ exit 0; no stage's exception is caught and carried past:
             BatchVerifier seam against the golden model
   seam-10k  the same at the north-star 10,000 validators (the tiled,
             overlapped dispatch)
-  light-1k  light.verifier.verify over a 1,000-validator chain, a few
-            skipping hops, honest and forged
+  light-1k  light.Client.verify_to_height over a 1,000-validator chain
+            whose set changes every height: a refused jump, a
+            bisection, two verified hops; then a forged target
   net-4     a live net: four validators + a late-joining full node on
             the kvstore app, 1 kB txs over RPC, cross-node invariants,
             acknowledged writes read back from another node
@@ -66,7 +67,8 @@ class Sizes(NamedTuple):
     qa_vals: int            # CometBFT QA v1: 175 validators
     star_vals: int          # BASELINE.json north star: 10,000
     light_vals: int         # BASELINE.json config #3: 1,000
-    light_hops: int
+    light_churn: int        # keys that change a height (light-1k: 10)
+    light_heights: int      # twice the reach of one hop, plus one
     net_vals: int           # BASELINE.json config #1: 4 validators
     net_height: int
     net_timeout_s: float
@@ -74,8 +76,8 @@ class Sizes(NamedTuple):
     honest_sample: int      # honest lanes checked against the golden
 
 
-REAL = Sizes(175, 10_000, 1000, 4, 4, 10, 120.0, 5, 512)
-TINY = Sizes(24, 40, 10, 2, 2, 4, 240.0, 2, 8)
+REAL = Sizes(175, 10_000, 1000, 10, 129, 4, 10, 120.0, 5, 512)
+TINY = Sizes(24, 40, 10, 1, 9, 2, 4, 240.0, 2, 8)
 TX_SIZE = 1024              # upstream QA's transaction size
 
 
@@ -334,33 +336,77 @@ class Smoke:
               f"{len(sample)} honest)", flush=True)
 
     def stage_light(self) -> None:
+        """The benchmark's deployment light-1k at a short chain: the
+        set at the tip shares no key with the trusted one, so the jump
+        is refused and bisected, and the midpoint keeps just over a
+        third."""
+        from benchmark.reference import skipping
+        from cometbft_tpu.db import MemDB
+        from cometbft_tpu.light.client import (
+            SKIPPING, Client, TrustOptions,
+        )
+        from cometbft_tpu.light.store import TrustedStore
         from cometbft_tpu.light.verifier import InvalidHeaderError
         from cometbft_tpu.node.node import warm_device_path
-        from cometbft_tpu.tools import benchmarks
+        from cometbft_tpu.types.block import LightBlock
 
-        n, hops = self.sizes.light_vals, self.sizes.light_hops
+        sz = self.sizes
+        n, tip = sz.light_vals, sz.light_heights
         warm_device_path(n)
         self.warmed()
-        # one more header than hops: the last is the one forged
-        trusted, vset, targets, now = benchmarks.light_chain(
-            n, hops + 1, seed=self.seed)
+        chain = skipping.build_chain("smoke-light", self.seed, n, 10,
+                                     sz.light_churn, tip)
+        wire = {h: lb.to_proto() for h, lb in chain.blocks.items()}
         self.lap("build")
-        for sh in targets[:hops]:
-            benchmarks.light_verify(trusted, vset, sh, now)
+
+        class Provider:
+            """Every fetch a freshly decoded block."""
+
+            def __init__(self, served: dict):
+                self.served = served
+
+            async def light_block(self, height: int) -> LightBlock:
+                return LightBlock.from_proto(
+                    self.served.get(height) or wire[height])
+
+            def id(self) -> str:
+                return "smoke-provider"
+
+        def sync(served: dict) -> TrustedStore:
+            provider, store = Provider(served), TrustedStore(MemDB())
+            client = Client(
+                chain.chain_id,
+                TrustOptions(24 * 3600 * 10 ** 9, 1,
+                             chain.header_hash(1)),
+                provider, [provider], store,
+                verification_mode=SKIPPING)
+
+            async def run() -> None:
+                await client.initialize(now=chain.now)
+                await client.verify_to_height(tip, now=chain.now)
+            asyncio.run(run())
+            return store
+
+        heights = sync({}).heights()
+        if heights != [1, (1 + tip) // 2, tip]:
+            raise RuntimeError(f"the store holds {heights}: the jump "
+                               f"to {tip} was not bisected once")
         self.lap("hops")
-        forged = targets[hops]
-        cs = forged.commit.signatures[0]
+        forged = LightBlock.from_proto(wire[tip])
+        cs = forged.signed_header.commit.signatures[0]
         cs.signature = bytes([cs.signature[0] ^ 1]) + cs.signature[1:]
         try:
-            benchmarks.light_verify(trusted, vset, forged, now)
+            sync({tip: forged.to_proto()})
         except InvalidHeaderError as e:
             if "(#0)" not in str(e):
                 raise
         else:
             raise RuntimeError("light client accepted a forged header")
         self.lap("forged")
-        print(f"[light-1k] {hops} skipping hops over {n} validators "
-              f"verified; a forged header was refused", flush=True)
+        print(f"[light-1k] {n} validators, {sz.light_churn} changing a "
+              f"height: 1 -> {tip} refused and bisected, {heights} "
+              f"trusted; a forged target was refused by index",
+              flush=True)
 
     def stage_net(self) -> None:
         from cometbft_tpu.node.node import warm_device_path

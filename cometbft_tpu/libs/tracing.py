@@ -73,9 +73,10 @@ BLOCKSYNC = "blocksync"
 STATE = "state"
 SUPERVISOR = "supervisor"
 NEMESIS = "nemesis"
+LIGHT = "light"
 
 CATEGORIES = (CONSENSUS, CRYPTO, P2P, MEMPOOL, ABCI, BLOCKSYNC, STATE,
-              SUPERVISOR, NEMESIS)
+              SUPERVISOR, NEMESIS, LIGHT)
 
 now_ns = time.monotonic_ns
 _get_ident = threading.get_ident

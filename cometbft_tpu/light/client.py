@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+from ..libs import tracing
 from ..libs.log import Logger, new_logger
 from ..types.block import LightBlock
 from ..types.evidence import LightClientAttackEvidence
@@ -18,8 +19,8 @@ from ..types.validation import Fraction
 from .provider import LightBlockNotFoundError, Provider, ProviderError
 from .store import TrustedStore
 from .verifier import (
-    DEFAULT_TRUST_LEVEL, LightClientError, header_expired,
-    validate_trust_level, verify, verify_backwards,
+    DEFAULT_TRUST_LEVEL, LightClientError, NewValSetCantBeTrustedError,
+    header_expired, validate_trust_level, verify, verify_backwards,
 )
 
 _S = 1_000_000_000
@@ -27,6 +28,27 @@ DEFAULT_MAX_CLOCK_DRIFT_NS = 10 * _S
 
 SEQUENTIAL = "sequential"
 SKIPPING = "skipping"
+
+_HOPS = None
+
+
+def hop_counters() -> dict:
+    """outcome -> its child of ``cometbft_light_hops_total``: one
+    verify() attempt each, by how it ended.  Process-global registry,
+    as the signature cache's counters: a light client has no node."""
+    global _HOPS
+    if _HOPS is None:
+        from ..libs import metrics as libmetrics
+        hops = libmetrics.DEFAULT.counter(
+            "light", "hops_total",
+            "Light-client verification attempts (one trusted -> "
+            "candidate hop each) by outcome: verified, cant_trust "
+            "(too little of the trusted set signed: bisected), "
+            "invalid.", labels=("outcome",))
+        _HOPS = {"verified": hops.with_labels("verified"),
+                 "cant_trust": hops.with_labels("cant_trust"),
+                 "invalid": hops.with_labels("invalid")}
+    return _HOPS
 
 
 class DivergenceError(LightClientError):
@@ -100,23 +122,37 @@ class Client:
         now = now or Timestamp.now()
         if height <= 0:
             raise LightClientError("height must be positive")
+        with tracing.span(tracing.LIGHT, "light_sync", height,
+                          to=height) as sync:
+            with tracing.span(tracing.LIGHT, "light_store_read"):
+                existing, base = self._stored(height)
+            if existing is not None:
+                return existing
+            sync.note(**{"from": base.height})
+            if height < base.height:
+                return await self._backwards(base, height)
+            return await self._verify_forward(base, height, now,
+                                              cache=cache)
+
+    def _stored(self, height: int) -> tuple:
+        """What the store has for a request: ``(the block at height,
+        None)``, or ``(None, the block to start from)``: the latest
+        when the height lies past it, the first when it lies before
+        all (then verified backwards), else the closest below."""
         existing = self.store.light_block(height)
         if existing is not None:
-            return existing
+            return existing, None
         latest = self.store.latest()
         if latest is None:
             raise LightClientError("client not initialized")
-        if height < latest.height:
-            first = self.store.first()
-            if first is not None and height < first.height:
-                return await self._backwards(first, height)
-            # between stored roots: verify forward from the closest
-            # lower stored block
-            base = self._closest_below(height)
-            return await self._verify_forward(base, height, now,
-                                              cache=cache)
-        return await self._verify_forward(latest, height, now,
-                                          cache=cache)
+        if height > latest.height:
+            return None, latest
+        first = self.store.first()
+        if first is not None and height < first.height:
+            return None, first
+        # between stored roots: verify forward from the closest
+        # lower stored block
+        return None, self._closest_below(height)
 
     async def update(self, now: Optional[Timestamp] = None
                      ) -> Optional[LightBlock]:
@@ -128,8 +164,10 @@ class Client:
         new = await self.primary.light_block(0)
         if new.height <= latest.height:
             return None
-        return await self._verify_forward(latest, new.height, now,
-                                          prefetched=new)
+        with tracing.span(tracing.LIGHT, "light_sync", new.height,
+                          **{"from": latest.height, "to": new.height}):
+            return await self._verify_forward(latest, new.height, now,
+                                              prefetched=new)
 
     async def verify_to_height(self, height: int,
                                now: Optional[Timestamp] = None
@@ -167,6 +205,7 @@ class Client:
                               prefetched: Optional[LightBlock] = None,
                               cache: Optional[SignatureCache] = None
                               ) -> LightBlock:
+        """Inside the caller's ``light_sync`` span."""
         trace: list[LightBlock] = [trusted]
         if self.mode == SEQUENTIAL:
             lb = await self._verify_sequential(trusted, height, now,
@@ -174,8 +213,39 @@ class Client:
         else:
             lb = await self._verify_skipping(trusted, height, now,
                                              prefetched, trace, cache)
-        await self._detect_divergence(lb, now, trace)
+        with tracing.span(tracing.LIGHT, "light_detect"):
+            await self._detect_divergence(lb, now, trace)
         return lb
+
+    async def _fetch(self, provider: Provider,
+                     height: int) -> LightBlock:
+        with tracing.span(tracing.LIGHT, "light_fetch"):
+            return await provider.light_block(height)
+
+    def _hop(self, trusted: LightBlock, candidate: LightBlock,
+             now: Timestamp, cache: Optional[SignatureCache]) -> None:
+        """One verify() attempt, as a ``light_hop`` span and a step
+        of ``cometbft_light_hops_total``; saves a verified candidate.
+        Raises what verify() raises."""
+        with tracing.span(tracing.LIGHT, "light_hop", candidate.height,
+                          trusted=trusted.height,
+                          candidate=candidate.height) as sp:
+            outcome = "invalid"
+            try:
+                verify(trusted.signed_header, trusted.validator_set,
+                       candidate.signed_header, candidate.validator_set,
+                       self.trust_options.period_ns, now,
+                       self.max_clock_drift_ns, self.trust_level,
+                       cache=cache)
+                outcome = "verified"
+            except NewValSetCantBeTrustedError:
+                outcome = "cant_trust"
+                raise
+            finally:
+                sp.note(outcome=outcome)
+                hop_counters()[outcome].add()
+        with tracing.span(tracing.LIGHT, "light_store_save"):
+            self.store.save_light_block(candidate)
 
     async def _verify_sequential(self, trusted: LightBlock,
                                  height: int, now: Timestamp,
@@ -186,13 +256,8 @@ class Client:
         verifySequential)."""
         current = trusted
         for h in range(trusted.height + 1, height + 1):
-            nxt = await self.primary.light_block(h)
-            verify(current.signed_header, current.validator_set,
-                   nxt.signed_header, nxt.validator_set,
-                   self.trust_options.period_ns, now,
-                   self.max_clock_drift_ns, self.trust_level,
-                   cache=cache)
-            self.store.save_light_block(nxt)
+            nxt = await self._fetch(self.primary, h)
+            self._hop(current, nxt, now, cache)
             if trace is not None:
                 trace.append(nxt)
             current = nxt
@@ -208,26 +273,18 @@ class Client:
         to the target; on insufficient trust, bisect."""
         target = prefetched if prefetched is not None and \
             prefetched.height == height else \
-            await self.primary.light_block(height)
+            await self._fetch(self.primary, height)
         verified = trusted
         pivots = [target]
         while pivots:
             candidate = pivots[-1]
             try:
-                verify(verified.signed_header, verified.validator_set,
-                       candidate.signed_header, candidate.validator_set,
-                       self.trust_options.period_ns, now,
-                       self.max_clock_drift_ns, self.trust_level,
-                       cache=cache)
-                self.store.save_light_block(candidate)
+                self._hop(verified, candidate, now, cache)
                 if trace is not None:
                     trace.append(candidate)
                 verified = candidate
                 pivots.pop()
-            except LightClientError as e:
-                from .verifier import NewValSetCantBeTrustedError
-                if not isinstance(e, NewValSetCantBeTrustedError):
-                    raise
+            except NewValSetCantBeTrustedError as e:
                 # can't jump that far: bisect
                 pivot_height = (verified.height + candidate.height) // 2
                 if pivot_height in (verified.height, candidate.height):
@@ -235,7 +292,7 @@ class Client:
                         "bisection failed: no trust path to target"
                     ) from e
                 pivots.append(
-                    await self.primary.light_block(pivot_height))
+                    await self._fetch(self.primary, pivot_height))
         return verified
 
     async def _backwards(self, first: LightBlock,
@@ -269,7 +326,7 @@ class Client:
         bad: list[Provider] = []
         for w in self.witnesses:
             try:
-                wlb = await w.light_block(h)
+                wlb = await self._fetch(w, h)
             except (ProviderError, LightBlockNotFoundError):
                 continue
             if wlb.signed_header.header.hash() == target_hash:

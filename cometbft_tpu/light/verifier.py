@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+from ..libs import tracing
 from ..types.block import SignedHeader
 from ..types.signature_cache import SignatureCache
 from ..types.timestamp import Timestamp
@@ -57,20 +58,25 @@ def _verify_new_header_and_vals(
         untrusted_header: SignedHeader, untrusted_vals: ValidatorSet,
         trusted_header: SignedHeader, now: Timestamp,
         max_clock_drift_ns: int) -> None:
-    untrusted_header.validate_basic(trusted_header.header.chain_id)
-    if untrusted_header.height <= trusted_header.height:
-        raise InvalidHeaderError(
-            f"header height not monotonic: got {untrusted_header.height},"
-            f" trusted {trusted_header.height}")
-    if untrusted_header.header.time.unix_ns() <= \
-            trusted_header.header.time.unix_ns():
-        raise InvalidHeaderError("header time not monotonic")
-    if untrusted_header.header.time.unix_ns() >= \
-            now.add_ns(max_clock_drift_ns).unix_ns():
-        raise InvalidHeaderError("header time exceeds max clock drift")
-    if untrusted_header.header.validators_hash != untrusted_vals.hash():
-        raise InvalidHeaderError(
-            "header validators hash does not match given validator set")
+    with tracing.span(tracing.LIGHT, "header_checks"):
+        untrusted_header.validate_basic(trusted_header.header.chain_id)
+        if untrusted_header.height <= trusted_header.height:
+            raise InvalidHeaderError(
+                f"header height not monotonic: got "
+                f"{untrusted_header.height}, trusted "
+                f"{trusted_header.height}")
+        if untrusted_header.header.time.unix_ns() <= \
+                trusted_header.header.time.unix_ns():
+            raise InvalidHeaderError("header time not monotonic")
+        if untrusted_header.header.time.unix_ns() >= \
+                now.add_ns(max_clock_drift_ns).unix_ns():
+            raise InvalidHeaderError(
+                "header time exceeds max clock drift")
+        if untrusted_header.header.validators_hash != \
+                untrusted_vals.hash():
+            raise InvalidHeaderError(
+                "header validators hash does not match given "
+                "validator set")
 
 
 def verify_adjacent(trusted_header: SignedHeader,

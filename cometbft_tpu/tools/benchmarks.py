@@ -5,7 +5,8 @@ kvstore testnet) is `tools/manifest.py` + `cometbft_tpu.cmd load`,
 and this module packages the verification-workload ones:
 
   #2  BatchVerifier microbench at 64 / 1k / 10k ed25519 sigs
-  #3  light-client skipping verification, large validator set
+  #3  light-client skipping verification: the benchmark's cell
+      light-1k.skip (benchmark/traffic/skip.py), not this module
   #4  consensus replay: per-height VoteSet tally + Commit verify
   #5  stress: large mixed-key commit + bls12381 aggregate path
 
@@ -135,62 +136,6 @@ def config2_batch_verify(sizes=(64, 1024, 10_000)) -> dict:
             "results_ms": results}
 
 
-def light_chain(n_vals: int, hops: int, seed=None):
-    """BASELINE config #3's fixture: a trusted SignedHeader at height
-    1 and ``hops`` later ones, 10 heights apart, all signed by one
-    n_vals-validator set.  Returns (trusted, vset, targets, now).
-    Keys come from ``seed`` when given, else from the OS."""
-    from ..crypto import ed25519
-    from ..types.block import Header, SignedHeader
-    from ..types.block_id import BlockID
-    from ..types.part_set import PartSetHeader
-    from ..types.timestamp import Timestamp
-
-    chain_id = "light-bench"
-    vset, privs = _make_valset(
-        seeded_privs(n_vals, seed, "light") if seed is not None
-        else [ed25519.gen_priv_key() for _ in range(n_vals)])
-
-    def signed_header(height: int) -> SignedHeader:
-        hdr = Header(chain_id=chain_id, height=height,
-                     time=Timestamp(1700000000 + height, 0),
-                     validators_hash=vset.hash(),
-                     next_validators_hash=vset.hash(),
-                     proposer_address=vset.validators[0].address)
-        bid = BlockID(hash=hdr.hash(),
-                      part_set_header=PartSetHeader(1, b"\x11" * 32))
-        return SignedHeader(
-            header=hdr,
-            commit=_signed_commit(chain_id, vset, privs, height, bid))
-
-    trusted = signed_header(1)
-    targets = [signed_header(1 + 10 * (i + 1)) for i in range(hops)]
-    return trusted, vset, targets, Timestamp(1700000600, 0)
-
-
-def light_verify(trusted, vset, target, now) -> None:
-    """One skipping hop with config #3's trust parameters (reference:
-    light/verifier.go Verify -> VerifyNonAdjacent); raises on an
-    unverifiable header."""
-    from ..light.verifier import DEFAULT_TRUST_LEVEL, verify
-    verify(trusted, vset, target, vset,
-           365 * 24 * 3600 * 10 ** 9, now, 10 ** 9,
-           DEFAULT_TRUST_LEVEL)
-
-
-def config3_light_client(n_vals=1000, hops=4) -> dict:
-    """Reference: light/verifier.go VerifyNonAdjacent with a large
-    valset (BASELINE config #3: 1k-validator SignedHeader chain)."""
-    trusted, vset, targets, now = light_chain(n_vals, hops)
-    t0 = _now()
-    for sh in targets:
-        light_verify(trusted, vset, sh, now)
-    dt = (_now() - t0) * 1000
-    return {"config": 3, "metric": "light_skipping_verify_ms_per_hop",
-            "validators": n_vals, "hops": hops,
-            "value_ms": round(dt / hops, 2)}
-
-
 def config4_replay_tally(n_vals=150, heights=10) -> dict:
     """Reference: per-height VoteSet tally (vote_set.go AddVote with
     per-vote verify) + Commit verify (BASELINE config #4's hot
@@ -285,9 +230,9 @@ def config5_mixed_stress(n_vals=1000, n_bls=64) -> dict:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="BASELINE benchmark configs #2-#5")
+        description="BASELINE benchmark configs #2, #4, #5")
     ap.add_argument("--config", type=int, default=0,
-                    choices=[0, 2, 3, 4, 5],
+                    choices=[0, 2, 4, 5],
                     help="run a single config (2-5); 0 = all. "
                          "Config #1 (live testnet) is tools/"
                          "manifest.py + `cometbft_tpu.cmd load`.")
@@ -297,8 +242,6 @@ def main(argv=None) -> int:
     runs = {
         2: lambda: config2_batch_verify(
             (64, 1024, 10_000) if args.full else (64, 256)),
-        3: lambda: config3_light_client(
-            1000 if args.full else 100),
         4: lambda: config4_replay_tally(150, 10 if args.full else 3),
         5: lambda: config5_mixed_stress(
             10_000 if args.full else 200,

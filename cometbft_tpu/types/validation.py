@@ -463,7 +463,7 @@ def _walk_commit(
         count_sig: Callable[[CommitSig], bool],
         count_all_signatures: bool, look_up_by_index: bool,
         cache: Optional[SignatureCache], strict: bool,
-        handle: Callable) -> int:
+        handle: Callable, sp=None) -> int:
     """The signature walk shared by the three verification paths
     (single / batch / grouped): ignore filter, optional structural
     validation, by-index or by-address validator lookup with
@@ -481,12 +481,18 @@ def _walk_commit(
     behavior); the same-type batch path omits it, mirroring the
     reference's verifyCommitBatch.  The nil-pubkey check is
     UNCONDITIONAL on every path — see the comment at the raise.
+
+    sp, the caller's ``commit_walk`` span, is told how far the walk
+    went (``walked`` signatures) and how many of them the cache
+    satisfied (``cache_hits``).
     """
     seen_vals: dict[int, int] = {}
     # block-id flag -> make(ts): the commit's sign-bytes template,
     # taken once a flag, not once a signature
     makers: dict[int, Callable] = {}
     tallied = 0
+    cache_hits = 0
+    idx = -1
     for idx, commit_sig in enumerate(commit.signatures):
         if ignore_sig(commit_sig):
             continue
@@ -499,10 +505,11 @@ def _walk_commit(
         if look_up_by_index:
             val = vals.validators[idx]
         else:
-            val_idx, val = vals.get_by_address(
+            val_idx = vals.index_by_address(
                 commit_sig.validator_address)
-            if val is None:
+            if val_idx < 0:
                 continue
+            val = vals.validators[val_idx]
             if val_idx in seen_vals:
                 raise VerificationError(
                     f"double vote from {val} "
@@ -529,15 +536,26 @@ def _walk_commit(
             cache_hit = (cv is not None and
                          cv.validator_address == val.pub_key.address() and
                          cv.vote_sign_bytes == vote_sign_bytes)
-        if not cache_hit:
-            if handle(idx, val, vote_sign_bytes, commit_sig) is False:
-                break
+        if cache_hit:
+            cache_hits += 1
+        elif handle(idx, val, vote_sign_bytes, commit_sig) is False:
+            break
 
         if count_sig(commit_sig):
             tallied += val.voting_power
         if not count_all_signatures and tallied > voting_power_needed:
             break
+    if sp is not None:
+        sp.note(walked=idx + 1, cache_hits=cache_hits)
     return tallied
+
+
+def _walk_span(look_up_by_index: bool):
+    """The ``commit_walk`` span of a batched path; _walk_commit adds
+    ``walked`` and ``cache_hits``."""
+    return tracing.span(
+        tracing.CONSENSUS, "commit_walk",
+        lookup="index" if look_up_by_index else "address")
 
 
 def _verify_commit_batch(
@@ -570,11 +588,11 @@ def _verify_commit_batch(
                 f"{commit_sig.signature.hex().upper()}") from e
         entries.append((idx, val.pub_key.address(), sign_bytes))
 
-    with tracing.span(tracing.CONSENSUS, "commit_walk"):
+    with _walk_span(look_up_by_index) as sp:
         tallied = _walk_commit(
             chain_id, vals, commit, voting_power_needed, ignore_sig,
             count_sig, count_all_signatures, look_up_by_index, cache,
-            strict=False, handle=handle)
+            strict=False, handle=handle, sp=sp)
 
     if tallied <= voting_power_needed:
         raise NotEnoughVotingPowerError(tallied, voting_power_needed)
@@ -654,11 +672,11 @@ def _verify_commit_grouped(
                 val.pub_key.address(), sign_bytes))
         return None
 
-    with tracing.span(tracing.CONSENSUS, "commit_walk"):
+    with _walk_span(look_up_by_index) as sp:
         tallied = _walk_commit(
             chain_id, vals, commit, voting_power_needed, ignore_sig,
             count_sig, count_all_signatures, look_up_by_index, cache,
-            strict=True, handle=handle)
+            strict=True, handle=handle, sp=sp)
 
     first_bad: Optional[int] = inline_bad
     for bv, entries in groups.values():

@@ -111,8 +111,9 @@ class TestLoadAgainstLiveNode:
 
 class TestBaselineBenchmarks:
     def test_configs_run_at_tiny_sizes(self):
-        """The BASELINE benchmark configs (#2-#5) execute and emit
-        sane timings (tools/benchmarks.py)."""
+        """The BASELINE benchmark configs (#2, #4, #5; #3 is the
+        benchmark's cell light-1k.skip) execute and emit sane timings
+        (tools/benchmarks.py)."""
         from cometbft_tpu.crypto import batch as crypto_batch
         from cometbft_tpu.tools import benchmarks as b
 
@@ -120,8 +121,6 @@ class TestBaselineBenchmarks:
         try:
             r2 = b.config2_batch_verify(sizes=(16,))
             assert r2["results_ms"]["16"] > 0
-            r3 = b.config3_light_client(n_vals=8, hops=2)
-            assert r3["value_ms"] > 0
             r4 = b.config4_replay_tally(n_vals=8, heights=2)
             assert r4["tally_ms_p50"] > 0
             r5 = b.config5_mixed_stress(n_vals=12, n_bls=4)

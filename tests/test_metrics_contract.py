@@ -294,6 +294,9 @@ _ALLOWED_LABELS = {
     "worker",       # verification workers: hard-coded names at the
                     # few SupervisedWorker construction sites
                     # (verify_stage / verify_kernel)
+    "outcome",      # how a light-client hop ended: three literals
+                    # (verified / cant_trust / invalid), bound once in
+                    # light/client.hop_counters
 }
 
 

@@ -64,6 +64,8 @@ def collect_catalog() -> list[dict]:
     crypto_pipeline.dispatch_histogram()
     ed25519_jax._refine_counter()
     signature_cache._metrics()
+    from cometbft_tpu.light import client as light_client
+    light_client.hop_counters()
     bls12381._agg_pk_metrics()
     types_validation.commit_verify_histogram()
     # verification pipeline: overlap ratio + tile rejects, and the
