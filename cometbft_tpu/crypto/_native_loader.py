@@ -19,10 +19,15 @@ import os
 import subprocess
 import sysconfig
 import threading
+import time
 from typing import Optional
 
 _mod = None
 _failed = False
+# load(allow_build=False) found nothing fresh on disk: the hot paths
+# (wire.encode runs once a message) take that answer until this
+# monotonic time and do not stat nine files a call meanwhile
+_miss_until = 0.0
 _build_lock = threading.Lock()
 
 
@@ -46,7 +51,8 @@ def _sources() -> list[str]:
             os.path.join(d, "sha512_mb.hpp"),
             os.path.join(d, "bls12381.hpp"),
             os.path.join(d, "ed25519_msm.hpp"),
-            os.path.join(d, "chacha20poly1305.hpp")]
+            os.path.join(d, "chacha20poly1305.hpp"),
+            os.path.join(d, "wire_codec.hpp")]
 
 
 def _host_tag() -> str:
@@ -124,13 +130,16 @@ def load(allow_build: bool = True):
     imports an already-built module.  Hot paths (merkle hashing runs
     inside the consensus loop) use that form; the node pre-builds in
     a thread at startup, and CLIs/tests build on first use."""
-    global _mod, _failed
+    global _mod, _failed, _miss_until
     if _mod is not None:
         return _mod
     if _failed or os.environ.get("COMETBFT_TPU_NATIVE", "1") == "0":
         return None
+    if not allow_build and time.monotonic() < _miss_until:
+        return None
     if not _target_fresh():
         if not allow_build:
+            _miss_until = time.monotonic() + 1.0
             return None
         if _build() is None:
             _failed = True

@@ -206,6 +206,25 @@ class Counter(_Metric):
         return "\n".join(lines)
 
 
+class CounterFunc(Counter):
+    """A counter family READ at scrape time: ``read()`` returns
+    {value of its one label: count}.  For counts their owner keeps as
+    plain integers because a metric object a call would cost more than
+    the work counted (wire/proto.py's codec: native / python /
+    declined)."""
+
+    def __init__(self, name: str, help_: str, label: str, read):
+        super().__init__(name, help_, (label,))
+        self._read = read
+
+    def series_count(self) -> int:
+        return len(self._read())
+
+    def _samples(self):
+        return [("", _fmt_labels(self.label_names, (k,)), float(v), None)
+                for k, v in sorted(self._read().items())]
+
+
 class Gauge(_Metric):
     kind = "gauge"
 
@@ -358,6 +377,11 @@ class Registry:
                 labels: Sequence[str] = ()) -> Counter:
         return self._register(Counter(
             f"{self.namespace}_{subsystem}_{name}", help_, labels))
+
+    def counter_func(self, subsystem: str, name: str, help_: str,
+                     label: str, read) -> CounterFunc:
+        return self._register(CounterFunc(
+            f"{self.namespace}_{subsystem}_{name}", help_, label, read))
 
     def gauge(self, subsystem: str, name: str, help_: str = "",
               labels: Sequence[str] = ()) -> Gauge:

@@ -10,12 +10,27 @@ Gogoproto-compatible semantics (reference: api/ generated marshalers):
   * unknown fields are skipped on decode (forward compatibility).
 
 Messages are plain dicts keyed by field name; absent == default.
+
+WHO executes a descriptor: encode()/decode() hand the call to the
+native executor (native/wire_codec.hpp, in the module
+crypto/_native_loader builds) when that module is loaded, exactly as
+crypto/merkle.py hands over a tree.  The walk in this file
+(_py_encode/_py_decode) is the reference, the COMETBFT_TPU_NATIVE=0
+path, and the answer to whatever the native executor declines (it
+returns None for a value or input it was not written for, so behaviour
+on odd inputs is this walk's).  The descriptors stay the ONE definition
+of the wire format; codec_stats() counts who answered.
 """
 from __future__ import annotations
 
 import struct
 from dataclasses import dataclass, field as dc_field
 from typing import Any, Optional, Sequence
+
+# imported whole, not `load` alone: a test that swaps the loader's
+# state or its `load` must reach this module too
+from ..crypto import _native_loader
+from ..libs import metrics as _libmetrics
 
 _MASK64 = (1 << 64) - 1
 
@@ -194,7 +209,7 @@ def _is_zero(kind: str, v: Any) -> bool:
     return int(v) == 0
 
 
-def encode(desc: Msg, d: dict) -> bytes:
+def _py_encode(desc: Msg, d: dict) -> bytes:
     out = bytearray()
     for f in desc.fields:
         v = d.get(f.name)
@@ -204,7 +219,7 @@ def encode(desc: Msg, d: dict) -> bytes:
             enc = f.enc
             if enc is None:                    # msg kind
                 for item in v:
-                    body = encode(f.msg, item)
+                    body = _py_encode(f.msg, item)
                     out += f.tag
                     _append_uvarint(out, len(body))
                     out += body
@@ -217,7 +232,7 @@ def encode(desc: Msg, d: dict) -> bytes:
                 if not f.always:
                     continue
                 v = {}
-            body = encode(f.msg, v)
+            body = _py_encode(f.msg, v)
             out += f.tag
             _append_uvarint(out, len(body))
             out += body
@@ -304,7 +319,7 @@ def _skip(data: bytes, pos: int, wt: int) -> int:
     raise ValueError(f"cannot skip wire type {wt}")
 
 
-def decode(desc: Msg, data: bytes) -> dict:
+def _py_decode(desc: Msg, data: bytes) -> dict:
     d: dict = {}
     pos = 0
     n = len(data)
@@ -324,7 +339,7 @@ def decode(desc: Msg, data: bytes) -> dict:
             if len(raw) != ln:
                 raise ValueError("truncated embedded message")
             pos += ln
-            v = decode(f.msg, raw)
+            v = _py_decode(f.msg, raw)
             if f.repeated:
                 d.setdefault(f.name, []).append(v)
             else:
@@ -340,6 +355,59 @@ def decode(desc: Msg, data: bytes) -> dict:
         if f.kind == "msg" and f.always and not f.repeated and f.name not in d:
             d[f.name] = {}
     return d
+
+
+# Calls the Python walk answered because no native module is loaded: a
+# plain integer, as the native module's own two, with no lock and no
+# metric object on a path every vote takes.  load(allow_build=False)
+# never compiles (this runs inside the consensus loop; the node
+# pre-builds at start-up, tests and CLIs build on first use).
+_python_calls = 0
+
+
+def encode(desc: Msg, d: dict) -> bytes:
+    global _python_calls
+    native = _native_loader.load(allow_build=False)
+    if native is not None:
+        out = native.wire_encode(desc, d)
+        if out is not None:
+            return out
+    else:
+        _python_calls += 1
+    return _py_encode(desc, d)
+
+
+def decode(desc: Msg, data: bytes) -> dict:
+    global _python_calls
+    native = _native_loader.load(allow_build=False)
+    if native is not None:
+        d = native.wire_decode(desc, data)
+        if d is not None:
+            return d
+    else:
+        _python_calls += 1
+    return _py_decode(desc, data)
+
+
+def codec_stats() -> dict:
+    """encode()/decode() calls of this process by who answered:
+    `native`, `python` (no native module loaded), `declined` (the
+    native executor handed the call back to the walk)."""
+    native = _native_loader.load(allow_build=False)
+    answered, declined = native.wire_stats() if native is not None else (0, 0)
+    return {"native": answered, "python": _python_calls,
+            "declined": declined}
+
+
+# read when /metrics is scraped (the process-global registry the
+# node's page merges in); nothing is counted through a metric object
+_libmetrics.DEFAULT.counter_func(
+    "wire", "codec_total",
+    "wire.proto encode()/decode() calls by who executed the "
+    "descriptor: native (the C++ executor), python (no native module "
+    "loaded: the reference walk), declined (the native executor "
+    "handed an input it was not written for back to the walk).",
+    "executor", codec_stats)
 
 
 def marshal_delimited(desc: Msg, d: dict) -> bytes:

@@ -25,6 +25,7 @@
 #include "ed25519_msm.hpp"
 #include "bls12381.hpp"
 #include "chacha20poly1305.hpp"
+#include "wire_codec.hpp"
 
 namespace {
 
@@ -1055,6 +1056,12 @@ PyMethodDef kMethods[] = {
     {"bls_g2_mul", bls_g2_mul, METH_VARARGS,
      "scalar multiple of a raw affine G2 point (k big-endian)"},
     {"sha256", sha256_one, METH_O, "SHA-256 of one bytes object"},
+    {"wire_encode", _PyCFunction_CAST(wire::wire_encode), METH_FASTCALL,
+     "wire/proto.py encode(desc, dict) -> bytes | None (declined)"},
+    {"wire_decode", _PyCFunction_CAST(wire::wire_decode), METH_FASTCALL,
+     "wire/proto.py decode(desc, bytes) -> dict | None (declined)"},
+    {"wire_stats", wire::wire_stats, METH_NOARGS,
+     "(calls the wire executor answered, calls it declined)"},
     {nullptr, nullptr, 0, nullptr},
 };
 

@@ -297,6 +297,9 @@ _ALLOWED_LABELS = {
     "outcome",      # how a light-client hop ended: three literals
                     # (verified / cant_trust / invalid), bound once in
                     # light/client.hop_counters
+    "executor",     # who ran a wire descriptor: three literals
+                    # (native / python / declined), the keys of
+                    # wire/proto.codec_stats
 }
 
 

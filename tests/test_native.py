@@ -80,6 +80,22 @@ class TestNative:
         monkeypatch.setattr(subprocess, "run", boom)
         assert _native_loader.load(allow_build=False) is None
 
+    def test_hot_path_remembers_a_miss(self, monkeypatch):
+        """wire.encode asks once a message: with nothing built, the
+        nine source files are not stat'ed again for a second; a load
+        that may build still looks at once."""
+        looked = []
+        monkeypatch.setattr(_native_loader, "_failed", False)
+        monkeypatch.setattr(_native_loader, "_mod", None)
+        monkeypatch.setattr(_native_loader, "_miss_until", 0.0)
+        monkeypatch.setattr(_native_loader, "_target_fresh",
+                            lambda: looked.append(1) and False)
+        monkeypatch.setattr(_native_loader, "_build", lambda: None)
+        for _ in range(50):
+            assert _native_loader.load(allow_build=False) is None
+        assert len(looked) == 1
+        assert _native_loader.load() is None and len(looked) == 2
+
 
 def _py_root(items):
     n = len(items)
