@@ -1,6 +1,7 @@
-"""Median `h2d` span under a warm `kernel_execute`:
-the four jax.device_put calls of one dispatch (they return before the
-copies finish)."""
+"""Median `h2d` span under a warm `kernel_execute`: the one
+jax.device_put of a dispatch's packed ``[bucket, 192]`` uint8 wire
+buffer (four calls before PR 26); it returns before the copy
+finishes."""
 from benchmark.lib import spantree
 
 

@@ -1,5 +1,6 @@
 """What a tiled `batch_verify` span spends outside its tiles: its
-duration less the `host_prep` of tile 0 (which nothing overlaps) less
+duration less the `host_prep` of tile 0 (no kernel overlaps it; since
+PR 28 it lies inside the commit's walk, on the walking thread) less
 the union of its `kernel_execute` spans (tile 0's launch to the last
 tile's settle; the later tiles' `host_prep` lies inside it), median
 over the tiled batches.  It holds the verifier wrappers' hand-over of

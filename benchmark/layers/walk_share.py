@@ -1,8 +1,10 @@
 """The Python walk's share of a commit verification: the median over
 the requests of `commit_walk` / the `commit_verify` span that holds
-it, in per cent.  The walk ends before the first tile is dispatched,
-so this is the part of a request during which the device has nothing
-to do."""
+it, in per cent.  Since PR 28 a full tile is dispatched from
+``BatchVerifier.add`` inside the walk, and since PR 30 tile 0's kernel
+runs under the walk's last milliseconds: this is the share of a
+request in which the host still has signatures to hand over, not a
+share the device waits through (PERF.md section 3)."""
 from benchmark.lib import spantree, stats
 
 

@@ -1,12 +1,14 @@
 """What one call of the verification kernel must move, from its shapes.
 
-The kernel (ops/ed25519_jax._pallas_verify_packed) takes, per padded
-lane, the packed uint8 wire layout — A and R (32 bytes each), the S and
-k 4-bit windows (64 bytes each) — and returns one mask byte.  On the
-device it widens them to int32 columns before the Pallas kernel reads
-them (4 bytes per element written and read once more).  These are the
-bytes the algorithm needs; scratch traffic inside VMEM is not HBM
-traffic and is not counted.
+The kernel (ops/ed25519_jax._pallas_verify_packed) takes ONE input,
+the packed ``[lanes, 192]`` uint8 wire buffer (since PR 26; four
+arrays before), a padded lane a row of A | R (32 bytes each) | the S
+and k 4-bit windows (64 bytes each), and returns one mask byte a lane.
+On the device it cuts the four column ranges (``wire_views``) and
+widens them to int32 columns before the Pallas kernel reads them (4
+bytes per element written and read once more).  These are the bytes
+the algorithm needs; scratch traffic inside VMEM is not HBM traffic
+and is not counted.
 """
 from __future__ import annotations
 

@@ -1,6 +1,7 @@
 """A tiled dispatch in the program's spans: the ``batch_verify`` spans
 whose batch went through the pipelined path
-(ops/ed25519_jax._verify_pipelined), each with its ``host_prep`` and
+(ops/ed25519_jax.TilePipeline, fed a tile at a time by ``verify_batch``
+or from ``BatchVerifier.add``), each with its ``host_prep`` and
 ``kernel_execute`` children in the order they started.
 
 Pure functions of the recorder's events, as lib/spantree's: spans

@@ -117,10 +117,17 @@ async def fabricate(chain_id: str, seed: int, n_validators: int,
 
 # -- fabrication in a child process ------------------------------------------
 # Data is made anew in every run and counts as set-up, and fabrication
-# is a second of host Python for every 35 heights: a child process (it
-# never imports JAX, so it never asks for the chip) makes the chain
-# while the parent sets the kernel's shapes up, and hands the two
-# stores over as key/value pairs.
+# is host Python that grows with the square of the chain: every block
+# is executed through the program's kvstore, whose root is taken over
+# ALL leaves at every height, 16 new keys a height, so a height costs
+# 18 ms at the start and 45 ms at height 1,500 (about 0.016 h +
+# 1.0e-5 h^2 seconds for h heights on the chip's host: 45 s for 1,500,
+# 73 for 2,000; PERF.md, PR 33).  A child process (it never imports JAX, so it never
+# asks for the chip) makes the chain while the parent sets the kernel's
+# shapes up, and hands the two stores over as key/value pairs.  The
+# parent makes the first heights itself meanwhile (``fabricate`` with
+# fewer ``heights``: the chain is a function of the seed, so they are
+# the child's first), for what it verifies before the child is done.
 
 def start_child(out_path: str, **kw) -> subprocess.Popen:
     env = dict(os.environ, JAX_PLATFORMS="cpu",

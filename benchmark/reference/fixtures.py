@@ -7,9 +7,10 @@ later PR can change what a cell verifies.  Everything derives from the
 seed; a fresh height is a fresh commit no memo or cache has seen.
 
 One change from the originals: signatures are made over
-Commit.vote_sign_bytes (the spliced template, ~8 us a vote) instead of
-building a Vote per validator (~70 us), because data is made anew in
-every run and counts as set-up.  ``check_sign_bytes`` pins the two
+Commit.vote_sign_bytes (the spliced template: ~1.1 us a vote on the CPU
+sandbox since PR 30's dedicated timestamp encoder, ~8 before) instead
+of building a Vote per validator (~70 us), because data is made anew
+in every run and counts as set-up.  ``check_sign_bytes`` pins the two
 paths against each other on seeded lanes.
 """
 from __future__ import annotations

@@ -59,6 +59,17 @@ def device_summary(busy=None) -> dict:
     return out
 
 
+def per_layer_metrics(bench, cell: str, obs) -> dict[str, dict]:
+    """A traced run's metrics: every per_layer entry of the cell through
+    its reader; one that finds nothing to read is left out."""
+    metrics = {}
+    for m in bench.metrics("per_layer", cell):
+        value = bench.reader(m["name"]).read(obs)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return metrics
+
+
 async def run_cell(ctx, driver) -> tuple[dict, list[str]]:
     """(the last line's object, what made the run incorrect)."""
     from benchmark.lib import probes, profile
@@ -185,10 +196,7 @@ async def run_cell(ctx, driver) -> tuple[dict, list[str]]:
                   laps=dict(ctx.laps),
                   device_kind=device_summary()["kind"],
                   trace=trace, trace_spans=trace_spans)
-        for m in ctx.bench.metrics("per_layer", ctx.cell.name):
-            value = ctx.bench.reader(m["name"]).read(obs)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        metrics = per_layer_metrics(ctx.bench, ctx.cell.name, obs)
         if trace is not None:
             breakdown = {
                 "device_ops": profile.top_ops(trace),

@@ -3,15 +3,22 @@ seeded chain from one in-process source peer through the program's
 BlocksyncReactor over localhost p2p; closed loop (the node asks for
 the next blocks as soon as it can).
 
-Set-up fabricates the chain from the seed (reference/chain.py), serves
-it from a BlocksyncReactor(active=False) on its own Switch, and starts
-a fresh node (fresh app, stores and state) syncing from it as a
-persistent peer.  Warm-up is the sync itself, until the warm-up rule is
-quiet; the window then counts the heights the node saved, by the
-timestamps the benchmark's own BlockStore puts on each save.  The chain
-must outlast warm-up plus window: if the node comes within
-``chain_margin`` heights of its end, the run fails instead of
-reporting a short window.
+Set-up fabricates the chain from the seed (reference/chain.py) in a
+child process and, meanwhile, in this one: the program's own warm-up of
+the device path, with the chain's first ``PREFIX_HEIGHTS`` made here
+from the same seed beside it, then pre-warm, the verifications the
+sync makes of those heights.  Only then does it wait for the child, and
+holds the child's chain to the prefix, block hash and app hash at every
+height, so that what pre-warm verified is the chain the node syncs.  It
+serves the chain from a BlocksyncReactor(active=False) on its own
+Switch, and starts a fresh node (fresh app, stores and state) syncing
+from it as a persistent peer.  Warm-up is the sync itself, until the
+warm-up rule is quiet; the window then counts the heights the node
+saved, by the timestamps the benchmark's own BlockStore puts on each
+save.  The chain must outlast warm-up plus window: if the node comes
+within ``chain_margin`` heights of its end, the run fails instead of
+reporting a short window, and names the rate the chain would have held
+(``ceiling``).
 
 One height inside warm-up (``forged_height``) is served the first time
 with a LastCommit forged below the 2/3 mark: the node must refuse it,
@@ -35,6 +42,9 @@ from benchmark.reference import chain as chainlib
 from benchmark.reference import fixtures
 
 CHAIN_ID = "bench-catchup"
+# the heights pre-warm may take to go quiet: it was quiet after 46 in
+# every run of PR 32, and fails past these
+PREFIX_HEIGHTS = 96
 
 
 class ServingStore:
@@ -92,6 +102,9 @@ class State:
     dst_state_store: object
     dst_app: object
     margin: int
+    seconds: float
+    window_from: int | None = None      # the node's height when the
+                                        # window opened
     setup_spans: list = field(default_factory=list)
     registries: tuple = ()
     done: asyncio.Event = field(default_factory=asyncio.Event)
@@ -126,52 +139,43 @@ async def set_up(ctx) -> State:
     chain_file = os.path.join(ctx.work_dir, f"chain-{ctx.seed}.pickle")
     child = chainlib.start_child(chain_file, **chain_kw)
     try:
-        # what a node does before it verifies anything (node.py start)
+        # what a node does before it verifies anything (node.py start),
+        # in a thread; beside it, on the loop, the chain's first heights
+        # from the same seed (no commit is verified in making them:
+        # nothing goes to the device)
+        prefix_kw = dict(chain_kw, heights=min(PREFIX_HEIGHTS,
+                                               chain_kw["heights"]))
         if crypto_batch.get_backend() == "tpu":
-            await asyncio.to_thread(warm_device_path, n)
+            _, prefix = await asyncio.gather(
+                asyncio.to_thread(warm_device_path, n),
+                chainlib.fabricate(**prefix_kw))
+        else:
+            prefix = await chainlib.fabricate(**prefix_kw)
         ctx.lap("warm_device_path")
+
+        # pre-warm: the verifications the sync makes of the chain's
+        # first heights, made directly and in a thread of their own,
+        # until the warm-up rule is quiet.  The program sets a kernel
+        # shape up inside the call that first needs it, and that costs
+        # three to four times as much from the reactor's deep stack on
+        # the event loop (where it also stalls the loop until the peer
+        # is dropped for a timeout) as from a shallow one (PERF.md,
+        # PR 22).  It runs over the prefix, so the child has the second
+        # cold shape's set-up to hide under as well as the first's.
+        pre = ctx.warmup_gate()
+        pre.min_ops = int(ctx.param("prewarm_ops"))
+        await asyncio.to_thread(prewarm, prefix, pre)
+        ctx.lap("prewarm")
         chain = await asyncio.to_thread(chainlib.load_child, child,
                                         chain_file, **chain_kw)
     finally:
         if child.poll() is None:
             child.kill()
         child.wait()
+    must_extend(prefix, chain)
+    del prefix
     gc.freeze()         # the chain is the benchmark's, not the program's
     ctx.lap("chain")
-
-    # pre-warm: the verifications the sync makes of the chain's first
-    # heights, made directly and in a thread of their own, until the
-    # warm-up rule is quiet.  The program sets a kernel shape up inside
-    # the call that first needs it, and that costs three to four times
-    # as much from the reactor's deep stack on the event loop (where it
-    # also stalls the loop until the peer is dropped for a timeout) as
-    # from a shallow one (PERF.md, PR 22).
-    pre = ctx.warmup_gate()
-    pre.min_ops = int(ctx.param("prewarm_ops"))
-
-    def prewarm() -> None:
-        from cometbft_tpu.types.block_id import BlockID
-        from cometbft_tpu.types.validation import (
-            verify_commit, verify_commit_light,
-        )
-        store, ids = chain.block_store, {}
-        for h in range(1, chain.height - 1):
-            block, nxt = store.load_block(h), store.load_block(h + 1)
-            ids[h] = BlockID(
-                hash=block.hash(),
-                part_set_header=block.make_part_set().header())
-            verify_commit_light(CHAIN_ID, chain.vset, ids[h], h,
-                                nxt.last_commit)
-            if h > 1:
-                verify_commit(CHAIN_ID, chain.vset, ids[h - 1], h - 1,
-                              block.last_commit)
-            pre.op_done()
-            if pre.done():
-                return
-        raise RuntimeError("pre-warm never went quiet")
-
-    await asyncio.to_thread(prewarm)
-    ctx.lap("prewarm")
 
     # source peer: serves blocks only
     mark = chain.vset.total_voting_power() * 2 // 3 \
@@ -203,7 +207,8 @@ async def set_up(ctx) -> State:
     state = State(chain=chain, serving=serving, src_switch=src_switch,
                   dst_switch=None, dst_reactor=None, dst_store=dst_bs,
                   dst_state_store=dst_ss, dst_app=dst_app,
-                  margin=int(ctx.param("chain_margin")))
+                  margin=int(ctx.param("chain_margin")),
+                  seconds=ctx.seconds)
 
     async def on_caught_up(st, height):
         state.done.set()
@@ -246,16 +251,69 @@ async def set_up(ctx) -> State:
     return state
 
 
+def prewarm(chain, gate) -> None:
+    """verify_commit_light and verify_commit over ``chain``'s heights,
+    as the sync makes them, until ``gate`` is quiet."""
+    from cometbft_tpu.types.block_id import BlockID
+    from cometbft_tpu.types.validation import (
+        verify_commit, verify_commit_light,
+    )
+    store, ids = chain.block_store, {}
+    for h in range(1, chain.height - 1):
+        block, nxt = store.load_block(h), store.load_block(h + 1)
+        ids[h] = BlockID(
+            hash=block.hash(),
+            part_set_header=block.make_part_set().header())
+        verify_commit_light(CHAIN_ID, chain.vset, ids[h], h,
+                            nxt.last_commit)
+        if h > 1:
+            verify_commit(CHAIN_ID, chain.vset, ids[h - 1], h - 1,
+                          block.last_commit)
+        gate.op_done()
+        if gate.done():
+            return
+    raise RuntimeError("pre-warm never went quiet")
+
+
+def must_extend(prefix, chain) -> None:
+    """The child's chain begins with the heights pre-warm verified."""
+    for h in range(1, prefix.height + 1):
+        if chain.block_hash.get(h) != prefix.block_hash[h] or \
+                chain.app_hash.get(h) != prefix.app_hash[h]:
+            raise RuntimeError(
+                f"the fabricated chain differs from the prefix "
+                f"pre-warm verified at height {h}: the fabricator is "
+                f"not a function of the seed")
+
+
+def ceiling(chain_heights: int, margin: int, window_from: int,
+            seconds: float) -> float:
+    """The fastest sync, in heights/s, that a chain of ``chain_heights``
+    outlasts when the window opens with the node at ``window_from``."""
+    return (chain_heights - margin - window_from) / seconds
+
+
 def _must_have_chain_left(state: State) -> None:
     left = state.chain.height - state.dst_store.height
     if left < state.margin or state.done.is_set():
         raise RuntimeError(
             f"the chain ran out: node at height "
             f"{state.dst_store.height} of {state.chain.height} "
-            f"(margin {state.margin}); lengthen chain_heights")
+            f"(margin {state.margin}); {_holds(state)}; lengthen "
+            f"chain_heights")
+
+
+def _holds(state: State) -> str:
+    if state.window_from is None:
+        return "the window had not opened"
+    rate = ceiling(state.chain.height, state.margin, state.window_from,
+                   state.seconds)
+    return (f"a {state.seconds:g} s window opened at height "
+            f"{state.window_from} holds at most {rate:.1f} heights/s")
 
 
 async def run(ctx, state: State, window) -> dict:
+    state.window_from = state.dst_store.height
     await asyncio.sleep(max(0.0, window.end - time.monotonic()))
     _must_have_chain_left(state)
     # the window is over: stop asking, so that nothing competes with
@@ -320,7 +378,8 @@ async def check(ctx, state: State, samples: dict) -> Outcome:
     in_window = samples["heights"]
     wrong = sum(1 for h in bad if samples["first_height"] <= h
                 < samples["first_height"] + in_window)
-    print(f"[catchup] synced to {top} of {chain.height}; window "
+    print(f"[catchup] synced to {top} of {chain.height} "
+          f"({_holds(state)}); window "
           f"heights {samples['first_height']}.."
           f"{samples['first_height'] + in_window - 1}; {len(bad)} "
           f"differ; {len(keys)} keys read back; forged block {f} "

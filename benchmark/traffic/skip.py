@@ -37,10 +37,8 @@ from __future__ import annotations
 
 import asyncio
 import gc
-import json
 import sys
 import time
-import types
 from dataclasses import dataclass, field
 
 from benchmark.lib import probes, schedule, spantree, stats
@@ -49,13 +47,6 @@ from benchmark.reference import fixtures, golden, skipping
 
 CHAIN_ID = "bench-light"
 EXIT_NO_PROGRAM = 3     # run.py's: the program is not in this checkout
-# the readers of the light client's spans (benchmark/layers/)
-LIGHT_LAYERS = (
-    "light_hops_per_request", "light_refusals_per_request",
-    "light_dispatches_per_request", "light_hop_ms", "light_refusal_ms",
-    "light_header_ms", "trusting_walk_ms", "sig_cache_hit_share",
-    "light_store_ms", "light_store_read_ms", "light_fetch_ms",
-    "light_unattributed_share")
 
 
 class Provider:
@@ -348,19 +339,6 @@ def expected_hop(hop) -> tuple:
     return (hop.trusted, hop.candidate, hop.outcome), checks
 
 
-def unlisted_layers(ctx, spans: list) -> dict:
-    """Those of LIGHT_LAYERS that BENCHMARK.json does not list for this
-    cell, read over ``spans``: the harness reads only what the manifest
-    lists, so a traced run prints these among its set-up facts until a
-    benchmark PR registers them (PERF.md, Open questions); then this
-    is empty."""
-    listed = {m["name"] for m in
-              ctx.bench.metrics("per_layer", ctx.cell.name)}
-    obs = types.SimpleNamespace(spans=spans)    # all that they read
-    return {name: ctx.bench.reader(name).read(obs)
-            for name in LIGHT_LAYERS if name not in listed}
-
-
 async def check(ctx, state: State, samples: dict) -> Outcome:
     from cometbft_tpu.libs import tracing
 
@@ -420,10 +398,6 @@ async def check(ctx, state: State, samples: dict) -> Outcome:
           f"{max(samples['lat_ms'], default=None)} ms, generator late "
           f"p95 {stats.percentile(samples['late_ms'], 95)} ms",
           flush=True)
-    unlisted = unlisted_layers(ctx, spans) if ctx.trace else {}
-    if unlisted:
-        print(f"[skip] light layers, not in the result line: "
-              f"{json.dumps(unlisted)}", flush=True)
     # a request the generator never reached before the window closed
     never = len(state.window) - len(results)
     return Outcome(attempted=len(state.window), failed=failed + never,

@@ -36,9 +36,8 @@ def compiles():
     return CompileLog()
 
 
-def run_tiny(cell_name, trace, seconds, compiles, tmp_path):
-    from cometbft_tpu.libs import tracing
-    old = tracing.recorder()
+def tiny_ctx(cell_name, trace, seconds, compiles, tmp_path):
+    """(a Ctx of the cell at TINY's sizes, the cell's traffic driver)"""
     bench = loader.Bench(ROOT)
     cell = bench.cell(cell_name)
     ctx = Ctx(bench, cell, seed=7, seconds=seconds, trace=trace,
@@ -46,11 +45,22 @@ def run_tiny(cell_name, trace, seconds, compiles, tmp_path):
               t_start=bench_run.time.monotonic())
     ctx.work_dir = str(tmp_path)
     ctx.overrides.update(TINY[cell_name])
+    return ctx, bench.traffic(cell.driver)
+
+
+def run_ctx(ctx, driver):
+    """run_cell's (result, problems), the recorder put back after."""
+    from cometbft_tpu.libs import tracing
+    old = tracing.recorder()
     try:
-        return asyncio.run(bench_run.run_cell(
-            ctx, bench.traffic(cell.driver)))
+        return asyncio.run(bench_run.run_cell(ctx, driver))
     finally:
         tracing.set_recorder(old)
+
+
+def run_tiny(cell_name, trace, seconds, compiles, tmp_path):
+    return run_ctx(*tiny_ctx(cell_name, trace, seconds, compiles,
+                             tmp_path))
 
 
 @pytest.mark.parametrize("trace", [False, True])

@@ -26,13 +26,11 @@ LAYER = dict.fromkeys(NEW, "light client") | {
     "sig_cache_hit_share": "commit verification",
     "light_fetch_ms": "light provider"}
 # the accepted metrics the cell is appended to whose readers read
-# spans alone ...
+# spans alone
 ACCEPTED = ["seam_ms", "fallback_share", "host_prep_ms",
             "kernel_execute_ms", "pad_share", "commit_walk_ms", "h2d_ms",
-            "launch_ms", "device_wait_ms", "d2h_ms"]
-# ... and the two it is not appended to: test_benchmark_eager pins
-# their list of cells
-PINNED = ["after_walk_ms", "eager_tiles_per_commit"]
+            "launch_ms", "device_wait_ms", "d2h_ms", "after_walk_ms",
+            "eager_tiles_per_commit"]
 with open(os.path.join(ROOT, "benchmark", "fixtures",
                        "spans_light1k.json")) as f:
     REC = json.load(f)
@@ -163,7 +161,7 @@ def test_readers_on_the_recorded_requests(metric, lo, hi):
     assert lo <= read(metric, REC["spans"]) <= hi
 
 
-@pytest.mark.parametrize("metric", ACCEPTED + PINNED)
+@pytest.mark.parametrize("metric", ACCEPTED)
 def test_accepted_readers_read_the_recorded_requests(metric):
     """The accepted readers, unedited, find something to read in the
     cell's spans."""
@@ -202,23 +200,17 @@ def test_a_program_without_the_spans_gives_nothing_to_read(metric):
 
 
 def test_what_the_cell_reports():
-    """The accepted metrics it was appended to.  The readers of the
-    light client's spans are files beside them that BENCHMARK.json does
-    not list yet: test_benchmark_eager holds after_walk_ms and
-    eager_tiles_per_commit to the list's last two places, and a PR
-    that changes the program may only append.  Whichever of them a
-    later PR lists has these fields."""
+    """The accepted metrics it was appended to, and the twelve readers
+    of the light client's spans, each an entry of its own with these
+    fields."""
     listed = {m["name"]: m for m in BENCH.manifest["per_layer"]}
     reported = [m["name"] for m in BENCH.metrics("per_layer", CELL)]
     for metric in ACCEPTED:
         assert CELL in listed[metric]["workloads"]
         assert metric in reported
-    for metric in PINNED:
-        assert CELL not in listed[metric]["workloads"]
     for metric in NEW:
         assert callable(BENCH.reader(metric).read)
-        if metric not in listed:
-            continue
+        assert reported.count(metric) == 1
         entry = dict(listed[metric])
         assert entry.pop("unit") == (
             "ms" if metric.endswith("_ms") else
@@ -231,17 +223,20 @@ def test_what_the_cell_reports():
             "workloads": [CELL]}
 
 
-def test_a_traced_run_prints_the_readers_the_manifest_does_not_list():
-    """traffic/skip.py reads, itself, exactly the readers of NEW that
-    the harness will not: none twice, none dropped."""
-    import types
-    skip = BENCH.traffic("skip")
-    assert list(skip.LIGHT_LAYERS) == NEW
-    ctx = types.SimpleNamespace(bench=BENCH, cell=BENCH.cell(CELL))
-    got = skip.unlisted_layers(ctx, REC["spans"])
-    reported = {m["name"] for m in BENCH.metrics("per_layer", CELL)}
-    assert list(got) == [m for m in NEW if m not in reported]
-    for metric, value in got.items():
-        assert value == read(metric, REC["spans"])
-    assert skip.unlisted_layers(ctx, OTHER["spans"]) == dict.fromkeys(
-        got)
+def test_a_traced_run_reads_every_light_reader_through_the_manifest():
+    """No reader of the light client's spans is left unlisted: the
+    harness's own reading of a traced run over the recorded requests
+    (run.py's per_layer_metrics) holds all twelve, each at its reader's
+    reading, beside the accepted ones; over a program without the
+    light client's spans it holds none of them."""
+    from benchmark.run import per_layer_metrics
+    line = per_layer_metrics(BENCH, CELL, obs(REC["spans"]))
+    assert [m for m in NEW + ACCEPTED if m not in line] == []
+    units = {m["name"]: m["unit"] for m in BENCH.manifest["per_layer"]}
+    for metric in NEW:
+        assert line[metric] == {"value": read(metric, REC["spans"]),
+                                "unit": units[metric]}
+    assert [line[m]["value"] for m in NEW[:3]] == [4, 3, 8]
+    other = per_layer_metrics(BENCH, CELL, obs(OTHER["spans"]))
+    assert [m for m in NEW if m in other] == []
+    assert "seam_ms" in other

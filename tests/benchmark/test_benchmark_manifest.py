@@ -123,3 +123,22 @@ def test_files_under_paths_use_only_the_allowed_characters():
                     continue
                 rel = os.path.relpath(os.path.join(d, name), ROOT)
                 assert ok.match(rel), rel
+
+
+# the readers of net-4.load, a cell whose files are in place and which
+# is not registered (PERF.md, Open questions)
+NET_4 = ("net_commit_verify_ms", "net_device_idle_share",
+         "net_fallback_share", "net_gen_late_p95_ms", "net_seam_ms",
+         "loop_lag_p95_ms", "txs_per_block", "block_interval_ms")
+READERS = sorted(
+    name[:-3] for name in os.listdir(os.path.join(ROOT, "benchmark",
+                                                  "layers"))
+    if name.endswith(".py") and name != "__init__.py")
+
+
+@pytest.mark.parametrize("reader", READERS)
+def test_every_reader_is_an_entry_or_a_named_net_4_reader(reader):
+    """A reader without an entry is read by no run and is no ledger
+    column: it cannot land silently (PR 31's twelve did)."""
+    listed = [m["name"] for m in M["per_layer"]]
+    assert (reader in listed) != (reader in NET_4), reader
