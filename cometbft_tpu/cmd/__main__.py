@@ -503,6 +503,63 @@ def cmd_version(args) -> int:
     return 0
 
 
+def cmd_replay(args) -> int:
+    """Play the node's consensus WAL back through the consensus state
+    machine over its own stores and app: no p2p, no signing, nothing
+    written to the WAL (reference: commands/replay.go +
+    internal/consensus/replay_file.go RunReplayFile)."""
+    import asyncio
+
+    cfg = _load_config(args.home)
+
+    async def run() -> list:
+        from ..abci.client import ClientCreator
+        from ..abci.kvstore import KVStoreApplication
+        from ..consensus.replay import Handshaker, playback
+        from ..crypto import batch as crypto_batch
+        from ..db import new_db
+        from ..state import make_genesis_state
+        from ..state.store import Store
+        from ..store import BlockStore
+        from ..types.genesis import GenesisDoc
+        db_dir = cfg.base.path(cfg.base.db_dir)
+        backend = cfg.base.db_backend
+        block_store = BlockStore(new_db("blockstore", backend, db_dir))
+        state_store = Store(new_db("state", backend, db_dir))
+        doc = GenesisDoc.from_file(cfg.base.path(cfg.base.genesis_file))
+        app = None
+        if cfg.base.abci in ("builtin", "builtin_unsync"):
+            if cfg.base.proxy_app not in ("kvstore",
+                                          "persistent_kvstore"):
+                raise SystemExit(
+                    f"unknown proxy_app {cfg.base.proxy_app!r}")
+            app = KVStoreApplication(db=new_db("app", backend, db_dir))
+        conns = ClientCreator(app=app, addr=cfg.base.proxy_app,
+                              transport=cfg.base.abci).new_app_conns()
+        await conns.start()
+        state = state_store.load()
+        if state is None:
+            state = make_genesis_state(doc)
+            state_store.save(state)
+        # as a node's start: the backend resolved once, off the loop
+        await asyncio.to_thread(crypto_batch.get_backend)
+        await Handshaker(state_store, state, block_store,
+                         doc).handshake(conns)
+        state = state_store.load() or state
+        return await playback(
+            cfg.consensus, state, state_store, block_store, conns,
+            cfg.base.path(cfg.consensus.wal_file),
+            to_height=args.to_height)
+
+    committed = asyncio.run(run())
+    if committed:
+        print(f"Replayed heights {committed[0]}..{committed[-1]} "
+              f"({len(committed)} committed)")
+    else:
+        print("Replayed the WAL: no height committed")
+    return 0
+
+
 def cmd_rollback(args) -> int:
     """Reference: commands/rollback.go + state/rollback.go."""
     from ..db import new_db
@@ -660,6 +717,12 @@ def main(argv=None) -> int:
     dd.add_argument("output_directory")
     dd.add_argument("--rpc-laddr", default="tcp://127.0.0.1:26657")
     dd.set_defaults(fn=cmd_debug_dump)
+
+    sp = sub.add_parser(
+        "replay", help="play the consensus WAL back over the stores")
+    sp.add_argument("--to-height", type=int, default=0,
+                    help="stop once this height is committed")
+    sp.set_defaults(fn=cmd_replay)
 
     sp = sub.add_parser("rollback", help="roll back one height")
     sp.add_argument("--hard", action="store_true",

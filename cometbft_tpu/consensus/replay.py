@@ -7,10 +7,13 @@ application.
 """
 from __future__ import annotations
 
+import itertools
 from typing import Optional
 
 from ..abci import types as abci
 from ..crypto import merkle
+from ..libs import metrics as libmetrics
+from ..libs import tracing
 from ..libs.log import Logger, new_logger
 from ..state.execution import (
     BlockExecutor, build_last_commit_info, update_state,
@@ -24,6 +27,8 @@ from ..types.validator import Validator
 from ..types.validator_set import ValidatorSet
 from .messages import message_from_wal
 from .round_state import TimeoutInfo
+from .state import ConsensusState
+from .ticker import NilTicker
 from .wal import WAL
 
 
@@ -294,6 +299,125 @@ class Handshaker:
                 f"hash {state.app_hash.hex()}")
 
 
+# process-global registry, as the vote counters it is read beside
+# (types/vote.py): a playback has no node
+_REPLAYED = libmetrics.DEFAULT.counter(
+    "consensus", "replay_heights_total",
+    "Heights committed by feeding the WAL's records back through the "
+    "state machine: crash recovery and the replay command.")
+
+# records a read-ahead may hold before the state machine is fed: a
+# height of up to ~5,000 validators is one burst (and one batch on the
+# device); a larger one is cut, and the rest pre-verified in the next
+READ_AHEAD = 10_240
+
+
+def _read_ahead(records) -> tuple[list, int, int]:
+    """Take records off the iterator of ``(record, frame bytes)`` up
+    to and including the next end-height marker, or ``READ_AHEAD`` of
+    them: (the inputs they stand for as ``_handle_burst`` takes them,
+    records read, bytes read).  ``round_state`` and ``end_height``
+    records carry no input (reference: replay.go readReplayMessage)."""
+    burst, n, size = [], 0, 0
+    for record, nbytes in records:
+        n += 1
+        size += nbytes
+        t = record.get("type")
+        if t == "end_height":
+            break
+        if t == "round_state":
+            continue
+        if t == "timeout":
+            # timeout-driven step transitions are replayed too
+            # (reference replay.go:142 dispatches timeoutInfo to
+            # handleTimeout) — otherwise a node that crashed right
+            # after e.g. a precommit-wait round advance restarts a
+            # round behind
+            burst.append(("timeout", TimeoutInfo(
+                duration_ns=0,
+                height=record.get("height", 0),
+                round=record.get("round", 0),
+                step=record.get("step", 0)), ""))
+        else:
+            burst.append(("peer", message_from_wal(record), ""))
+        if n >= READ_AHEAD:
+            break
+    return burst, n, size
+
+
+async def _feed(cs, records) -> tuple[int, bool]:
+    """Feed a state machine in replay mode the records of the
+    iterator, a read-ahead at a time, through the receive routine's
+    own burst handler: the votes a read-ahead holds are pre-verified
+    in one batch, then every record is handled in WAL order.  Stops
+    where the machine's height has advanced or the records end:
+    (inputs handled, whether records may be left)."""
+    height, n, read = cs.rs.height, 0, 1
+    while read and cs.rs.height == height:
+        with tracing.span(tracing.CONSENSUS, "wal_read") as sp:
+            burst, read, size = _read_ahead(records)
+            sp.note(records=read, bytes=size)
+        await cs._handle_burst(burst, fair=False)
+        n += len(burst)
+    _REPLAYED.add(cs.rs.height - height)
+    return n, bool(read)
+
+
+def _frames(wal_path: str):
+    for path in WAL.group_files(wal_path):
+        yield from WAL.iter_frames(path)
+
+
+async def playback(config, state: SMState, state_store: Store,
+                   block_store, app_conns, wal_path: str,
+                   to_height: int = 0, event_bus=None,
+                   logger: Optional[Logger] = None) -> list[int]:
+    """Play a consensus WAL back through a fresh state machine over
+    the node's stores, which ``Handshaker.handshake`` has reconciled
+    with the app (reference: replay_file.go RunReplayFile, the
+    ``replay`` command).  Every record from the end-height marker
+    below the state's height on (the whole WAL for a state at its
+    initial height) goes through the normal path, height after height,
+    until the WAL ends or ``to_height`` is committed.  It signs
+    nothing (no validator key), writes nothing to the WAL it reads and
+    starts no ticker.  Returns the heights it committed."""
+    block_exec = BlockExecutor(state_store, app_conns.consensus,
+                               event_bus=event_bus,
+                               block_store=block_store)
+    cs = ConsensusState(config, state, block_exec, block_store,
+                        event_bus=event_bus, logger=logger)
+    cs.ticker = NilTicker()
+    cs.replay_mode = True
+    records = _frames(wal_path)
+    start = cs.rs.height
+    if start > state.initial_height:
+        for record, _ in records:
+            if record.get("type") == "end_height" and \
+                    record.get("height") == start - 1:
+                break
+        else:
+            raise ReplayError(
+                f"cannot replay height {start}: WAL has no end-height "
+                f"marker for {start - 1}")
+    committed: list[int] = []
+    with tracing.span(tracing.CONSENSUS, "wal_replay",
+                      **{"from": start}) as root:
+        # the first record of a height is read before its span opens:
+        # a WAL that ends on a marker leaves no empty height behind
+        while (first := next(records, None)) is not None:
+            height = cs.rs.height
+            with tracing.span(tracing.CONSENSUS, "replay_height",
+                              height=height) as sp:
+                await _feed(cs, itertools.chain((first,), records))
+                done = cs.rs.height > height
+                sp.note(outcome="committed" if done else "stalled")
+            committed.extend(range(height, cs.rs.height))
+            if not done or (to_height and cs.rs.height > to_height):
+                break
+        root.note(to=cs.rs.height - 1)
+    return committed
+
+
 async def catchup_replay(cs, wal_path: str) -> int:
     """Re-feed WAL messages for the in-flight height into a fresh
     ConsensusState (reference: replay.go catchupReplay :97).
@@ -319,28 +443,13 @@ async def catchup_replay(cs, wal_path: str) -> int:
             tail = list(WAL.iter_group(wal_path))
         except FileNotFoundError:
             return 0
-    n = 0
+    records = ((record, 0) for record in tail)
+    n, more = 0, True
     cs.replay_mode = True
     try:
-        for record in tail:
-            t = record.get("type")
-            if t in ("round_state", "end_height"):
-                continue
-            if t == "timeout":
-                # replay timeout-driven step transitions too (reference
-                # replay.go:142 dispatches timeoutInfo to handleTimeout) —
-                # otherwise a node that crashed right after e.g. a
-                # precommit-wait round advance restarts a round behind
-                await cs._handle_timeout(TimeoutInfo(
-                    duration_ns=0,
-                    height=record.get("height", 0),
-                    round=record.get("round", 0),
-                    step=record.get("step", 0)))
-                n += 1
-                continue
-            msg = message_from_wal(record)
-            await cs._handle_msg(msg, "", internal=False)
-            n += 1
+        while more:
+            fed, more = await _feed(cs, records)
+            n += fed
     finally:
         cs.replay_mode = False
     return n

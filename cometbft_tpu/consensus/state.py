@@ -12,6 +12,7 @@ invariant holds (only that task mutates RoundState).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import time
 from typing import Callable, Optional
 
@@ -287,31 +288,15 @@ class ConsensusState:
                 # (peers, RPC, watchers) on a busy chain
                 await asyncio.sleep(0)
                 first = await self._input_queue.get()
-                # burst drain: batch-pre-verify the signatures of every
-                # queued vote in one shot (TPU kernel / native MSM by
-                # key type), then process the burst serially in the
-                # exact arrival order — the state machine sees the same
-                # sequence as unbatched processing, but vote storms pay
-                # one batched verification instead of per-vote ones
+                # burst drain: whatever else is queued is handled
+                # with it, its votes pre-verified in one batch
                 burst = [first]
                 while len(burst) < 256:
                     try:
                         burst.append(self._input_queue.get_nowait())
                     except asyncio.QueueEmpty:
                         break
-                if len(burst) > 1:
-                    await self._preverify_burst(burst)
-                for i, (kind, msg, peer_id) in enumerate(burst):
-                    if i:
-                        # keep the old per-message fairness yield: the
-                        # handlers have no guaranteed suspension point,
-                        # and a 256-message stretch would starve peers
-                        await asyncio.sleep(0)
-                    if kind == "timeout":
-                        await self._handle_timeout(msg)
-                    else:
-                        await self._handle_msg(
-                            msg, peer_id, internal=(kind == "internal"))
+                await self._handle_burst(burst)
             except asyncio.CancelledError:
                 raise
             except Exception:
@@ -322,11 +307,45 @@ class ConsensusState:
                 self.wal.flush_and_sync()
                 raise
 
-    async def _preverify_burst(self, burst) -> None:
-        """Collect the signatures of queued VoteMessages for the
-        CURRENT height's validator set and batch-verify them into the
+    async def _handle_burst(self, burst, fair: bool = True) -> None:
+        """Handle ``(kind, msg, peer_id)`` inputs in the order given,
+        the signatures of their votes batch-verified first in one shot
+        (TPU kernel / native MSM by key type): the state machine sees
+        the same sequence as unbatched processing, but a vote storm
+        pays one batched verification instead of one a vote.  THE
+        owner of that order for the receive routine, WAL playback and
+        crash recovery (replay.py) alike.  ``fair`` yields to the loop
+        between messages: the handlers have no guaranteed suspension
+        point, and a 256-message stretch would starve peers; a
+        playback has no one to be fair to."""
+        memo0, serial0 = vote_mod.verify_counts()
+        votes = await self._preverify_burst(burst) \
+            if len(burst) > 1 else 0
+        with tracing.span(tracing.CONSENSUS, "vote_tally") \
+                if votes else contextlib.nullcontext() as sp:
+            for i, (kind, msg, peer_id) in enumerate(burst):
+                if i and fair:
+                    await asyncio.sleep(0)
+                if kind == "timeout":
+                    await self._handle_timeout(msg)
+                else:
+                    await self._handle_msg(
+                        msg, peer_id, internal=(kind == "internal"))
+            if votes:
+                memo1, serial1 = vote_mod.verify_counts()
+                # serial: since before the batch, whose confirmation
+                # of a refused lane is this burst's serial verify
+                sp.note(votes=votes, memo_hits=memo1 - memo0,
+                        serial_verifies=serial1 - serial0)
+
+    async def _preverify_burst(self, burst) -> int:
+        """Collect the signatures of a burst's VoteMessages — the
+        CURRENT height's against its validator set, and the precommits
+        of the height before (the late ones, which go to LastCommit)
+        against the last set — and batch-verify them into the
         verified-triple memo (types/vote.py) — the tally-path batching
         the reference leaves per-vote (SURVEY: vote_set.go:219-236).
+        Returns the number of votes in the burst.
 
         The batch itself runs OFF the event loop, on the verification
         staging worker (crypto/pipeline.py): this await is a verdict
@@ -338,14 +357,44 @@ class ConsensusState:
         the same serial order as before.  Purely advisory: lookup
         failures or invalid signatures are left for the serial path,
         whose verdicts do not change."""
-        entries = []
-        for kind, msg, _peer in burst:
-            if kind == "timeout" or not isinstance(msg, VoteMessage):
+        votes = [msg.vote for kind, msg, _peer in burst
+                 if kind != "timeout" and isinstance(msg, VoteMessage)]
+        if len(votes) < 2:
+            return len(votes)
+        with tracing.span(tracing.CONSENSUS, "vote_preverify") as sp:
+            entries, late = self._burst_entries(votes)
+            sp.note(entries=len(entries), late=late)
+            if len(entries) < 2:
+                return len(votes)
+            try:
+                sp.note(fresh=await asyncio.wrap_future(
+                    vote_mod.preverify_signatures_async(entries,
+                                                        under=sp)))
+            except Exception:
+                # advisory: a worker failure just means the serial
+                # tally verifies each signature itself
+                self.logger.debug(
+                    "burst pre-verification failed (serial tally "
+                    "decides)", exc_info=True)
+        return len(votes)
+
+    def _burst_entries(self, votes) -> tuple[list, int]:
+        """(the signature triples of ``votes`` that name a validator
+        of their set, how many of them are late precommits')."""
+        entries: list = []
+        late = 0
+        rs = self.rs
+        for vote in votes:
+            if vote is None:
                 continue
-            vote = msg.vote
-            if vote is None or vote.height != self.rs.height:
+            is_late = vote.height + 1 == rs.height and \
+                vote.type == canonical.PRECOMMIT_TYPE
+            if is_late:
+                vals = rs.last_validators
+            elif vote.height == rs.height:
+                vals = rs.validators
+            else:
                 continue
-            vals = self.rs.validators
             if (vals is None or vote.validator_index < 0 or
                     vote.validator_index >= vals.size()):
                 continue
@@ -353,18 +402,12 @@ class ConsensusState:
             if (val.pub_key is None or
                     val.pub_key.address() != vote.validator_address):
                 continue
+            n = len(entries)
             self._append_vote_entries(
                 entries, vote, val.pub_key, self.sm_state.chain_id)
-        if len(entries) >= 2:
-            try:
-                await asyncio.wrap_future(
-                    vote_mod.preverify_signatures_async(entries))
-            except Exception:
-                # advisory: a worker failure just means the serial
-                # tally verifies each signature itself
-                self.logger.debug(
-                    "burst pre-verification failed (serial tally "
-                    "decides)", exc_info=True)
+            if is_late:
+                late += len(entries) - n
+        return entries, late
 
     def _append_vote_entries(self, entries, vote, pub_key,
                              chain_id: str) -> None:
@@ -438,8 +481,11 @@ class ConsensusState:
             try:
                 await self._try_add_vote(msg.vote, peer_id)
             except (VoteSetError, HeightVoteSetError, VoteError) as e:
+                vote = msg.vote
                 self.logger.error("failed to add vote", err=str(e),
-                                  peer=peer_id)
+                                  peer=peer_id, height=vote.height,
+                                  type=vote.type,
+                                  index=vote.validator_index)
         elif isinstance(msg, AggregateCommitMessage):
             try:
                 await self._try_add_aggregate_commit(msg.commit,
@@ -1361,7 +1407,9 @@ class ConsensusState:
         if rs.proposal_block is None or \
                 rs.proposal_block.hash() != block_id.hash:
             return
-        await self._finalize_commit(height)
+        with tracing.span(tracing.CONSENSUS, "finalize_commit",
+                          height=height):
+            await self._finalize_commit(height)
 
     async def _finalize_commit(self, height: int) -> None:
         """Reference: finalizeCommit (:1834), split for the commit

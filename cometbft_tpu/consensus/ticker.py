@@ -46,3 +46,15 @@ class TimeoutTicker:
         if self._task is not None and not self._task.done():
             self._task.cancel()
         self._current = None
+
+
+class NilTicker:
+    """No timeouts at all: a WAL playback is driven by its records,
+    the ``timeout`` ones among them (reference: replay_file.go plays
+    a state that was never started)."""
+
+    def schedule_timeout(self, ti: TimeoutInfo) -> None:
+        pass
+
+    def stop(self) -> None:
+        pass

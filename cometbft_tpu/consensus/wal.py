@@ -163,30 +163,40 @@ class WAL:
     def iter_messages(path: str, strict: bool = False) -> Iterator[dict]:
         """Decode records; on a torn tail (crash mid-write) stop unless
         strict."""
+        for msg, _ in WAL.iter_frames(path, strict=strict):
+            yield msg
+
+    @staticmethod
+    def iter_frames(path: str, strict: bool = False
+                    ) -> Iterator[tuple[dict, int]]:
+        """(record, bytes of its frame) for one file, read a frame at
+        a time: a playback of a long WAL holds one record, not the
+        file.  A torn tail (crash mid-write: a header or payload cut
+        short) is a clean stop unless strict; a whole frame that fails
+        its CRC or claims more than the size limit is corruption,
+        always an error — the rule of ``_scan_valid_prefix``."""
         with open(path, "rb") as f:
-            data = f.read()
-        good = _scan_valid_prefix(data)
-        pos = 0
-        while pos < good:
-            crc, length = struct.unpack(">II", data[pos:pos + 8])
-            yield json.loads(data[pos + 8:pos + 8 + length])
-            pos += 8 + length
-        if good < len(data):
-            # distinguish a torn tail (clean-stop unless strict) from
-            # mid-file corruption (always an error)
-            tail = len(data) - good
-            if tail >= 8:
-                crc, length = struct.unpack(">II",
-                                            data[good:good + 8])
-                if length <= MAX_MSG_SIZE_BYTES and \
-                        len(data) - good - 8 >= length:
-                    raise CorruptWALError(
-                        f"crc mismatch at offset {good}")
-                if length > MAX_MSG_SIZE_BYTES:
-                    raise CorruptWALError(
-                        f"frame too large: {length}")
-            if strict:
-                raise CorruptWALError("truncated frame")
+            pos = 0
+            while True:
+                head = f.read(8)
+                if not head:
+                    return
+                if len(head) == 8:
+                    crc, length = struct.unpack(">II", head)
+                    if length > MAX_MSG_SIZE_BYTES:
+                        raise CorruptWALError(
+                            f"frame too large: {length}")
+                    payload = f.read(length)
+                    if len(payload) == length:
+                        if zlib.crc32(payload) & 0xFFFFFFFF != crc:
+                            raise CorruptWALError(
+                                f"crc mismatch at offset {pos}")
+                        yield json.loads(payload), 8 + length
+                        pos += 8 + length
+                        continue
+                if strict:
+                    raise CorruptWALError("truncated frame")
+                return
 
     @staticmethod
     def search_for_end_height(path: str, height: int

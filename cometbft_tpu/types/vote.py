@@ -8,6 +8,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field, replace
 
 from ..crypto.keys import PubKey
+from ..libs import metrics as _libmetrics
+from ..libs import tracing as _tracing
 from . import canonical
 from .block_id import BlockID
 from .part_set import PartSetError
@@ -87,15 +89,39 @@ def _memo_reject(key: tuple[bytes, bytes, bytes]) -> None:
         _REJECTED.popitem(last=False)
 
 
-def checked_verify(pub_key: PubKey, msg: bytes, sig: bytes) -> bool:
-    """pub_key.verify_signature with the verified/rejected memos."""
-    key = _memo_key(pub_key, msg, sig)
-    if key in _VERIFIED:
-        _VERIFIED.move_to_end(key)
-        return True
-    if key in _REJECTED:
-        _REJECTED.move_to_end(key)
-        return False
+# How a vote's signature was judged on the serial path, and what the
+# batches left in the memos: plain integers, read at scrape time as
+# cometbft_consensus_vote_verify_total{path} and
+# cometbft_consensus_vote_preverified_total{verdict} (a metric object
+# a call would cost more than the dict lookup counted).  A node whose
+# votes arrive in bursts shows "memo"; "serial" is one signature
+# verified on the CPU: a vote no batch covered, or the confirmation
+# of a lane a batch refused.
+_VERIFY_COUNTS = {"memo": 0, "serial": 0}
+_PREVERIFIED = {"valid": 0, "invalid": 0, "unjudged": 0}
+
+
+def verify_counts() -> tuple[int, int]:
+    """(memo answers, serial verifications) so far in this process."""
+    return _VERIFY_COUNTS["memo"], _VERIFY_COUNTS["serial"]
+
+
+_libmetrics.DEFAULT.counter_func(
+    "consensus", "vote_verify_total",
+    "Vote signatures judged on the serial path, by how: answered by "
+    "the verified/rejected memo a batch filled, or verified one by "
+    "one on the CPU.", "path", lambda: _VERIFY_COUNTS)
+_libmetrics.DEFAULT.counter_func(
+    "consensus", "vote_preverified_total",
+    "Vote signatures a burst pre-verification put through a batch "
+    "verifier, by the verdict it left in the memo (unjudged: left to "
+    "the serial path).", "verdict", lambda: _PREVERIFIED)
+
+
+def _serial_verify(pub_key: PubKey, msg: bytes, sig: bytes,
+                   key: tuple[bytes, bytes, bytes]) -> bool:
+    """One signature on the CPU; its verdict enters the memos."""
+    _VERIFY_COUNTS["serial"] += 1
     ok = pub_key.verify_signature(msg, sig)
     if ok:
         _memo_add(key)
@@ -104,12 +130,28 @@ def checked_verify(pub_key: PubKey, msg: bytes, sig: bytes) -> bool:
     return ok
 
 
-def preverify_signatures(entries) -> None:
+def checked_verify(pub_key: PubKey, msg: bytes, sig: bytes) -> bool:
+    """pub_key.verify_signature with the verified/rejected memos."""
+    key = _memo_key(pub_key, msg, sig)
+    if key in _VERIFIED:
+        _VERIFIED.move_to_end(key)
+        _VERIFY_COUNTS["memo"] += 1
+        return True
+    if key in _REJECTED:
+        _REJECTED.move_to_end(key)
+        _VERIFY_COUNTS["memo"] += 1
+        return False
+    return _serial_verify(pub_key, msg, sig, key)
+
+
+def preverify_signatures(entries) -> int:
     """Batch-verify (pub_key, msg, sig) triples and memoize both
-    verdicts.  Never raises and proves nothing on its own: entries the
-    batch could not judge (None mask — unsupported key type, malformed
-    input, singleton group, verifier error) are left for the caller's
-    serial path to verify and reject with its own errors.
+    verdicts; returns how many of them were fresh (in neither memo)
+    and so went to a batch.  Never raises and proves nothing on its
+    own: entries the batch could not judge (None mask — unsupported
+    key type, malformed input, singleton group, verifier error) are
+    left for the caller's serial path to verify and reject with its
+    own errors.
 
     A False mask entry is confirmed by ONE serial verify before it
     enters the negative memo: the CPU/BLS batch verifiers' reject
@@ -131,29 +173,39 @@ def preverify_signatures(entries) -> None:
         fresh.append((pub_key, msg, sig))
         keys.append(key)
     if len(fresh) < 2:
-        return
+        return 0
     mask = crypto_batch.batch_verify_by_type(fresh)
     for (pub_key, msg, sig), key, good in zip(fresh, keys, mask):
         if good:
             _memo_add(key)
-        elif good is not None:
-            if pub_key.verify_signature(msg, sig):
-                _memo_add(key)           # batch false-negative fixed
-            else:
-                _memo_reject(key)
+            _PREVERIFIED["valid"] += 1
+        elif good is None:
+            _PREVERIFIED["unjudged"] += 1
+        elif _serial_verify(pub_key, msg, sig, key):
+            _PREVERIFIED["valid"] += 1   # batch false-negative fixed
+        else:
+            _PREVERIFIED["invalid"] += 1
+    return len(fresh)
 
 
-def preverify_signatures_async(entries):
+def preverify_signatures_async(entries, under=None):
     """``preverify_signatures`` on the verification staging worker:
-    returns a concurrent Future that resolves (to None) once the
-    burst's verdicts are memoized — the consensus receive routine
-    awaits it as a verdict barrier while the event loop keeps
-    draining gossip (consensus/state.py).  Memo reads/writes are
-    single-op dict mutations, atomic under the GIL, so the worker
-    and the loop-side ``checked_verify`` interleave safely; the memo
-    is advisory either way (a miss just re-verifies serially)."""
+    returns a concurrent Future that resolves (to the number of fresh
+    entries) once the burst's verdicts are memoized — the consensus
+    receive routine awaits it as a verdict barrier while the event
+    loop keeps draining gossip (consensus/state.py).  ``under`` is the
+    caller's open span: the worker's context is its own, so the
+    seam's ``batch_verify`` span is made its child by name.  Memo
+    reads/writes are single-op dict mutations, atomic under the GIL,
+    so the worker and the loop-side ``checked_verify`` interleave
+    safely; the memo is advisory either way (a miss just re-verifies
+    serially)."""
     from ..crypto import pipeline
-    return pipeline.submit(preverify_signatures, entries)
+
+    def run() -> int:
+        with _tracing.under(under):
+            return preverify_signatures(entries)
+    return pipeline.submit(run)
 
 
 @dataclass

@@ -300,6 +300,12 @@ _ALLOWED_LABELS = {
     "executor",     # who ran a wire descriptor: three literals
                     # (native / python / declined), the keys of
                     # wire/proto.codec_stats
+    "path",         # how a vote's signature was judged on the serial
+                    # path: two literals (memo / serial), the keys of
+                    # types/vote._VERIFY_COUNTS
+    "verdict",      # what a burst pre-verification left in the memo:
+                    # three literals (valid / invalid / unjudged), the
+                    # keys of types/vote._PREVERIFIED
 }
 
 

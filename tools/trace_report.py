@@ -61,6 +61,14 @@ CONSENSUS_SPAN_BUCKETS = {
     # names, added to no bucket
     "commit_verify": None,
     "commit_walk": None,
+    # a burst and a WAL playback (consensus/state.py, replay.py):
+    # frames around the step, state and crypto spans already summed
+    "vote_preverify": None,
+    "vote_tally": None,
+    "finalize_commit": None,
+    "wal_replay": None,
+    "replay_height": None,
+    "wal_read": None,
 }
 
 # state span -> bucket (pinned the same way): the executor and the
