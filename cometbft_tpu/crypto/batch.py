@@ -14,11 +14,12 @@ TPU-native addition: a process-global backend selector, set with
              resolved once per process, blocking, and logged.
 
 The device verifier does not wait for ``verify()``: the moment
-``add()`` has filled one pipeline tile (4,096 lanes) it dispatches
-that tile, on the adding thread, and the kernel runs while the caller
-goes on adding; ``verify()`` dispatches the remainder and settles
-(GuardedTpuBatchVerifier).  A batch below one tile is one dispatch
-from ``verify()``, as ever.
+``add()`` has filled one pipeline tile (1,024 lanes) it hands that
+tile to the device pipeline, whose host prep runs on a native thread
+beside the adding one; the tile is launched by the next hand-off and
+its kernel runs while the caller goes on adding; ``verify()`` hands
+over the remainder and settles (GuardedTpuBatchVerifier).  A batch
+below one tile is one dispatch from ``verify()``, as ever.
 
 Every verifier this module hands out also answers ``verify_async()``
 (keys.BatchVerifier): an awaitable verdict future whose work runs on
@@ -244,31 +245,37 @@ class GuardedTpuBatchVerifier(BatchVerifier):
     """TPU batch verifier behind the process-global circuit breaker,
     which starts before its caller has finished adding.
 
-    add() appends and compares a length; when the items not yet
-    dispatched fill one pipeline tile (crypto/pipeline.tile_bucket,
-    4,096 lanes) and the breaker is closed,
-    exactly that many go to the device then and there
-    (ops/ed25519_jax.TilePipeline.feed), so the kernel runs while the
-    caller is still walking its commit.  verify() feeds the remainder
-    at the same shape and settles: a 10,000-validator commit waits
-    for one kernel, not two.  A batch that never fills a tile —
-    every validator set below 4,096 — is untouched: verify() makes
-    the one dispatch it always made.  What the verifier does depends
-    on the number of items added and on nothing else.
+    add() appends the raw triple the native prep reads and compares a
+    length; when the items not yet handed over fill one pipeline tile
+    (crypto/pipeline.tile_bucket, 1,024 lanes) and the breaker is
+    closed, exactly that many go to the device pipeline then and
+    there (ops/ed25519_jax.TilePipeline.feed): their host prep
+    begins on the native module's prep thread and the tile handed
+    over before them, whose prep is done by now, is launched, so the
+    kernels run while the caller is still walking its commit and a
+    streamed tile costs the adding thread its hand-off and a launch.
+    verify() feeds the remainder at the same shape and settles: of a
+    10,000-validator commit's seven tiles, five are launched inside
+    the walk.  A batch that never fills a tile — every validator set
+    below 1,024 signatures a batch — is untouched: verify() makes the
+    one dispatch it always made.  What the verifier does depends on
+    the number of items added and on nothing else.
 
-    A verifier dropped with a tile in flight (the walk raised, the
-    tally fell short) leaves nothing behind: the device finishes a
-    kernel nobody reads, no span of it records, the breaker hears
+    A verifier dropped with a prep or a tile in flight (the walk
+    raised, the tally fell short) leaves nothing behind: the prep's
+    handle takes its job back or lets it finish, the device finishes
+    kernels nobody reads, no span of them records, the breaker hears
     nothing.  The breaker is asked for a probe only by verify(),
-    which always reports back; add() dispatches under a closed
-    breaker alone.
+    which always reports back; add() feeds under a closed breaker
+    alone.
 
     verify() attempts the device kernel only while the breaker admits
     it; a dispatch failure, in add() or in verify(), records against
     the breaker (latched open for non-transient faults, so the failing
     kernel is attempted at most once per process), is logged with its
     type and message, and the SAME batch, whole, falls back to the CPU
-    verifier — callers always get a verdict.
+    verifier, which rebuilds the keys from their bytes — callers
+    always get a verdict.
 
     The batch_verify span opens when the verifier first does work on
     the device path — the first tile fed from add(), else verify() —
@@ -279,7 +286,8 @@ class GuardedTpuBatchVerifier(BatchVerifier):
 
     def __init__(self, breaker=None):
         self._breaker = breaker if breaker is not None else tpu_breaker()
-        self._items: list[tuple[PubKey, bytes, bytes]] = []
+        # (pub, msg, sig) as bytes: what the native prep reads
+        self._items: list[tuple[bytes, bytes, bytes]] = []
         self._tile = tile_bucket()
         self._feed_at = self._tile  # len(_items) at which add() feeds
         self._fed = 0               # items handed to the pipeline
@@ -294,7 +302,7 @@ class GuardedTpuBatchVerifier(BatchVerifier):
         if len(sig) != 64:
             raise ValueError("malformed signature")
         items = self._items
-        items.append((pub_key, bytes(msg), bytes(sig)))
+        items.append((pub_key.bytes(), bytes(msg), bytes(sig)))
         if len(items) >= self._feed_at:
             self._feed_eagerly()
 
@@ -328,22 +336,8 @@ class GuardedTpuBatchVerifier(BatchVerifier):
             self._pipe = TilePipeline(self._tile)
         lo, self._fed = self._fed, min(self._fed + self._tile,
                                        len(self._items))
-        self._hand_over(lo, self._fed,
-                        lambda raw: self._pipe.feed(raw, eager=eager))
-
-    def _hand_over(self, lo: int, hi: int, run):
-        """run(items[lo:hi] as raw triples), under the seam's span.
-        The triples are freed here, with an explicit ``del``, so that
-        their release (1 ms for 6,667 on a v5e's host: PERF.md, PR 27)
-        lies inside the span, and for a tile inside its flight."""
         with tracing.under(self._span):
-            with tracing.span(tracing.CRYPTO, "item_handover"):
-                raw = [(pk.bytes(), m, s)
-                       for pk, m, s in self._items[lo:hi]]
-            out = run(raw)
-            with tracing.span(tracing.CRYPTO, "item_release"):
-                del raw
-        return out
+            self._pipe.feed(self._items[lo:self._fed], eager=eager)
 
     def _begin_span(self) -> None:
         self._span = tracing.timed(tracing.CRYPTO, "batch_verify",
@@ -381,7 +375,8 @@ class GuardedTpuBatchVerifier(BatchVerifier):
                 return self._pipe.finish()
         self._begin_span()
         from ..ops.ed25519_jax import verify_batch
-        return self._hand_over(0, len(self._items), verify_batch)
+        with tracing.under(self._span):
+            return verify_batch(self._items)
 
     def verify(self):
         br = self._breaker
@@ -405,8 +400,8 @@ class GuardedTpuBatchVerifier(BatchVerifier):
                           batch=len(self._items), backend="cpu",
                           fallback=attempted_tpu):
             cpu = ed25519.CpuBatchVerifier()
-            for pk, m, s in self._items:
-                cpu.add(pk, m, s)
+            for pub, m, s in self._items:
+                cpu.add(ed25519.Ed25519PubKey(pub), m, s)
             out = cpu.verify()
         _observe_verify("cpu", len(self._items),
                         time.perf_counter() - t0)

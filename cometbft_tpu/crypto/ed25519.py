@@ -188,8 +188,8 @@ class CpuBatchVerifier(BatchVerifier):
     verified individually to produce the exact validity mask, the
     same fallback contract as the TPU path.
 
-    Batches larger than one pipeline tile (crypto/pipeline.py,
-    default 4096) verify as a tiled pipeline through the native tile
+    Batches larger than one CPU pipeline tile (crypto/pipeline
+    MSM_TILE, 4096) verify as a tiled pipeline through the native tile
     kernel: tile i runs GIL-free on the kernel worker while this
     thread packs and stages tile i+1 and settles tile i-1, and a
     reject bisects WITHIN its tile — one bad signature in a 10k
@@ -227,7 +227,7 @@ class CpuBatchVerifier(BatchVerifier):
                 try:
                     from . import pipeline
                     if not self._monolithic and \
-                            n > pipeline.TILE:
+                            n > pipeline.MSM_TILE:
                         return pipeline.verify_items_pipelined(
                             native, raw, self._verify_one)
                     if self._batch_holds(native, raw):

@@ -6,7 +6,7 @@ was 452 ms e2e against 116 ms device-only, and on the CPU backend a
 10k native batch blocks whatever thread dispatches it for ~170 ms.
 This module makes verification a pipeline instead of a blocking call:
 
-  * the batch splits into pad-bucket tiles (4096 lanes, the
+  * the batch splits into pad-bucket tiles (MSM_TILE, 4096 lanes, the
     kernel ladder's mid bucket — small enough that one bad signature
     bisects inside its own tile, large enough that the Pippenger MSM
     keeps most of its batch efficiency);
@@ -61,9 +61,17 @@ BASE_BUCKETS = (64, 1024, 4096, 10240, 16384)
 # tuner (ops/ed25519_jax._tune_record) inserts refined buckets and
 # test hooks write it, always in place — nothing rebinds it
 BUCKETS = list(BASE_BUCKETS)
-# the pipeline tile in lanes: a pad-bucket shape, so CPU tiles and TPU
-# tiles label the same histogram buckets
-TILE = 4096
+# the DEVICE pipeline's tile in lanes, a pad-bucket shape: the granule
+# at which the seam's verifier streams from ``add()`` and the one shape
+# of every chunk above it.  1,024 and not 4,096 since PR 36: a
+# 10,000-validator commit (6,667 signatures) starts its first kernel
+# after 2,048 signatures walked, pads to 7,168 lanes and not 8,192,
+# and a tile's host prep (1.2 ms) hides under the walk of the next
+TILE = 1024
+# the CPU pipeline's tile (verify_items_pipelined): the Pippenger MSM
+# loses batch efficiency below it (10,000 signatures on the CPU
+# sandbox, PR 36: ~96 ms at 4,096, ~104 at 1,024), so it keeps its own
+MSM_TILE = 4096
 
 
 def bucket(n: int) -> int:
@@ -83,7 +91,7 @@ def tile_bucket() -> int:
     return bucket(TILE)
 
 
-def tile_plan(n: int, tile: Optional[int] = None) -> list:
+def tile_plan(n: int, tile: int) -> list:
     """[(start, end), ...] covering n lanes in BALANCED slices of at
     most ``tile`` lanes: 10k at tile 4096 plans three ~3334-lane
     tiles, not 4096+4096+1808.  Balancing matters twice — the
@@ -91,7 +99,7 @@ def tile_plan(n: int, tile: Optional[int] = None) -> list:
     the signed-digit MSM's per-tile bucket sweep amortizes best when
     no tile is small (measured ~3% fewer point adds at the 10k
     shape)."""
-    t = tile or TILE
+    t = tile
     if n <= 0:
         return []
     ntiles = -(-n // t)
@@ -255,7 +263,7 @@ def verify_items_pipelined(
     n = len(items)
     if n == 0:
         return True, []
-    t = tile or TILE
+    t = tile or MSM_TILE
     pad_bucket = str(t)
     plan = tile_plan(n, t)
     mask = [True] * n
@@ -340,7 +348,7 @@ def verify_items_pipelined(
     return all(mask), mask
 
 
-__all__ = ["BASE_BUCKETS", "BUCKETS", "TILE", "bucket", "tile_bucket",
-           "tile_plan", "verify_items_pipelined", "submit",
+__all__ = ["BASE_BUCKETS", "BUCKETS", "TILE", "MSM_TILE", "bucket",
+           "tile_bucket", "tile_plan", "verify_items_pipelined", "submit",
            "run_off_loop", "dispatch_histogram", "overlap_histogram",
            "reset_workers"]

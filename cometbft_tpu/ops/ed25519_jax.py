@@ -20,6 +20,7 @@ hashlib) and nibble-window decomposition of the scalars.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import hashlib
 import os
@@ -362,14 +363,14 @@ def verify_batch(
     """Verify [(pub, msg, sig), ...] on the default JAX device.
 
     Batches above one pipeline tile (crypto/pipeline.tile_bucket,
-    4096 lanes) are fed to a TilePipeline in
-    the balanced chunks of crypto/pipeline.tile_plan (10,000 as three
-    ~3,334-lane tiles), all at the tile's bucket: while tile i
-    executes under JAX's async dispatch, the host preps tile i+1.
-    Smaller batches keep the monolithic single-bucket dispatch.  A
-    caller that has its items one at a time does not wait for the
-    whole list: crypto/batch.GuardedTpuBatchVerifier feeds the same
-    pipeline a full tile at a time from ``add()``.
+    1,024 lanes) are fed to a TilePipeline in
+    the balanced chunks of crypto/pipeline.tile_plan (10,000 as ten
+    1,000-lane tiles), all at the tile's bucket: while tile i
+    executes under JAX's async dispatch, the native thread preps
+    tile i+1.  Smaller batches keep the monolithic single-bucket
+    dispatch.  A caller that has its items one at a time does not
+    wait for the whole list: crypto/batch.GuardedTpuBatchVerifier
+    feeds the same pipeline a full tile at a time from ``add()``.
 
     Returns (all_valid, per_sig_mask) — the reference BatchVerifier.Verify
     contract (crypto/crypto.go:47).
@@ -388,20 +389,48 @@ def verify_batch(
     return pipe.finish()
 
 
+_STREAMED_TILES = None
+
+
+def streamed_tiles_counter():
+    """Tiles a TilePipeline launched, by whether the launch found the
+    tile's prep done (``ready``) or waited for it (``waited``): the
+    counter that says the prep runs beside its caller."""
+    global _STREAMED_TILES
+    if _STREAMED_TILES is None:
+        from ..libs import metrics as libmetrics
+        _STREAMED_TILES = libmetrics.DEFAULT.counter(
+            "crypto", "streamed_tiles_total",
+            "Tiles the device pipeline launched, by whether the "
+            "launch found the tile's host prep finished (ready) or "
+            "waited for it (waited).", labels=("prep",))
+    return _STREAMED_TILES
+
+
+# a tile the pipeline has launched and not settled
+_Launched = collections.namedtuple("_Launched",
+                                   "n warm pre_bad dev span")
+
+
 class TilePipeline:
     """The tiled, overlapped dispatch, fed a tile at a time.
 
-    ``feed(chunk)`` preps the chunk (host_prep), launches it (JAX
-    async dispatch — the jitted call returns a device future, and the
-    mask's copy to the host is queued behind the kernel at once) and
-    only then settles the tile launched before it: at most two tiles
-    are in flight, and the host_prep of tile i+1 runs while tile i's
-    kernel executes.  ``finish()`` settles what is left and hands
-    back (all_valid, mask) in feed order.  The one implementation of
+    ``feed(chunk)`` BEGINS the chunk's host prep, which runs on the
+    native module's prep thread (native ed25519_prep_begin: no GIL),
+    launches the chunk fed before it, whose prep ran while the caller
+    did something else (the seam's caller walked 1,024 signatures:
+    2.7 ms for 1.2), and settles, without waiting, the tiles whose
+    kernels have finished.  So a feed costs its caller a hand-off and
+    a launch (JAX async dispatch: the jitted call returns a device
+    future, and the mask's copy to the host is queued behind the
+    kernel at once); tile i is launched by feed i+1, or by
+    ``finish()``.  At most IN_FLIGHT tiles are launched and not
+    settled: one more waits for the oldest.  ``finish()`` waits for
+    the last prep, launches it, settles what is left and hands back
+    (all_valid, mask) in feed order.  The one implementation of
     prep, launch, settle, pre_bad and mask assembly above one tile:
     verify_batch feeds it a planned list, the seam's verifier feeds
-    it from ``add()`` while its caller is still walking (``eager``),
-    so that a tile which finished meanwhile settles without a wait.
+    it from ``add()`` while its caller is still walking (``eager``).
 
     Every chunk, the last and shortest too, dispatches at the ONE
     shape of the tile's bucket: warmup() warms exactly that.
@@ -409,42 +438,66 @@ class TilePipeline:
     (parallel/mesh.pipeline_partitioner) so per-tile dispatch pays no
     mesh/sharding re-resolution.
 
-    A tile's kernel_execute span runs from the instant before its
-    _launch to the end of its settle (h2d and launch at its start,
-    device_wait and d2h at its end); the next tile's host_prep is a
-    sibling that overlaps it — the overlap the pipeline exists for.
-    A span records when it ends: a pipeline dropped with a tile in
-    flight (its caller raised first) records nothing for that tile
-    and raises nothing later."""
+    A tile's host_prep span is the prep's OWN time, from the native
+    thread's clock readings, recorded when the tile is launched: a
+    cost, not a wait.  What the launch waited for it is
+    ``prep_wait_us`` on the tile's kernel_execute span (0 when the
+    overlap works) and the ``prep`` label of
+    crypto_streamed_tiles_total.  The kernel_execute span runs from
+    the instant before the tile's _launch to the end of its settle
+    (h2d and launch at its start, device_wait and d2h at its end);
+    the spans of tiles in flight overlap.  A span records when it
+    ends: a pipeline dropped with a prep or a tile in flight (its
+    caller raised first) records nothing for it and raises nothing
+    later; the prep's handle takes its job back or lets it finish."""
+
+    IN_FLIGHT = 4
 
     def __init__(self, tile: int):
         self._choice = _kernel_choice()
         self._m = _padded(tile, self._choice)
         self._hist = dispatch_histogram()
         self._masks: list[np.ndarray] = []
-        self._inflight = None       # (n, warm, pre_bad, dev, span)
+        self._prepping = None       # (handle, n, eager): begun
+        self._launched = collections.deque()    # _Launched, feed order
         self._tiles = 0
         self._t_run0 = tracing.now_ns()
         self._phase_s = 0.0
 
     def feed(self, chunk, eager: bool = False) -> None:
-        """Dispatch one chunk of at most a tile of (pub, msg, sig)
-        items.  ``eager`` marks a tile fed before its batch was
-        complete (the span's attribute, which
+        """Take one chunk of at most a tile of (pub, msg, sig) items.
+        ``eager`` marks a tile fed before its batch was complete (the
+        span's attribute, which
         benchmark/layers/eager_tiles_per_commit counts)."""
-        choice, m, n = self._choice, self._m, len(chunk)
+        prev, self._prepping = self._prepping, (
+            _prep_begin(chunk, self._m), len(chunk), eager)
+        if prev is not None:
+            self._launch_tile(*prev)
+        while self._launched and self._launched[0].dev.is_ready():
+            self._settle()
+
+    def _launch_tile(self, handle, n: int, eager: bool) -> None:
+        choice, m = self._choice, self._m
+        wire, pre_bad, t0, elapsed, waited = handle.result()
+        wire, pre_bad = _wire_arrays(wire, pre_bad, m)
         warm = (choice, m) in _SEEN_SHAPES
-        with tracing.timed(tracing.CRYPTO, "host_prep", batch=n,
-                           bucket=m, pipelined=True) as prep:
-            wire, pre_bad = prep_arrays(chunk, m)
+        tracing.record_span(tracing.CRYPTO, "host_prep", t0,
+                            t0 + elapsed, batch=n, bucket=m,
+                            pipelined=True)
         pad_bucket = str(m)
         self._hist.with_labels("host_prep", choice, pad_bucket,
-                               "1" if warm else "0").observe(prep.seconds)
-        self._phase_s += prep.seconds
+                               "1" if warm else "0").observe(
+                                   elapsed / 1e9)
+        self._phase_s += elapsed / 1e9
+        streamed_tiles_counter().with_labels(
+            "waited" if waited else "ready").add()
+        if len(self._launched) >= self.IN_FLIGHT:
+            self._settle()
         attrs = {"eager": True} if eager else {}
         sp = tracing.timed(tracing.CRYPTO, "kernel_execute", batch=n,
                            bucket=m, kernel=choice, warm=warm,
                            pipelined=True, tile=self._tiles,
+                           prep_wait_us=waited // 1000,
                            **attrs).begin()
         with tracing.under(sp):
             dev = _launch(wire, choice=choice,
@@ -452,38 +505,34 @@ class TilePipeline:
         dev.copy_to_host_async()
         _SEEN_SHAPES.add((choice, m))
         self._tiles += 1
-        if self._inflight is not None:
-            self._settle(prep_inside=prep.seconds)
-        self._inflight = (n, warm, pre_bad, dev, sp)
+        self._launched.append(_Launched(n, warm, pre_bad, dev, sp))
 
-    def _settle(self, prep_inside: float) -> None:
-        n, warm, pre_bad, dev, sp = self._inflight
-        self._inflight = None
+    def _settle(self) -> None:
+        """The oldest launched tile: wait, mask, span."""
+        n, warm, pre_bad, dev, sp = self._launched.popleft()
         with tracing.under(sp):
             ok = _force(dev, sp)
         sp.end()
-        # dispatch -> settled: the window the device (or the XLA
-        # runtime thread) owned the tile, i.e. what host_prep of the
-        # NEXT tile overlapped with
+        # launch -> settled: the window the device (or the XLA runtime
+        # thread) owned the tile; tiles in flight overlap each other
+        # and the preps beside them, which is what the overlap ratio
+        # reads above 1.0
         choice, pad_bucket = self._choice, str(self._m)
         self._hist.with_labels("kernel_execute", choice, pad_bucket,
                                "1" if warm else "0").observe(sp.seconds)
+        self._phase_s += sp.seconds
         ok = ok[:n].copy()
         ok[pre_bad[:n]] = False
         self._masks.append(ok)
-        # the overlap-ratio kernel phase subtracts the NEXT tile's
-        # host_prep, which by construction sits inside this envelope
-        # (stage(i+1) runs between dispatch(i) and settle(i)) — else
-        # a pipeline whose device did nothing until the force would
-        # still read ~2.0 "overlap"; what remains above the contained
-        # prep is execution the async dispatch genuinely hid
-        self._phase_s += max(0.0, sp.seconds - prep_inside)
 
     def finish(self) -> tuple[bool, list[bool]]:
-        """Settle the last tile; (all_valid, mask) over everything
-        fed, in feed order."""
-        if self._inflight is not None:
-            self._settle(prep_inside=0.0)
+        """Launch the last tile and settle every tile; (all_valid,
+        mask) over everything fed, in feed order."""
+        prev, self._prepping = self._prepping, None
+        if prev is not None:
+            self._launch_tile(*prev)
+        while self._launched:
+            self._settle()
         wall = (tracing.now_ns() - self._t_run0) / 1e9
         if wall > 0:
             overlap_histogram().observe(self._phase_s / wall)
@@ -527,9 +576,9 @@ def _launch(wire, *, choice: str, interpret: bool = False,
     A single-device dispatch is one non-blocking ``jax.device_put`` of
     the whole buffer and one jitted call of one argument: a transfer
     costs ~0.27 ms a call on a v5e whatever its size (PERF.md, PR 24
-    and 26).  No buffer is donated: a tile's wire is 786 KB at the
-    4,096 bucket, at most two tiles are in flight, and the device's
-    peak stayed 4.2 MB at 10,000 validators (PERF.md, PR 27)."""
+    and 26).  No buffer is donated: a tile's wire is 197 KB at the
+    1,024 bucket and at most TilePipeline.IN_FLIGHT tiles are in
+    flight."""
     if part is not None:
         return part.dispatch(*wire_views(wire))
     with tracing.span(tracing.CRYPTO, "h2d"):
@@ -638,6 +687,60 @@ def _padding_wire(m: int) -> np.ndarray:
     return wire
 
 
+def _native_prep():
+    """The native module when it holds the C prep, else None (said
+    once, because the numpy path is slow)."""
+    global _WARNED_NO_NATIVE
+    from ..crypto._native_loader import load as _load_native
+    native = _load_native(allow_build=False)
+    if native is not None and hasattr(native, "ed25519_prep_begin"):
+        return native
+    if not _WARNED_NO_NATIVE:
+        _WARNED_NO_NATIVE = True
+        from ..libs.log import new_logger
+        new_logger("crypto").warn(
+            "native module not built; device host prep is running "
+            "per-item in Python")
+    return None
+
+
+def _wire_arrays(wire: bytes, pre_bad: bytes, m: int):
+    """The native prep's two buffers as (wire [m,192]u8, pre_bad
+    [m]bool)."""
+    return (np.frombuffer(wire, np.uint8).reshape(m, WIRE_LANE_BYTES),
+            np.frombuffer(pre_bad, np.uint8).astype(bool))
+
+
+class _PrepNow:
+    """prep_arrays behind the native handle's interface, for a host
+    without the native module: the prep runs in result(), on its
+    caller's thread."""
+
+    def __init__(self, items, m: int):
+        self._args = (items, m)
+
+    def result(self):
+        t0 = tracing.now_ns()
+        wire, pre_bad = prep_arrays(*self._args)
+        elapsed = tracing.now_ns() - t0
+        return (wire.tobytes(), pre_bad.astype(np.uint8).tobytes(),
+                t0, elapsed, elapsed)
+
+
+def _prep_begin(items, m: int):
+    """Begin prep_arrays(items, m) beside the caller: the native
+    module's handle (phase 2 on its prep thread, the GIL never taken
+    there), whose ``result()`` waits with the GIL released and returns
+    (wire, pre_bad, start_ns, elapsed_ns, waited_ns) — the two
+    buffers, the prep's own clock readings on tracing.now_ns's clock
+    and how long the call waited, 0 when the prep had finished."""
+    native = _native_prep()
+    if native is None:
+        return _PrepNow(items, m)
+    return native.ed25519_prep_begin(items, m, _B_BYTES,
+                                     _IDENTITY_BYTES)
+
+
 def prep_arrays(items, m: int):
     """The full host-side prep for a batch of (pub, msg, sig) items,
     padded to m lanes: length/canonical-S checks, k = SHA-512(R||A||msg)
@@ -649,28 +752,15 @@ def prep_arrays(items, m: int):
     wire stays at 1 byte per element and one transfer.  Padding lanes
     hold B, the identity and zero windows, and verify trivially.  Uses
     the one-pass C prep when the native module is built (the node
-    builds it at start), else the vectorized numpy path with a per-item
-    Python SHA-512 — and says so once, because that path is slow.
-    Both fill the buffer in place: nothing is concatenated."""
-    global _WARNED_NO_NATIVE
-    from ..crypto._native_loader import load as _load_native
-    native = _load_native(allow_build=False)
-    if native is None and not _WARNED_NO_NATIVE:
-        _WARNED_NO_NATIVE = True
-        from ..libs.log import new_logger
-        new_logger("crypto").warn(
-            "native module not built; device host prep is running "
-            "per-item in Python")
-    if native is not None and hasattr(native, "ed25519_prep"):
-        # the ENTIRE host prep in one C pass (length checks,
-        # canonical-S, k = SHA-512(R||A||msg) mod L, window split),
-        # threaded across cores with the GIL released
-        wire_buf, bad_buf = native.ed25519_prep(
-            items, m, _B_BYTES, _IDENTITY_BYTES)
-        wire = np.frombuffer(wire_buf, np.uint8).reshape(
-            m, WIRE_LANE_BYTES)
-        pre_bad = np.frombuffer(bad_buf, np.uint8).astype(bool)
-        return wire, pre_bad
+    builds it at start: ed25519_prep, which is _prep_begin's prep run
+    on the calling thread with the GIL released), else the vectorized
+    numpy path with a per-item Python SHA-512 — and says so once,
+    because that path is slow.  Both fill the buffer in place: nothing
+    is concatenated."""
+    native = _native_prep()
+    if native is not None:
+        return _wire_arrays(*native.ed25519_prep(
+            items, m, _B_BYTES, _IDENTITY_BYTES), m)
 
     wire = _padding_wire(m)
     a_b, r_b, s_w8, k_w8 = wire_views(wire)
@@ -765,7 +855,7 @@ def warmup(n: int) -> None:
     """Pre-compile the shape a batch of n signatures dispatches at:
     the bucket covering n, or above one tile the tile's bucket — the
     one shape of every TilePipeline chunk, planned by verify_batch
-    or fed from the seam's ``add()`` (6,667 signatures: 4,096)."""
+    or fed from the seam's ``add()`` (6,667 signatures: 1,024)."""
     _warmup_bucket(_padded(min(n, tile_bucket()), _kernel_choice()))
 
 

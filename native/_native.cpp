@@ -13,9 +13,17 @@
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
+#include <pthread.h>
+#include <sched.h>
+#include <signal.h>
+#include <time.h>
+
+#include <algorithm>
+#include <condition_variable>
 #include <cstdint>
 #include <cstring>
-#include <functional>
+#include <deque>
+#include <mutex>
 #include <thread>
 #include <vector>
 
@@ -344,28 +352,222 @@ void lanes(const ItemRef* refs, Py_ssize_t lo, Py_ssize_t hi,
 #endif
 }
 
-void run_threads(Py_ssize_t n,
-                 const std::function<void(Py_ssize_t, Py_ssize_t)>& fn) {
-    unsigned hw = std::thread::hardware_concurrency();
-    unsigned nt = hw > 8 ? 8 : (hw ? hw : 1);
-    if (nt <= 1 || n < 2048) {
-        fn(0, n);
+// One prep: what phase 1 borrowed from the Python objects and what
+// phase 2 fills.  A PrepHandle owns it; the worker holds a plain
+// pointer to it while it is queued or running, and the handle does
+// not go before the job is its own again.
+struct Worker;
+
+struct Job {
+    PyObject* fast = nullptr;           // the items, kept alive
+    std::vector<PyObject*> fits;        // each item as a fast sequence
+    std::vector<ItemRef> refs;
+    Py_ssize_t n = 0, m = 0;
+    uint8_t b[32], id[32];
+    PyObject* wire_out = nullptr;
+    PyObject* bad_out = nullptr;
+    uint8_t* wire_p = nullptr;
+    uint8_t* bad_p = nullptr;
+    enum State { QUEUED, RUNNING, DONE };
+    State state = QUEUED;               // under its worker's mutex
+    Worker* worker = nullptr;           // where it was posted, if at all
+    int64_t t0_ns = 0, t1_ns = 0;       // phase 2, CLOCK_MONOTONIC
+};
+
+inline int64_t mono_ns() {
+    // the clock of Python's time.monotonic_ns(), so that a span can
+    // be recorded from these readings (libs/tracing.record_span)
+    timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    return int64_t(ts.tv_sec) * 1000000000 + ts.tv_nsec;
+}
+
+// phase 2: no Python object is touched and the GIL is not needed
+void run(Job* j) {
+    j->t0_ns = mono_ns();
+    // padding defaults (windows of unwritten lanes must be zero)
+    std::memset(j->wire_p, 0, size_t(WIRE_LANE) * size_t(j->m));
+    std::memset(j->bad_p, 0, size_t(j->m));
+    for (Py_ssize_t i = 0; i < j->m; i++) {
+        uint8_t* row = j->wire_p + i * WIRE_LANE;
+        std::memcpy(row, j->b, 32);
+        std::memcpy(row + WIRE_R, j->id, 32);
+    }
+    lanes(j->refs.data(), 0, j->n, j->wire_p, j->bad_p);
+    j->t1_ns = mono_ns();
+}
+
+// The one persistent native thread that runs posted jobs in order.
+// It never takes the GIL.  Started at the first post; never joined
+// (it sleeps on its queue until the process goes); a forked child,
+// which has no such thread, starts its own at its first post and
+// runs what its parent had posted on the thread that asks for it.
+struct Worker {
+    std::mutex mu;
+    std::condition_variable work, done;
+    std::deque<Job*> queue;
+
+    void loop() {
+        std::unique_lock<std::mutex> lk(mu);
+        for (;;) {
+            work.wait(lk, [&] { return !queue.empty(); });
+            Job* j = queue.front();
+            queue.pop_front();
+            j->state = Job::RUNNING;
+            lk.unlock();
+            run(j);
+            lk.lock();
+            j->state = Job::DONE;
+            done.notify_all();
+        }
+    }
+};
+
+Worker* g_worker = nullptr;             // under the GIL
+
+// The kernel starts a thread on its creator's CPU and, in a KVM guest,
+// goes on waking it there (a halted vCPU counts as preempted, so the
+// wake-up finds no idle CPU to prefer), where it runs each prep in
+// its caller's place: begin() returned when the prep was DONE, 270 us
+// for 22 (CPU sandbox, PR 36).  So the thread moves off that CPU
+// once, at its start, and is then as free as before; from there on it
+// wakes where it last ran.
+void leave_cpu(int cpu) {
+    cpu_set_t allowed;
+    if (cpu < 0 || sched_getaffinity(0, sizeof allowed, &allowed) != 0 ||
+        !CPU_ISSET(cpu, &allowed) || CPU_COUNT(&allowed) < 2)
         return;
+    cpu_set_t others = allowed;
+    CPU_CLR(cpu, &others);
+    if (sched_setaffinity(0, sizeof others, &others) == 0)
+        sched_setaffinity(0, sizeof allowed, &allowed);
+}
+
+void forget_worker_in_child() { g_worker = nullptr; }
+
+Worker* worker() {
+    if (g_worker) return g_worker;
+    static bool registered = false;
+    if (!registered) {
+        pthread_atfork(nullptr, nullptr, forget_worker_in_child);
+        registered = true;
     }
-    std::vector<std::thread> ts;
-    Py_ssize_t chunk = (n + nt - 1) / nt;
-    for (unsigned t = 0; t < nt; t++) {
-        Py_ssize_t lo = Py_ssize_t(t) * chunk;
-        Py_ssize_t hi = lo + chunk < n ? lo + chunk : n;
-        if (lo >= hi) break;
-        ts.emplace_back(fn, lo, hi);
+    Worker* w = new Worker;
+    // signals stay with the interpreter's threads
+    sigset_t all, old;
+    sigfillset(&all);
+    pthread_sigmask(SIG_SETMASK, &all, &old);
+    int creator_cpu = sched_getcpu();
+    std::thread([w, creator_cpu] {
+        leave_cpu(creator_cpu);
+        w->loop();
+    }).detach();
+    pthread_sigmask(SIG_SETMASK, &old, nullptr);
+    return g_worker = w;
+}
+
+// Make the job its owner's again: take it off the queue if no thread
+// has begun it, else wait until it is done.  ``reading`` is result():
+// an unbegun job is run here, and both that and the wait release the
+// GIL; a handle that is being freed runs nothing and keeps the GIL for
+// the millisecond a running prep has left.  Returns the nanoseconds
+// this took; 0 for a job that was done.
+int64_t settle(Job* j, bool reading) {
+    int64_t t0 = mono_ns();
+    Worker* w = j->worker;
+    if (w && w == g_worker) {
+        std::unique_lock<std::mutex> lk(w->mu);
+        if (j->state == Job::RUNNING) {
+            PyThreadState* ts = reading ? PyEval_SaveThread() : nullptr;
+            w->done.wait(lk, [&] { return j->state == Job::DONE; });
+            lk.unlock();
+            if (ts) PyEval_RestoreThread(ts);
+            return mono_ns() - t0;
+        }
+        if (j->state == Job::QUEUED) {
+            auto& q = w->queue;
+            q.erase(std::find(q.begin(), q.end(), j));
+        }
     }
-    for (auto& th : ts) th.join();
+    // done; or never posted, taken back, or posted to a thread this
+    // process (a forked child) does not have
+    j->worker = nullptr;
+    if (j->state == Job::DONE || !reading) return 0;
+    PyThreadState* ts = PyEval_SaveThread();
+    run(j);
+    PyEval_RestoreThread(ts);
+    j->state = Job::DONE;
+    return mono_ns() - t0;
 }
 
 }  // namespace prep
 
-PyObject* ed25519_prep(PyObject*, PyObject* args) {
+struct PrepHandle {
+    PyObject_HEAD
+    prep::Job* job;
+};
+
+void prep_handle_free_refs(prep::Job* j) {
+    for (PyObject* fit : j->fits) Py_DECREF(fit);
+    j->fits.clear();
+    Py_CLEAR(j->fast);
+    Py_CLEAR(j->wire_out);
+    Py_CLEAR(j->bad_out);
+}
+
+void prep_handle_dealloc(PyObject* self) {
+    prep::Job* j = reinterpret_cast<PrepHandle*>(self)->job;
+    if (j) {
+        // dropped unread: unqueue it, or let a running prep finish
+        prep::settle(j, /*reading=*/false);
+        prep_handle_free_refs(j);
+        delete j;
+    }
+    Py_TYPE(self)->tp_free(self);
+}
+
+// result() -> (wire, pre_bad, start_ns, elapsed_ns, waited_ns): the
+// two buffers of ed25519_prep, the prep's own clock readings
+// (CLOCK_MONOTONIC, time.monotonic_ns's) and how long this call
+// waited for it, 0 when it had finished.  Once.
+PyObject* prep_handle_result(PyObject* self, PyObject*) {
+    prep::Job* j = reinterpret_cast<PrepHandle*>(self)->job;
+    if (!j || !j->wire_out) {
+        PyErr_SetString(PyExc_RuntimeError,
+                        "prep result already taken");
+        return nullptr;
+    }
+    int64_t waited = prep::settle(j, /*reading=*/true);
+    PyObject* out = Py_BuildValue(
+        "(OOLLL)", j->wire_out, j->bad_out, (long long)j->t0_ns,
+        (long long)(j->t1_ns - j->t0_ns), (long long)waited);
+    prep_handle_free_refs(j);
+    return out;
+}
+
+PyMethodDef kPrepHandleMethods[] = {
+    {"result", prep_handle_result, METH_NOARGS,
+     "wait (GIL released) -> (wire, pre_bad, start_ns, elapsed_ns, "
+     "waited_ns)"},
+    {nullptr, nullptr, 0, nullptr},
+};
+
+PyTypeObject kPrepHandleType = {PyVarObject_HEAD_INIT(nullptr, 0)};
+
+bool prep_handle_type_ready() {
+    kPrepHandleType.tp_name = "_native.PrepHandle";
+    kPrepHandleType.tp_basicsize = sizeof(PrepHandle);
+    kPrepHandleType.tp_flags = Py_TPFLAGS_DEFAULT;
+    kPrepHandleType.tp_dealloc = prep_handle_dealloc;
+    kPrepHandleType.tp_methods = kPrepHandleMethods;
+    kPrepHandleType.tp_doc = "an ed25519 host prep in flight";
+    return PyType_Ready(&kPrepHandleType) == 0;
+}
+
+// phase 1 (GIL held): borrow data pointers out of the Python objects,
+// kept alive by the job's references until its result is taken or
+// its handle freed; ``post`` hands phase 2 to the native thread.
+PyObject* prep_begin(PyObject* args, bool post) {
     PyObject* seq_in;
     Py_ssize_t m;
     const char* b_bytes;
@@ -387,26 +589,32 @@ PyObject* ed25519_prep(PyObject*, PyObject* args) {
         PyErr_SetString(PyExc_ValueError, "m < len(items)");
         return nullptr;
     }
-    PyObject* wire_out = PyBytes_FromStringAndSize(
-        nullptr, prep::WIRE_LANE * m);
-    PyObject* bad_out = PyBytes_FromStringAndSize(nullptr, m);
-    if (!wire_out || !bad_out) {
-        Py_XDECREF(wire_out); Py_XDECREF(bad_out); Py_DECREF(fast);
+    PrepHandle* h = PyObject_New(PrepHandle, &kPrepHandleType);
+    if (!h) {
+        Py_DECREF(fast);
         return nullptr;
     }
-    uint8_t* wire_p =
-        reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(wire_out));
-    uint8_t* bad_p =
-        reinterpret_cast<uint8_t*>(PyBytes_AS_STRING(bad_out));
-
-    // phase 1 (GIL held): borrow data pointers out of the Python
-    // objects; kept alive by `fast` + `fits` until the workers join
-    std::vector<prep::ItemRef> refs;
-    refs.resize(static_cast<size_t>(n));
-    std::vector<PyObject*> fits;
-    fits.reserve(size_t(n));
+    prep::Job* j = h->job = new prep::Job;
+    j->fast = fast;
+    j->n = n;
+    j->m = m;
+    std::memcpy(j->b, b_bytes, 32);
+    std::memcpy(j->id, id_bytes, 32);
+    j->wire_out = PyBytes_FromStringAndSize(nullptr,
+                                            prep::WIRE_LANE * m);
+    j->bad_out = PyBytes_FromStringAndSize(nullptr, m);
+    if (!j->wire_out || !j->bad_out) {
+        Py_DECREF(h);
+        return nullptr;
+    }
+    j->wire_p = reinterpret_cast<uint8_t*>(
+        PyBytes_AS_STRING(j->wire_out));
+    j->bad_p = reinterpret_cast<uint8_t*>(
+        PyBytes_AS_STRING(j->bad_out));
+    j->refs.resize(static_cast<size_t>(n));
+    j->fits.reserve(size_t(n));
     for (Py_ssize_t i = 0; i < n; i++) {
-        prep::ItemRef& ref = refs[size_t(i)];
+        prep::ItemRef& ref = j->refs[size_t(i)];
         ref.bad = true;
         PyObject* it = PySequence_Fast_GET_ITEM(fast, i);
         PyObject* fit = PySequence_Fast(it, "item must be a tuple");
@@ -415,7 +623,7 @@ PyObject* ed25519_prep(PyObject*, PyObject* args) {
             Py_XDECREF(fit);
             continue;
         }
-        fits.push_back(fit);
+        j->fits.push_back(fit);
         char *pub, *msg, *sig;
         Py_ssize_t publen, msglen, siglen;
         if (PyBytes_AsStringAndSize(PySequence_Fast_GET_ITEM(fit, 0),
@@ -434,29 +642,34 @@ PyObject* ed25519_prep(PyObject*, PyObject* args) {
         ref.sig = reinterpret_cast<uint8_t*>(sig);
         ref.bad = false;
     }
-
-    // phase 2 (GIL released): hash/window lanes, straight into the
-    // rows of the one wire buffer
-    {
-        const prep::ItemRef* refp = refs.data();
-        Py_BEGIN_ALLOW_THREADS
-        // padding defaults (windows of unwritten lanes must be zero)
-        std::memset(wire_p, 0, size_t(prep::WIRE_LANE) * size_t(m));
-        for (Py_ssize_t i = 0; i < m; i++) {
-            uint8_t* row = wire_p + i * prep::WIRE_LANE;
-            std::memcpy(row, b_bytes, 32);
-            std::memcpy(row + prep::WIRE_R, id_bytes, 32);
-            bad_p[i] = 0;
+    if (post) {
+        prep::Worker* w = j->worker = prep::worker();
+        {
+            std::lock_guard<std::mutex> lk(w->mu);
+            w->queue.push_back(j);
         }
-        prep::run_threads(n, [&](Py_ssize_t lo, Py_ssize_t hi) {
-            prep::lanes(refp, lo, hi, wire_p, bad_p);
-        });
-        Py_END_ALLOW_THREADS
+        w->work.notify_one();
     }
-    for (PyObject* fit : fits) Py_DECREF(fit);
-    Py_DECREF(fast);
-    PyObject* out = PyTuple_Pack(2, wire_out, bad_out);
-    Py_DECREF(wire_out); Py_DECREF(bad_out);
+    return reinterpret_cast<PyObject*>(h);
+}
+
+// ed25519_prep_begin(items, m, b_bytes, identity_bytes) -> PrepHandle:
+// phase 1 here, phase 2 on the native thread while the caller goes on
+PyObject* ed25519_prep_begin(PyObject*, PyObject* args) {
+    return prep_begin(args, /*post=*/true);
+}
+
+// the same prep for a caller with nothing to do meanwhile: begun
+// without a post, so result() runs phase 2 on this thread, GIL
+// released, and no thread is woken for it
+PyObject* ed25519_prep(PyObject*, PyObject* args) {
+    PyObject* h = prep_begin(args, /*post=*/false);
+    if (!h) return nullptr;
+    PyObject* res = prep_handle_result(h, nullptr);
+    Py_DECREF(h);
+    if (!res) return nullptr;
+    PyObject* out = PyTuple_GetSlice(res, 0, 2);
+    Py_DECREF(res);
     return out;
 }
 
@@ -1026,6 +1239,10 @@ PyMethodDef kMethods[] = {
     {"ed25519_prep", ed25519_prep, METH_VARARGS,
      "full batch-verify host prep: (items, m, B, identity) -> "
      "(wire [m*192], pre_bad [m])"},
+    {"ed25519_prep_begin", ed25519_prep_begin, METH_VARARGS,
+     "the same prep as a future: phase 2 on the native thread; "
+     "-> PrepHandle, whose result() is (wire, pre_bad, start_ns, "
+     "elapsed_ns, waited_ns)"},
     {"ed25519_batch_verify_tile", ed25519_batch_verify_tile,
      METH_VARARGS,
      "per-tile RLC batch verification over packed blobs "
@@ -1074,5 +1291,6 @@ PyModuleDef kModule = {
 }  // namespace
 
 PyMODINIT_FUNC PyInit__native(void) {
+    if (!prep_handle_type_ready()) return nullptr;
     return PyModule_Create(&kModule);
 }

@@ -1,18 +1,20 @@
 """Commit verification through the TILED device path, verdict by
 verdict, with a golden kernel at the wire (tests/wire_golden.py) in
 place of the compiled ones: walk -> BatchVerifier seam, whose add()
-feeds a full tile to ops TilePipeline the moment it has one ->
-prep_arrays, tiles, pre_bad, mask assembly -> the index a refusal
-names.
+hands a full tile to ops TilePipeline the moment it has one (the
+tile's host prep begins on the native prep thread; the tile handed
+over before it is launched) -> tiles, pre_bad, mask assembly -> the
+index a refusal names.
 
 verify_commit_light is held to the plain reference of configuration
 valset-10k (benchmark/reference/commit_light.py), verify_commit to its
 strict twin below, on sets of 150 and 200 validators at a 64-lane
 tile (two to four tiles a commit), and at the edges of the streamed
-path: a batch of exactly one tile, one more, exactly two; a verifier
-dropped with a tile in flight; a kernel that fails under add(); an
-open breaker; verify_async().  One more test pins the plan at the
-real size, 10,000 validators, without a kernel.
+path: a batch of exactly one tile, one more, exactly two, seven with
+a short last one; masks in feed order; a verifier dropped with a prep
+in flight and with a tile in flight; a kernel that fails under add()
+and under verify(); an open breaker; verify_async().  One more test
+pins the plan at the real size, 10,000 validators, without a kernel.
 """
 import asyncio
 import dataclasses
@@ -255,15 +257,30 @@ def adds(monkeypatch):
     return count
 
 
-@pytest.mark.parametrize("spoiled", [None, 0, -1])
+# where a forged signature sits, given the batch and its tile count;
+# the middle tile of one or two tiles is the first
+SPOILED = {
+    "honest": lambda n, tiles: None,
+    "first_lane": lambda n, tiles: 0,
+    "last_lane": lambda n, tiles: n - 1,
+    "first_lane_of_a_middle_tile":
+        lambda n, tiles: (tiles // 2) * TILE,
+    "last_lane_of_a_middle_tile":
+        lambda n, tiles: min(n, (tiles // 2 + 1) * TILE) - 1,
+}
+
+
+@pytest.mark.parametrize("spoiled", SPOILED)
 @pytest.mark.parametrize("n,tiles", [(TILE, 1), (TILE + 1, 2),
-                                     (2 * TILE, 2)])
+                                     (2 * TILE, 2),
+                                     (6 * TILE + 33, 7)])
 def test_a_batch_at_the_edge_of_a_tile(device_path, n, tiles, spoiled):
-    """Exactly one tile (fed by the last add(), nothing left for
-    verify() but the settle), one lane more (a remainder of one, at
-    the tile's shape), exactly two tiles (both fed from add(), empty
-    remainder)."""
-    at = None if spoiled is None else spoiled % n
+    """Exactly one tile (handed over by the last add(), launched and
+    settled by verify()), one lane more (a remainder of one, at the
+    tile's shape), exactly two tiles (both handed over from add(),
+    empty remainder), seven with a short last one (valset-10k's
+    shape: six from add(), 33 lanes from verify())."""
+    at = SPOILED[spoiled](n, tiles)
     vset, bid, height, commit = variant(
         n, {} if at is None else {at: resigned(forged)})
     assert verdict("verify_commit", vset, bid, height, commit) == (
@@ -272,77 +289,127 @@ def test_a_batch_at_the_edge_of_a_tile(device_path, n, tiles, spoiled):
     assert device_path == [TILE] * tiles
 
 
-def test_the_first_tile_is_on_its_way_before_the_walk_ends(
+def seam_verifier(vset, commit, upto=None):
+    """A verifier of the seam with the commit's first ``upto``
+    signatures added, as the walk adds them."""
+    bv = crypto_batch.create_batch_verifier(vset.validators[0].pub_key)
+    for i, v in enumerate(vset.validators[:upto]):
+        bv.add(v.pub_key, commit.vote_sign_bytes(CHAIN_ID, i),
+               commit.signatures[i].signature)
+    return bv
+
+
+def test_masks_come_back_in_feed_order(device_path):
+    """Seven tiles, forged lanes at both ends of the first, a middle
+    and the last tile, one refused by host prep: the mask names
+    exactly them, whichever tile settled when."""
+    n = 6 * TILE + 33
+    bad = {0, TILE - 1, TILE, 3 * TILE + 5, 6 * TILE - 1, 6 * TILE,
+           n - 1}
+    changed = {i: resigned(forged) for i in bad}
+    changed[2 * TILE + 9] = resigned(s_plus_l)
+    vset, _, _, commit = variant(n, changed)
+    bv = seam_verifier(vset, commit)
+    # six tiles handed over, five of them launched, inside the adds
+    assert device_path == [TILE] * 5
+    ok, mask = bv.verify()
+    assert not ok and mask == [i not in changed for i in range(n)]
+    assert device_path == [TILE] * 7
+
+
+def test_tiles_are_on_their_way_before_the_walk_ends(
         device_path, adds, monkeypatch):
-    """150 validators of equal power, light: 101 signatures go as two
-    dispatches of 64 lanes, the first made by the 64th add() of 101;
-    never another shape."""
+    """200 validators, strict: the 64th add() begins tile 0's prep,
+    the 128th launches tile 0, the 192nd tile 1; verify() hands over
+    the 8 left, which launches tile 2, and launches them.  Never
+    another shape."""
     seen = []
     golden = ej._jit_verify_packed
     for name in KERNELS:
         monkeypatch.setattr(
             ej, name, lambda wire, **static:
             seen.append(adds[0]) or golden(wire, **static))
-    vset, bid, height, commit = variant(150)
-    assert light_stop(vset) == 101
-    assert verdict("verify_commit_light", vset, bid, height,
+    vset, bid, height, commit = variant(200)
+    assert verdict("verify_commit", vset, bid, height,
                    commit) == (commit_light.ACCEPTED, None)
-    assert device_path == [TILE, TILE]
-    assert seen == [TILE, 101] and adds[0] == 101
+    assert device_path == [TILE] * 4
+    assert seen == [2 * TILE, 3 * TILE, 200, 200] and adds[0] == 200
 
 
-def test_a_short_tally_drops_the_tile_in_flight(device_path, recorder):
-    """60 of 150 absent: the walk adds 90 signatures (a tile goes out
-    at the 64th), then the tally is short.  NotEnoughVotingPowerError
-    comes before any mask is read; of the dropped tile no span
-    records and the breaker hears nothing; the next verification, on
-    a verifier of its own, is right."""
-    absent = {i: lambda cs: CommitSig.absent() for i in range(90, 150)}
-    vset, bid, height, commit = variant(150, absent)
-    assert verdict("verify_commit", vset, bid, height, commit) == (
-        commit_light.NOT_ENOUGH_POWER, 900)
-    assert device_path == [TILE]
-    names = {e["name"] for e in tracing.snapshot()}
-    assert "commit_walk" in names and "host_prep" in names
-    assert not names & {"batch_verify", "kernel_execute",
-                        "device_wait", "d2h", "mask_handback"}
+# (validators, signatures the walk adds before it stops, tiles
+# launched by then): a prep alone in flight, a prep and a tile
+IN_FLIGHT = {"a_prep": (150, 90, 0), "a_prep_and_a_tile": (200, 130, 1)}
+
+
+def dropped_cleanly(launched: int) -> None:
+    """What a dropped verifier leaves: no batch_verify and no
+    mask_handback; of a tile that was launched (the golden kernel
+    has finished when feed() looks, so it is settled there) its
+    spans, under a parent that never records; the breaker unheard."""
+    names = [e["name"] for e in tracing.snapshot()]
+    assert "commit_walk" in names
+    assert not {"batch_verify", "mask_handback"} & set(names)
+    assert names.count("kernel_execute") == launched
+    assert names.count("host_prep") == launched
     br = crypto_batch.tpu_breaker()
     assert br.state == "closed" and br._failures == 0
 
+
+@pytest.mark.parametrize("in_flight", IN_FLIGHT)
+def test_a_short_tally_drops_what_is_in_flight(device_path, recorder,
+                                               in_flight):
+    """Too many absent: the walk adds its signatures (a tile's prep
+    begins at every 64th, the tile before it is launched), then the
+    tally is short.  NotEnoughVotingPowerError comes before any mask
+    is read; the prep in flight is taken back or left to finish, the
+    breaker hears nothing; the next verification, on a verifier of
+    its own, is right."""
+    n, present, launched = IN_FLIGHT[in_flight]
+    absent = {i: lambda cs: CommitSig.absent()
+              for i in range(present, n)}
+    vset, bid, height, commit = variant(n, absent)
+    assert verdict("verify_commit", vset, bid, height, commit) == (
+        commit_light.NOT_ENOUGH_POWER, present * 10)
+    assert device_path == [TILE] * launched
+    dropped_cleanly(launched)
+
+    tracing.clear()
     vset, bid, height, commit = variant(150, {70: resigned(forged)})
     assert verdict("verify_commit", vset, bid, height, commit) == (
         commit_light.WRONG_SIGNATURE, 70)
-    assert device_path == [TILE] * 4
+    assert device_path == [TILE] * (launched + 3)
     (seam,) = [e for e in tracing.snapshot()
                if e["name"] == "batch_verify"]
     assert seam["attrs"] == {"backend": "tpu", "batch": 150}
 
 
-def test_a_walk_that_raises_after_a_tile_was_fed(device_path, recorder):
-    """A signature of 63 bytes at index 100: add() refuses it, the
-    walk raises as it always did, the tile fed at the 64th add is
-    dropped without a trace."""
+@pytest.mark.parametrize("in_flight", IN_FLIGHT)
+def test_a_walk_that_raises_drops_what_is_in_flight(
+        device_path, recorder, in_flight):
+    """A signature of 63 bytes: add() refuses it, the walk raises as
+    it always did, what was handed over by then is dropped."""
+    n, at, launched = IN_FLIGHT[in_flight]
     vset, bid, height, commit = variant(
-        150, {100: resigned(lambda sig: sig[:63])})
+        n, {at: resigned(lambda sig: sig[:63])})
     assert verdict("verify_commit", vset, bid, height, commit) == (
-        commit_light.WRONG_SIGNATURE, 100)
-    assert device_path == [TILE]
-    assert not {e["name"] for e in tracing.snapshot()} & {
-        "batch_verify", "kernel_execute"}
-    assert crypto_batch.tpu_breaker().state == "closed"
+        commit_light.WRONG_SIGNATURE, at)
+    assert device_path == [TILE] * launched
+    dropped_cleanly(launched)
     vset, bid, height, commit = variant(150)
     assert verdict("verify_commit", vset, bid, height, commit) == (
         commit_light.ACCEPTED, None)
 
 
-@pytest.mark.parametrize("explodes_at", [0, 1, 2])
+@pytest.mark.parametrize("explodes_at", [0, 1, 2, 3])
 def test_a_kernel_that_raises_sends_the_whole_batch_to_the_cpu(
         device_path, recorder, monkeypatch, crypto_log, explodes_at):
-    """The kernel fails under the first add()-fed tile, the second,
-    or the remainder verify() feeds: each time the failure is
-    recorded once against the breaker (latched: no transient shape),
-    logged, and the WHOLE batch of 150 is judged by the CPU verifier
-    with fallback=True: same verdict, same index."""
+    """200 signatures, four tiles: the kernel fails under tile 0
+    (launched by the 128th add()), under tile 1 (by the 192nd), under
+    tile 2 (by verify()'s hand-over of the remainder) or under the
+    remainder itself: each time the failure is recorded once against
+    the breaker (latched: no transient shape), logged, and the WHOLE
+    batch of 200 is judged by the CPU verifier, from the bytes the
+    seam kept, with fallback=True: same verdict, same index."""
     golden = ej._jit_verify_packed
     calls = []
 
@@ -354,7 +421,7 @@ def test_a_kernel_that_raises_sends_the_whole_batch_to_the_cpu(
 
     for name in KERNELS:
         monkeypatch.setattr(ej, name, exploding)
-    vset, bid, height, commit = variant(150, {140: resigned(forged)})
+    vset, bid, height, commit = variant(200, {140: resigned(forged)})
     try:
         assert verdict("verify_commit", vset, bid, height, commit) \
             == (commit_light.WRONG_SIGNATURE, 140)
@@ -365,9 +432,9 @@ def test_a_kernel_that_raises_sends_the_whole_batch_to_the_cpu(
         # the device's span ends where it failed, with what had
         # been added by then
         assert seams == [
-            {"backend": "tpu", "batch": (TILE, 2 * TILE, 150)[
+            {"backend": "tpu", "batch": (2 * TILE, 3 * TILE, 200, 200)[
                 explodes_at], "error": "RuntimeError"},
-            {"backend": "cpu", "batch": 150, "fallback": True}]
+            {"backend": "cpu", "batch": 200, "fallback": True}]
         errors = [r for r in crypto_log if r.levelname == "ERROR"]
         assert len(errors) == 1
         assert "Mosaic lowering failed" in errors[0].getMessage()
@@ -397,18 +464,15 @@ def test_an_open_breaker_means_no_dispatch_from_add(device_path,
 
 
 def test_verify_async_settles_what_add_dispatched(device_path):
-    """add() on the event loop's thread feeds two tiles; the staging
-    worker that runs verify() feeds the third and settles all."""
+    """add() on the event loop's thread hands over two tiles and
+    launches the first; the staging worker that runs verify()
+    launches the second and the remainder and settles all."""
     from cometbft_tpu.crypto import pipeline
     vset, _, _, commit = variant(150, {130: resigned(forged)})
 
     async def go():
-        bv = crypto_batch.create_batch_verifier(
-            vset.validators[0].pub_key)
-        for i, v in enumerate(vset.validators):
-            bv.add(v.pub_key, commit.vote_sign_bytes(CHAIN_ID, i),
-                   commit.signatures[i].signature)
-        assert device_path == [TILE, TILE]
+        bv = seam_verifier(vset, commit)
+        assert device_path == [TILE]
         return await bv.verify_async()
 
     try:
@@ -456,22 +520,27 @@ def test_golden_kernel_agrees_with_the_golden_model_on_edge_lanes():
 
 def test_the_plan_at_10000_validators(monkeypatch):
     """valset-10k: a light verification takes 6,667 signatures.  The
-    seam's verifier streams them as 4,096 (fed from add()) + 2,571
-    (fed by verify()); verify_batch, handed the whole list, plans two
-    balanced tiles.  Either way every chunk dispatches at the 4,096
-    bucket, the pipeline's one shape, and warm-up warms that one."""
+    seam's verifier streams them as six tiles of 1,024 (handed over
+    from add()) + 523 (by verify()); verify_batch, handed the whole
+    list, plans seven balanced tiles.  Either way every chunk
+    dispatches at the 1,024 bucket, the pipeline's one shape: 7,168
+    lanes for 6,667, and warm-up warms that one shape.  The CPU
+    verifier's MSM keeps its 4,096."""
     monkeypatch.setattr(ej, "SHARD_MIN", 1000000)
     assert 10000 * 10 * 2 // 3 // 10 + 1 == 6667
-    plan = tile_plan(6667, 4096)
-    assert plan == [(0, 3334), (3334, 6667)]
+    assert (crypto_pipeline.TILE, crypto_pipeline.MSM_TILE) == \
+        (1024, 4096)
+    plan = tile_plan(6667, 1024)
+    assert plan == [(lo, min(lo + 953, 6667))
+                    for lo in range(0, 6667, 953)] and len(plan) == 7
     bv = crypto_batch.GuardedTpuBatchVerifier()
-    assert (bv._tile, bv._feed_at) == (4096, 4096)
-    assert 6667 - bv._tile == 2571
+    assert (bv._tile, bv._feed_at) == (1024, 1024)
+    assert divmod(6667, bv._tile) == (6, 523)
     for kernel in ("pallas", "xla"):
         monkeypatch.setenv("COMETBFT_TPU_KERNEL", kernel)
-        assert ej.TilePipeline(bv._tile)._m == 4096
+        assert ej.TilePipeline(bv._tile)._m == 1024
         assert [ej._padded(hi - lo, kernel) for lo, hi in plan] == \
-            [4096, 4096]
+            [1024] * 7
     warmed = []
     for name in KERNELS:
         monkeypatch.setattr(
@@ -483,4 +552,4 @@ def test_the_plan_at_10000_validators(monkeypatch):
         ej.warmup(6667)
     finally:
         ej._warmup_bucket.cache_clear()
-    assert warmed == [(4096, ej.WIRE_LANE_BYTES)]
+    assert warmed == [(1024, ej.WIRE_LANE_BYTES)]

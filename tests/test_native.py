@@ -125,6 +125,139 @@ class TestEd25519Prep:
         assert len(wire) == 8 * 192 and len(bad) == 8
 
 
+def _prep_items(n=200):
+    """A seeded batch with malformed and non-canonical-S items among
+    honest ones, messages across SHA-512's block boundaries."""
+    import random
+
+    from cometbft_tpu.crypto import _ed25519_ref as ref
+    rng = random.Random(36)
+    lengths = [0, 5, 47, 48, 63, 64, 111, 112, 120, 200, 300, 1000]
+    items = []
+    for i in range(n):
+        seed = rng.randbytes(32)
+        msg = rng.randbytes(lengths[i % len(lengths)])
+        pub, sig = ref.public_key(seed), ref.sign(seed, msg)
+        if i % 9 == 4:      # non-canonical S
+            sig = sig[:32] + (ref.L + 5).to_bytes(32, "little")
+        if i % 13 == 6:     # malformed
+            pub = b"short"
+        items.append((pub, msg, sig))
+    if n > 17:
+        items[17] = None
+    return items
+
+
+class TestEd25519PrepFuture:
+    """ed25519_prep_begin: the same prep, phase 2 on the module's
+    native thread, behind a handle."""
+    B, I = b"b" * 32, b"i" * 32
+
+    def _begin(self, items, m=256):
+        native = _native()
+        if not hasattr(native, "ed25519_prep_begin"):
+            pytest.skip("older native module")
+        return native.ed25519_prep_begin(items, m, self.B, self.I)
+
+    def test_result_is_ed25519_preps_and_the_numpy_paths(
+            self, monkeypatch):
+        import numpy as np
+
+        from cometbft_tpu.libs import tracing
+        from cometbft_tpu.ops import ed25519_jax as ej
+        items = _prep_items()
+        t_before = tracing.now_ns()
+        wire, bad, start_ns, elapsed_ns, waited_ns = \
+            self._begin(items).result()
+        t_after = tracing.now_ns()
+        assert (wire, bad) == _native().ed25519_prep(
+            items, 256, self.B, self.I)
+        # the prep's own readings, on the recorder's clock
+        assert t_before <= start_ns <= start_ns + elapsed_ns <= t_after
+        assert elapsed_ns > 0 and 0 <= waited_ns <= t_after - t_before
+        # through ops, against the numpy path (which cannot take the
+        # None among the items)
+        del items[17]
+        h = ej._prep_begin(items, 256)
+        assert type(h).__name__ == "PrepHandle"
+        wire, bad = h.result()[:2]
+        monkeypatch.setenv("COMETBFT_TPU_NATIVE", "0")
+        saved_mod, saved_failed = (_native_loader._mod,
+                                   _native_loader._failed)
+        _native_loader._mod = None
+        try:
+            assert type(ej._prep_begin(items, 256)).__name__ == \
+                "_PrepNow"
+            py_wire, py_bad = ej._prep_begin(items, 256).result()[:2]
+        finally:
+            _native_loader._mod = saved_mod
+            _native_loader._failed = saved_failed
+        assert (wire, bad) == (py_wire, py_bad)
+        assert np.array_equal(ej._wire_arrays(wire, bad, 256)[0],
+                              ej.prep_arrays(items, 256)[0])
+        assert 0 < sum(py_bad) < len(items)
+
+    def test_a_finished_prep_is_not_waited_for(self):
+        import time
+        h = self._begin(_prep_items(64), 64)
+        time.sleep(0.2)
+        assert h.result()[4] == 0
+
+    def test_the_result_is_taken_once(self):
+        h = self._begin(_prep_items(16), 16)
+        h.result()
+        with pytest.raises(RuntimeError):
+            h.result()
+
+    def test_two_handles_in_flight_settle_in_either_order(self):
+        items = _prep_items()
+        want = _native().ed25519_prep(items, 256, self.B, self.I)
+        first, second = self._begin(items), self._begin(items[:100])
+        short = second.result()
+        assert first.result()[:2] == want
+        assert short[:2] == _native().ed25519_prep(
+            items[:100], 256, self.B, self.I)
+        with pytest.raises(ValueError):
+            self._begin(items, 8)       # m < len(items)
+
+    def test_a_handle_dropped_unread_frees_what_it_borrowed(self):
+        import sys
+        items = _prep_items()
+        probe = items[0]
+        refs = sys.getrefcount(probe), sys.getrefcount(items)
+        for _ in range(50):
+            h = self._begin(items)      # queued, running or done
+            del h
+        held = [self._begin(items) for _ in range(4)]
+        assert sys.getrefcount(items) > refs[1]
+        del held
+        assert (sys.getrefcount(probe), sys.getrefcount(items)) == refs
+        # the thread is still there for the next one
+        assert self._begin(items).result()[:2] == \
+            _native().ed25519_prep(items, 256, self.B, self.I)
+
+    def test_a_forked_child_runs_what_its_parent_began(self):
+        """The native thread does not cross a fork: the child runs a
+        prep its parent had posted on the thread that asks for it, and
+        starts a thread of its own at its first begin."""
+        import os
+        import signal
+        items = _prep_items()
+        want = _native().ed25519_prep(items, 256, self.B, self.I)
+        h = self._begin(items)
+        pid = os.fork()
+        if pid == 0:
+            ok = False
+            try:
+                signal.alarm(20)        # a hang fails, it does not wait
+                ok = h.result()[:2] == want and \
+                    self._begin(items).result()[:2] == want
+            finally:
+                os._exit(0 if ok else 1)
+        assert os.waitpid(pid, 0)[1] == 0
+        assert h.result()[:2] == want
+
+
 class TestSha512AndKScalars:
     def test_sha512_many_parity(self):
         native = _native()

@@ -470,10 +470,12 @@ class TestTiledDispatchSpans:
     def test_each_tile_runs_from_dispatch_to_mask(self, recorder,
                                                   monkeypatch):
         """A batch above one tile: one kernel_execute span per tile,
-        begun before the tile's dispatch (so before the next tile's
-        host_prep) and ended after its own settle, the four legs its
-        children.  The kernel is stubbed (its compile takes minutes on
-        a CPU); the dispatch path around it is the real one."""
+        begun before the tile's launch and ended after its own
+        settle, the four legs its children; one host_prep span per
+        tile, the prep's own time on the native thread's clock
+        readings, over before its tile is launched.  The kernel is
+        stubbed (its compile takes minutes on a CPU); the dispatch
+        path around it is the real one."""
         import jax.numpy as jnp
 
         from cometbft_tpu.crypto import ed25519
@@ -490,10 +492,15 @@ class TestTiledDispatchSpans:
         pub = priv.pub_key().bytes()
         items = [(pub, b"m%d" % i, priv.sign(b"m%d" % i))
                  for i in range(150)]
+        streamed = ej.streamed_tiles_counter()
+        before = sum(streamed.with_labels(how).value
+                     for how in ("ready", "waited"))
         with tracing.span(tracing.CRYPTO, "batch_verify", height=9,
                           batch=len(items)) as seam:
             ok, mask = ej.verify_batch(items)
         assert ok and len(mask) == 150
+        assert sum(streamed.with_labels(how).value
+                   for how in ("ready", "waited")) == before + 3
 
         events = tracing.snapshot()
         kids = _children(events)
@@ -503,12 +510,13 @@ class TestTiledDispatchSpans:
         preps = [e for e in events if e["name"] == "host_prep"]
         assert [t["attrs"]["tile"] for t in tiles] == [0, 1, 2]
         assert len(preps) == 3
-        for t in tiles:
+        for t, prep in zip(tiles, preps):
             assert t["parent"] == seam.id and t["height"] == 9
             assert t["attrs"]["pipelined"] is True
             assert t["attrs"]["batch"] == 50
             assert t["attrs"]["bucket"] == 64
             assert t["attrs"]["platform"] == "cpu"
+            assert t["attrs"]["prep_wait_us"] >= 0
             legs = kids[t["id"]]
             assert [e["name"] for e in legs] == \
                 ["h2d", "launch", "device_wait", "d2h"]
@@ -516,14 +524,17 @@ class TestTiledDispatchSpans:
             end = t["ts_ns"] + t["dur_ns"]
             assert t["ts_ns"] <= legs[0]["ts_ns"]
             assert legs[3]["ts_ns"] + legs[3]["dur_ns"] <= end
+            # the tile's prep: under the seam, inside it, a cost of
+            # its own and over before the launch
+            assert prep["parent"] == seam.id and prep["height"] == 9
+            assert prep["attrs"] == {"batch": 50, "bucket": 64,
+                                     "pipelined": True}
+            assert seam.t0 <= prep["ts_ns"] and prep["dur_ns"] > 0
+            assert prep["ts_ns"] + prep["dur_ns"] <= t["ts_ns"]
+        # a tile's prep is begun before the tile before it is launched
         for i in (0, 1):
-            nxt = preps[i + 1]
-            # tile i is in flight before the next tile's prep begins
-            # and is settled only after that prep has ended
-            assert tiles[i]["ts_ns"] < nxt["ts_ns"]
-            assert tiles[i]["ts_ns"] + tiles[i]["dur_ns"] > \
-                nxt["ts_ns"] + nxt["dur_ns"]
-            assert nxt["parent"] == seam.id
+            assert preps[i]["ts_ns"] < preps[i + 1]["ts_ns"]
+            assert tiles[i]["ts_ns"] < tiles[i + 1]["ts_ns"]
 
     @pytest.fixture
     def seam(self, recorder, monkeypatch):
@@ -566,14 +577,16 @@ class TestTiledDispatchSpans:
 
     def test_a_streamed_batch_opens_its_span_at_the_first_feed(
             self, seam):
-        """150 items at a 64-lane tile: add() feeds tiles 0 and 1
-        inside the walk, verify() the 22 left.  batch_verify opens at
-        the first feed, a child of the span open when the verifier
-        was made (commit_verify) and not of the walk it overlaps;
-        under it, a tile: item_handover, host_prep, kernel_execute
-        (eager on the fed tiles only), item_release inside the tile's
-        flight; mask_handback last (what
-        benchmark/layers/seam_outside_tiles_ms and
+        """150 items at a 64-lane tile: add() hands over tiles 0 and
+        1 inside the walk (the 64th add begins tile 0's prep, the
+        128th launches it), verify() the 22 left.  batch_verify opens
+        at the first hand-over, a child of the span open when the
+        verifier was made (commit_verify) and not of the walk it
+        overlaps; under it, a tile: host_prep (the prep's own time)
+        and kernel_execute with ``tile``, ``prep_wait_us`` and, on
+        the tiles handed over from add() only, ``eager``;
+        mask_handback last; no span of a hand-over, which is a list
+        slice now (what benchmark/layers/seam_outside_tiles_ms and
         eager_tiles_per_commit read is made of them)."""
         request = seam(150)
         events = tracing.snapshot()
@@ -585,35 +598,33 @@ class TestTiledDispatchSpans:
         walk_end = walk["ts_ns"] + walk["dur_ns"]
         assert walk["ts_ns"] < bv["ts_ns"] < walk_end
         assert bv["ts_ns"] + bv["dur_ns"] > walk_end
-        kids = sorted(_children(events)[bv["id"]],
-                      key=lambda e: e["ts_ns"])
-        assert [e["name"] for e in kids] == [
-            "item_handover", "host_prep", "kernel_execute",
-            "item_release"] * 3 + ["mask_handback"]
+        kids = _children(events)[bv["id"]]
+        assert sorted(e["name"] for e in kids) == \
+            ["host_prep"] * 3 + ["kernel_execute"] * 3 + \
+            ["mask_handback"]
+        preps = [e for e in kids if e["name"] == "host_prep"]
         tiles = [e for e in kids if e["name"] == "kernel_execute"]
-        for i, t in enumerate(tiles):
+        for i, (prep, t) in enumerate(zip(preps, tiles)):
             a = t["attrs"]
             assert a["pipelined"] is True and a["tile"] == i
             assert a["bucket"] == 64 and "warm" in a
             assert a["batch"] == (64, 64, 22)[i]
             assert a.get("eager") == (True, True, None)[i]
-            # fed from the walk, or after it
-            assert (t["ts_ns"] < walk_end) == (i < 2)
-        # the first span of the batch is the first tile's hand-over,
-        # and each tile's items are freed while its kernel is out
-        assert kids[0]["ts_ns"] >= bv["ts_ns"]
-        for i in range(3):
-            hand, prep, tile, release = kids[4 * i:4 * i + 4]
-            assert hand["ts_ns"] + hand["dur_ns"] <= prep["ts_ns"]
-            assert prep["ts_ns"] + prep["dur_ns"] <= tile["ts_ns"]
-            assert tile["ts_ns"] < release["ts_ns"] < \
-                tile["ts_ns"] + tile["dur_ns"]
+            assert isinstance(a["prep_wait_us"], int)
+            # launched from the walk, or after it
+            assert (t["ts_ns"] < walk_end) == (i < 1)
+            assert prep["attrs"]["batch"] == a["batch"]
+            assert bv["ts_ns"] <= prep["ts_ns"]
+            assert prep["ts_ns"] + prep["dur_ns"] <= t["ts_ns"]
+        # tile 0's prep ran beside the walk
+        assert preps[0]["ts_ns"] + preps[0]["dur_ns"] < walk_end
         ends = [e["ts_ns"] + e["dur_ns"] for e in kids]
         assert max(ends[:-1]) <= kids[-1]["ts_ns"]
+        assert kids[-1]["name"] == "mask_handback"
         assert ends[-1] <= bv["ts_ns"] + bv["dur_ns"]
         # a span where the time is, not one a signature
-        assert sum(1 for e in events if e["name"] in (
-            "item_handover", "mask_handback", "item_release")) == 7
+        assert not [e for e in events if e["name"] in (
+            "item_handover", "item_release")]
 
     def test_an_untiled_batch_keeps_its_tree(self, seam):
         """Below one tile nothing is fed from add(): batch_verify
@@ -629,11 +640,10 @@ class TestTiledDispatchSpans:
         kids = sorted(_children(events)[bv["id"]],
                       key=lambda e: e["ts_ns"])
         assert [e["name"] for e in kids] == [
-            "item_handover", "host_prep", "kernel_execute",
-            "item_release"]
-        assert "pipelined" not in kids[2]["attrs"]
-        assert "eager" not in kids[2]["attrs"]
-        assert [e["name"] for e in _children(events)[kids[2]["id"]]] \
+            "host_prep", "kernel_execute"]
+        for attr in ("pipelined", "eager", "prep_wait_us"):
+            assert attr not in kids[1]["attrs"]
+        assert [e["name"] for e in _children(events)[kids[1]["id"]]] \
             == ["h2d", "launch", "device_wait", "d2h"]
         ends = [e["ts_ns"] + e["dur_ns"] for e in kids]
         assert all(a <= b["ts_ns"] for a, b in zip(ends, kids[1:]))

@@ -780,7 +780,7 @@ def bench_ed25519_pipelined_dispatch(fast: bool):
     mono = _cpu_bv(items, monolithic=True)
 
     hist = cpipe.dispatch_histogram()
-    tile = str(cpipe.TILE)
+    tile = str(cpipe.MSM_TILE)
     prep = hist.with_labels("host_prep", "native", tile, "1")
     execu = hist.with_labels("kernel_execute", "native", tile, "1")
     prep0, exec0 = prep._sum, execu._sum
