@@ -238,7 +238,8 @@ class BlocksyncReactor(Reactor):
                 if not ready:
                     continue
                 with tracing.span(tracing.BLOCKSYNC, "sync_height",
-                                  height=first.header.height) as sp:
+                                  height=first.header.height,
+                                  runtime=True) as sp:
                     applied = await self._sync_height(
                         pool, first, second, first_ext)
                     sp.note(outcome="applied" if applied

@@ -407,7 +407,8 @@ async def playback(config, state: SMState, state_store: Store,
         while (first := next(records, None)) is not None:
             height = cs.rs.height
             with tracing.span(tracing.CONSENSUS, "replay_height",
-                              height=height) as sp:
+                              height=height,
+                              runtime=True) as sp:
                 await _feed(cs, itertools.chain((first,), records))
                 done = cs.rs.height > height
                 sp.note(outcome="committed" if done else "stalled")

@@ -36,16 +36,20 @@ class RateLimiter:
                            self._tokens + (now - self._last) * self.rate)
         self._last = now
 
-    async def take(self, n: int) -> None:
-        """Account n bytes, sleeping as needed to hold the target rate."""
+    async def take(self, n: int) -> float:
+        """Account n bytes, sleeping as needed to hold the target rate.
+        Returns the seconds it slept: 0.0 when the bytes fit."""
         self._account(n)
         if self.rate <= 0:
-            return
+            return 0.0
         self._refill()
         self._tokens -= n
-        if self._tokens < 0:
-            # sleep until the deficit refills
-            await asyncio.sleep(-self._tokens / self.rate)
+        if self._tokens >= 0:
+            return 0.0
+        # sleep until the deficit refills
+        t0 = time.monotonic()
+        await asyncio.sleep(-self._tokens / self.rate)
+        return time.monotonic() - t0
 
     def try_take(self, n: int) -> bool:
         """Non-blocking: True (and accounted) if n bytes fit now."""

@@ -30,6 +30,7 @@ v2 additions (the "metrics v2 + perf lab" layer):
 """
 from __future__ import annotations
 
+import resource
 import threading
 import time
 from typing import Optional, Sequence
@@ -440,6 +441,47 @@ def render_merged(*registries: Registry,
 # breaker state live here (they have no node context) — the node's
 # /metrics endpoint merges this registry in via render_merged().
 DEFAULT = Registry()
+
+
+# The interpreter's own time, read when /metrics is scraped (upstream's
+# go_gc_duration_seconds and process_cpu_seconds_total come from its
+# client's default collectors): the recorder's collection hook keeps
+# the totals (libs/tracing.py), the kernel the rest.  Nothing is
+# observed on a hot path.
+def _gc_totals(col: int, scale: float) -> dict:
+    return {str(gen): t[col] * scale
+            for gen, t in enumerate(tracing.gc_generation_totals())}
+
+
+def _cpu_seconds() -> dict:
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return {"user": ru.ru_utime, "system": ru.ru_stime}
+
+
+def _context_switches() -> dict:
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return {"voluntary": ru.ru_nvcsw, "involuntary": ru.ru_nivcsw}
+
+
+DEFAULT.counter_func(
+    "runtime", "gc_pause_seconds_total",
+    "Seconds the interpreter spent in garbage collections, by the "
+    "generation collected (2 = a full collection).", "generation",
+    lambda: _gc_totals(0, 1e-9))
+DEFAULT.counter_func(
+    "runtime", "gc_collections_total",
+    "Garbage collections, by the generation collected.", "generation",
+    lambda: _gc_totals(1, 1))
+DEFAULT.counter_func(
+    "process", "cpu_seconds_total",
+    "CPU time the process has used, in user and in system mode "
+    "(getrusage): against wall time it says whether the host ran the "
+    "process or kept it waiting.", "mode", _cpu_seconds)
+DEFAULT.counter_func(
+    "process", "context_switches_total",
+    "Context switches of the process (getrusage): voluntary = it "
+    "waited, involuntary = the host took the CPU away.", "kind",
+    _context_switches)
 
 
 class Timer:

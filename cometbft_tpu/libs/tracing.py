@@ -19,6 +19,11 @@ Readers:
   * ``tools/trace_report.py`` — per-height gossip/verify/execute/commit
     breakdown rendered from a dump.
 
+The interpreter's own time (category ``runtime``): one ``gc.callbacks``
+hook keeps the process's garbage-collection pause totals and records a
+``gc_pause`` span under the span the collection struck; a span opened
+with ``runtime=True`` notes the pauses that fell inside it (``gc_us``).
+
 Disabled mode compiles to a no-op: ``span()`` returns a shared inert
 context manager and ``instant()`` returns immediately — the benchmark
 guard in tests/test_tracing.py holds the disabled path under 1µs per
@@ -54,6 +59,7 @@ addrbook save/load conversion.
 from __future__ import annotations
 
 import contextvars
+import gc
 import itertools
 import json
 import os
@@ -74,9 +80,10 @@ STATE = "state"
 SUPERVISOR = "supervisor"
 NEMESIS = "nemesis"
 LIGHT = "light"
+RUNTIME = "runtime"
 
 CATEGORIES = (CONSENSUS, CRYPTO, P2P, MEMPOOL, ABCI, BLOCKSYNC, STATE,
-              SUPERVISOR, NEMESIS, LIGHT)
+              SUPERVISOR, NEMESIS, LIGHT, RUNTIME)
 
 now_ns = time.monotonic_ns
 _get_ident = threading.get_ident
@@ -278,6 +285,57 @@ class Recorder:
 _R = Recorder()
 
 
+# ---------------------------------------------------------------------
+# the interpreter's own time: garbage collections
+
+# a generation-0 collection shorter than this is counted, not recorded
+# (hundreds a second would fill the ring)
+GC_SPAN_MIN_NS = 1_000_000
+
+_gc_ns = 0                  # pauses so far, all generations
+_gc_gen_ns = [0, 0, 0]      # ... by generation
+_gc_gen_n = [0, 0, 0]       # collections by generation
+_gc_t0 = 0                  # start of the collection under way
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry.  A collection holds the GIL from start
+    to stop and never nests, so one start time and plain ints do; it
+    runs on the thread that tripped it, whose open span is the parent
+    of the ``gc_pause``.  Counts with the recorder off too."""
+    global _gc_t0, _gc_ns
+    if phase == "start":
+        _gc_t0 = now_ns()
+        return
+    t1 = now_ns()
+    t0 = _gc_t0
+    gen = info["generation"]
+    _gc_ns += t1 - t0
+    _gc_gen_ns[gen] += t1 - t0
+    _gc_gen_n[gen] += 1
+    if gen or t1 - t0 >= GC_SPAN_MIN_NS:
+        r = _R
+        if r.enabled and (r.categories is None or
+                          RUNTIME in r.categories):
+            parent, height = _causal(0)
+            r.record(RUNTIME, "gc_pause", t0, t1, height,
+                     {"generation": gen,
+                      "collected": info["collected"]}, parent=parent)
+
+
+def gc_ns_total() -> int:
+    """Nanoseconds this process has spent in garbage collections."""
+    return _gc_ns
+
+
+def gc_generation_totals() -> tuple[tuple[int, int], ...]:
+    """(pause ns, collections) of generation 0, 1 and 2."""
+    return tuple(zip(_gc_gen_ns, _gc_gen_n))
+
+
+gc.callbacks.append(_gc_hook)
+
+
 class _NopSpan:
     """Shared inert context manager for the disabled path."""
     __slots__ = ()
@@ -301,12 +359,16 @@ class _Span:
     context, for an interval that is not one lexical block (a
     pipelined tile: :func:`under` then names it the parent of each
     piece).  ``_r`` is None for a :func:`timed` span whose category
-    is off: it reads the clock for its caller and records nothing."""
+    is off: it reads the clock for its caller and records nothing.
+    ``_rt`` is the ``runtime`` flag: a recording span that has it
+    reads the collector's pause total at both ends and notes
+    ``gc_us``."""
     __slots__ = ("_r", "cat", "name", "height", "attrs", "t0", "t1",
-                 "id", "parent", "closed", "_token")
+                 "id", "parent", "closed", "_token", "_rt", "_gc0")
 
     def __init__(self, r: Optional[Recorder], cat: str, name: str,
-                 height: int, attrs: Optional[dict]):
+                 height: int, attrs: Optional[dict],
+                 runtime: bool = False):
         self._r = r
         self.cat = cat
         self.name = name
@@ -316,11 +378,16 @@ class _Span:
         self.id = self.parent = 0
         self.closed = False
         self._token = None
+        # the reading belongs to the runtime category, as gc_pause does
+        self._rt = runtime and r is not None and (
+            r.categories is None or RUNTIME in r.categories)
 
     def begin(self):
         if self._r is not None:
             self.parent, self.height = _causal(self.height)
             self.id = next(_IDS)
+            if self._rt:
+                self._gc0 = _gc_ns
         self.t0 = now_ns()
         return self
 
@@ -328,6 +395,10 @@ class _Span:
         self.t1 = now_ns()
         self.closed = True
         if self._r is not None:
+            if self._rt:
+                if self.attrs is None:
+                    self.attrs = {}
+                self.attrs["gc_us"] = (_gc_ns - self._gc0) // 1000
             self._r.record(self.cat, self.name, self.t0, self.t1,
                            self.height, self.attrs, self.id,
                            self.parent)
@@ -378,26 +449,33 @@ class _Under:
 # ---------------------------------------------------------------------
 # module-level API — what the instrumented call sites use
 
-def span(category: str, name: str, height: int = 0, **attrs):
+def span(category: str, name: str, height: int = 0, *,
+         runtime: bool = False, **attrs):
     """Context manager recording a monotonic span on exit.  When the
-    category (or tracing) is disabled this is a no-op."""
+    category (or tracing) is disabled this is a no-op.  ``runtime``
+    makes the span note what of the interpreter's own time fell
+    inside it: ``gc_us``, the process's garbage-collection pauses (a
+    collection holds the GIL, so it is the right charge whichever
+    thread or task the span is on)."""
     r = _R
     if not r.enabled or (r.categories is not None and
                          category not in r.categories):
         return _NOP
-    return _Span(r, category, name, height, attrs or None)
+    return _Span(r, category, name, height, attrs or None, runtime)
 
 
-def timed(category: str, name: str, height: int = 0, **attrs) -> _Span:
+def timed(category: str, name: str, height: int = 0, *,
+          runtime: bool = False, **attrs) -> _Span:
     """A span whose clock readings its caller also uses (``.seconds``
     after it closed): one pair of readings feeds the span and the
     metric observed at the same boundary.  Always reads the clock;
-    records only when the category is on."""
+    records (and reads what ``runtime`` asks for) only when the
+    category is on."""
     r = _R
     on = r.enabled and (r.categories is None or
                         category in r.categories)
     return _Span(r if on else None, category, name, height,
-                 attrs or None)
+                 attrs or None, runtime)
 
 
 def under(sp):

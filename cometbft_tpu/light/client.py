@@ -123,7 +123,7 @@ class Client:
         if height <= 0:
             raise LightClientError("height must be positive")
         with tracing.span(tracing.LIGHT, "light_sync", height,
-                          to=height) as sync:
+                          runtime=True, to=height) as sync:
             with tracing.span(tracing.LIGHT, "light_store_read"):
                 existing, base = self._stored(height)
             if existing is not None:
@@ -165,6 +165,7 @@ class Client:
         if new.height <= latest.height:
             return None
         with tracing.span(tracing.LIGHT, "light_sync", new.height,
+                          runtime=True,
                           **{"from": latest.height, "to": new.height}):
             return await self._verify_forward(latest, new.height, now,
                                               prefetched=new)

@@ -205,18 +205,16 @@ class MConnection:
                 payload, eof = ch.next_packet()
                 pkt = bytes([_PKT_MSG, ch.desc.id,
                              1 if eof else 0]) + payload
-                _t0 = asyncio.get_running_loop().time()
-                await self.send_limiter.take(len(pkt))
-                _dt = asyncio.get_running_loop().time() - _t0
-                if _dt > 0:
+                slept = await self.send_limiter.take(len(pkt))
+                if slept > 0:
                     self.metrics.send_rate_limiter_delay.with_labels(
-                        self.peer_id).add(_dt)
+                        self.peer_id).add(slept)
                     self.metrics.queue_stall_seconds.with_labels(
-                        f"{ch.desc.id:#x}").observe(_dt)
+                        f"{ch.desc.id:#x}").observe(slept)
                     tracing.instant(tracing.P2P, "send_rate_stall",
                                     chan=ch.desc.id,
                                     peer=self.peer_id[:12],
-                                    stall_ms=round(_dt * 1e3, 3))
+                                    stall_ms=round(slept * 1e3, 3))
                 await self._sconn.write_msg(pkt)
                 if eof:
                     # one event per complete message, not per packet
@@ -245,12 +243,10 @@ class MConnection:
         try:
             while not self._closed:
                 msg = await self._sconn.read_msg()
-                _t0 = asyncio.get_running_loop().time()
-                await self.recv_limiter.take(len(msg))
-                _dt = asyncio.get_running_loop().time() - _t0
-                if _dt > 0:
+                slept = await self.recv_limiter.take(len(msg))
+                if slept > 0:
                     self.metrics.recv_rate_limiter_delay.with_labels(
-                        self.peer_id).add(_dt)
+                        self.peer_id).add(slept)
                 self._last_recv = asyncio.get_running_loop().time()
                 if len(msg) >= 2 and msg[0] == _PKT_MSG:
                     self.metrics.message_receive_bytes_total \

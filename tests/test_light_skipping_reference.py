@@ -320,6 +320,8 @@ def test_one_request_is_one_span_tree(recorder, batches):
 
     (root,) = [e for e in events if e["name"] == "light_sync"]
     assert root["parent"] == 0 and root["category"] == tracing.LIGHT
+    # gc_us: the collections that struck the request (ISSUE 37)
+    assert root["attrs"].pop("gc_us") >= 0
     assert root["attrs"] == {"from": 1, "to": HEIGHTS}
     hops = below(root, "light_hop")
     assert len(hops) == len(want) == 3      # 33 refused, 17, 33
@@ -395,6 +397,7 @@ def test_every_way_in_opens_one_root(recorder, way_in):
     events = tracing.snapshot(category=tracing.LIGHT)
     (root,) = [e for e in events if e["name"] == "light_sync"]
     below = {e["name"] for e in events if e["parent"] == root["id"]}
+    assert root["attrs"].pop("gc_us") >= 0
     if way_in == "stored":
         assert root["attrs"] == {"to": HEIGHTS}
         assert below == {"light_store_read"}

@@ -309,6 +309,10 @@ _ALLOWED_LABELS = {
     "prep",         # whether a streamed tile's launch found its host
                     # prep done: two literals (ready / waited), bound
                     # in ops/ed25519_jax.TilePipeline._launch_tile
+    "generation",   # the collector's three generations (0 / 1 / 2),
+                    # the indices of libs/tracing's totals
+    "mode",         # CPU time in user or system mode: two literals,
+                    # the keys of libs/metrics._cpu_seconds
 }
 
 
@@ -421,6 +425,95 @@ def _mk_cfg(d, name):
     return cfg, pv
 
 
+RUNTIME_FAMILIES = {
+    "cometbft_runtime_gc_pause_seconds_total":
+        ("generation", {"0", "1", "2"}),
+    "cometbft_runtime_gc_collections_total":
+        ("generation", {"0", "1", "2"}),
+    "cometbft_process_cpu_seconds_total": ("mode", {"user", "system"}),
+    "cometbft_process_context_switches_total":
+        ("kind", {"voluntary", "involuntary"}),
+}
+
+
+def _family_values(fams: dict, name: str) -> dict:
+    label, _ = RUNTIME_FAMILIES[name]
+    return {labels[label]: v for _, labels, v in fams[name]["samples"]}
+
+
+class TestRuntimeFamilies:
+    """The interpreter's own time on the process-global registry:
+    read at scrape time, nothing observed on a hot path."""
+
+    @pytest.mark.parametrize("name", sorted(RUNTIME_FAMILIES))
+    def test_family_is_a_counter_with_its_literals(self, name):
+        fams = assert_exposition_contract(DEFAULT.render())
+        label, values = RUNTIME_FAMILIES[name]
+        assert fams[name]["type"] == "counter"
+        assert set(_family_values(fams, name)) == values
+        assert all(v >= 0 for v in _family_values(fams, name).values())
+
+    def test_pause_totals_are_the_sum_of_the_hooks_pauses(self):
+        """Per generation, what /metrics gained over a stretch is what
+        the gc_pause spans of that stretch add up to (with the span
+        threshold at zero every collection is a span), and what the
+        benchmark's own snapshot of the registry reads."""
+        import gc
+        import sys
+        root = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        if root not in sys.path:
+            sys.path.insert(0, root)
+        from benchmark.lib import probes
+
+        pauses = "cometbft_runtime_gc_pause_seconds_total"
+        counts = "cometbft_runtime_gc_collections_total"
+        old = tracing.set_recorder(tracing.Recorder(buffer_size=1024))
+        threshold = tracing.GC_SPAN_MIN_NS
+        tracing.GC_SPAN_MIN_NS = 0
+        was = gc.isenabled()
+        gc.disable()
+        try:
+            before = parse_exposition(DEFAULT.render())
+            snap0 = probes.metrics_snapshot(DEFAULT)
+            for generation in (0, 1, 2, 2, 0):
+                gc.collect(generation)
+            after = parse_exposition(DEFAULT.render())
+            delta = probes.metrics_delta(
+                snap0, probes.metrics_snapshot(DEFAULT))
+            spans = [e for e in tracing.snapshot()
+                     if e["name"] == "gc_pause"]
+        finally:
+            if was:
+                gc.enable()
+            tracing.GC_SPAN_MIN_NS = threshold
+            tracing.set_recorder(old)
+        assert len(spans) == 5
+        for gen, n in (("0", 2), ("1", 1), ("2", 2)):
+            span_s = sum(e["dur_ns"] for e in spans
+                         if str(e["attrs"]["generation"]) == gen) / 1e9
+            got = (_family_values(after, pauses)[gen]
+                   - _family_values(before, pauses)[gen])
+            assert got == pytest.approx(span_s, rel=1e-6, abs=1e-9)
+            assert (_family_values(after, counts)[gen]
+                    - _family_values(before, counts)[gen]) == n
+            assert probes.total(delta, pauses, generation=gen) == \
+                pytest.approx(span_s, rel=1e-6, abs=1e-9)
+            assert probes.total(delta, counts, generation=gen) == n
+
+    def test_cpu_seconds_follow_the_work(self):
+        def cpu():
+            return sum(_family_values(
+                parse_exposition(DEFAULT.render()),
+                "cometbft_process_cpu_seconds_total").values())
+        import time
+        c0 = cpu()
+        t_end = time.perf_counter() + 0.05
+        while time.perf_counter() < t_end:
+            pass
+        assert cpu() - c0 > 0.02
+
+
 class TestLiveExpositionContract:
     def test_live_multi_validator_metrics_contract(self):
         """GET /metrics on a live 3-validator net passes the full
@@ -503,6 +596,13 @@ class TestLiveExpositionContract:
                     assert hist_observed(
                         "cometbft_crypto_batch_verify_seconds",
                         backend="cpu", pad_bucket="64")
+                    # the interpreter's own time, merged in from the
+                    # process-global registry
+                    for name, (label, values) in \
+                            RUNTIME_FAMILIES.items():
+                        assert fams[name]["type"] == "counter", name
+                        assert {lb[label] for _, lb, _ in
+                                fams[name]["samples"]} == values, name
                     # the stall family serves its full bucket ladder
                     # even before any stall happened
                     stall = fams[

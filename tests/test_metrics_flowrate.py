@@ -83,6 +83,88 @@ class TestRateLimiter:
             assert elapsed < 4.0
         asyncio.run(run())
 
+    def test_take_returns_what_it_slept(self):
+        async def run():
+            lim = RateLimiter(100_000)      # 100 kB burst
+            assert await lim.take(60_000) == 0.0
+            slept = await lim.take(60_000)  # 20 kB over: ~0.2 s
+            assert 0.15 < slept < 1.0
+            assert await RateLimiter(0).take(10**9) == 0.0
+        asyncio.run(run())
+
+    @pytest.mark.parametrize("send_rate,stalls", [
+        (5_120_000, False),     # the default: every packet fits
+        (1_000, True),          # a 1,027-byte packet into 1,000 tokens
+    ])
+    def test_send_rate_stall_only_when_take_slept(self, send_rate,
+                                                  stalls):
+        """The stall instant and the two stall metrics are for a sender
+        the limiter held back, not for every packet: two clock readings
+        always differ."""
+        from cometbft_tpu.libs import tracing
+        from cometbft_tpu.p2p.conn import ChannelDescriptor, MConnection
+        from cometbft_tpu.p2p.metrics import Metrics
+
+        class Pipe:
+            """A secret connection that takes everything and says
+            nothing."""
+
+            def __init__(self):
+                self.written = []
+                self._never = asyncio.Event()
+
+            async def write_msg(self, data):
+                self.written.append(data)
+
+            async def read_msg(self):
+                await self._never.wait()
+
+            def close(self):
+                pass
+
+        async def run():
+            pipe = Pipe()
+            metrics = Metrics(Registry())
+
+            async def on_receive(cid, msg):
+                pass
+
+            conn = MConnection(
+                pipe, [ChannelDescriptor(id=0x20)], on_receive,
+                lambda e: None, send_rate=send_rate, metrics=metrics,
+                peer_id="peer-under-test")
+            conn.start()
+            try:
+                assert conn.send(0x20, b"x" * 1500)     # two packets
+                for _ in range(200):
+                    if len(pipe.written) == 2:
+                        break
+                    await asyncio.sleep(0.01)
+                assert len(pipe.written) == 2
+            finally:
+                conn.close()
+            return metrics
+
+        old = tracing.set_recorder(tracing.Recorder(buffer_size=1024))
+        try:
+            metrics = asyncio.run(run())
+            events = tracing.snapshot(category=tracing.P2P)
+        finally:
+            tracing.set_recorder(old)
+        assert [e["name"] for e in events].count("send") == 1
+        stall_events = [e for e in events
+                        if e["name"] == "send_rate_stall"]
+        delay = metrics.send_rate_limiter_delay.with_labels(
+            "peer-under-test").value
+        observed = metrics.queue_stall_seconds.with_labels(
+            "0x20")._count
+        if stalls:
+            assert stall_events and delay > 0 and observed > 0
+            assert len(stall_events) == observed
+            assert all(e["attrs"]["stall_ms"] > 0 for e in stall_events)
+        else:
+            assert stall_events == [] and delay == 0 and observed == 0
+
     def test_try_take(self):
         lim = RateLimiter(1000, burst=1000)
         assert lim.try_take(800)

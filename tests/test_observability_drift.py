@@ -96,6 +96,21 @@ class TestTraceReportNamePinning:
             "one span per boundary: a name the state ring has is "
             "not opened again by consensus")
 
+    def test_runtime_spans_are_what_the_hook_records(self):
+        """The runtime category has one writer, the recorder's own
+        collection hook (libs/tracing.py), which records through
+        ``r.record`` and not through the module-level API the census
+        above scans for."""
+        tr = _load("trace_report")
+        with open(os.path.join(_PKG, "libs", "tracing.py")) as f:
+            src = f.read()
+        emitted = set(re.findall(
+            r"\.record\(\s*RUNTIME\s*,\s*\"([^\"]+)\"", src))
+        assert emitted == set(tr.RUNTIME_SPANS), (
+            emitted, tr.RUNTIME_SPANS)
+        for spans in (_emitted("RUNTIME")):
+            assert not spans, "a second writer of the runtime category"
+
     def test_consensus_instants_all_marked(self):
         tr = _load("trace_report")
         _, instants = _emitted("CONSENSUS")

@@ -69,6 +69,8 @@ def collect_catalog() -> list[dict]:
     light_client.hop_counters()
     bls12381._agg_pk_metrics()
     types_validation.commit_verify_histogram()
+    # registered where the module is imported
+    from cometbft_tpu.consensus import replay as _replay  # noqa: F401
     # verification pipeline: overlap ratio + tile rejects, and the
     # staging/kernel workers' queue-wait/depth families (register a
     # worker on a throwaway registry-backed pair via the lazy

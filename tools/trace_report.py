@@ -32,6 +32,13 @@ timestamp falls into.  Buckets:
                separately because pipelined work off the critical path
                must not be read as height wall-clock.
 
+The ``runtime`` column is the interpreter's own time inside the
+height's window: ms of garbage collection (``gc``: the ``gc_us`` of
+the height's outermost spans that note it, every collection counted),
+of which recorded as ``gc_pause`` spans by generation (``g0/g1/g2``: a
+generation-0 collection under 1 ms is not recorded), and the ms of
+``commit_release`` (``rel``: a commit's verifier and entries freed).
+
 Marker instants (compact-block relay, aggregate-commit catchup, vote
 and part arrivals) are counted per height in the ``markers`` column —
 they carry no duration, but their counts tell the protocol story
@@ -61,6 +68,8 @@ CONSENSUS_SPAN_BUCKETS = {
     # names, added to no bucket
     "commit_verify": None,
     "commit_walk": None,
+    # read by name into the runtime column
+    "commit_release": None,
     # a burst and a WAL playback (consensus/state.py, replay.py):
     # frames around the step, state and crypto spans already summed
     "vote_preverify": None,
@@ -84,6 +93,10 @@ STATE_SPAN_BUCKETS = {
     "state_save": None,
     "fire_events": None,
 }
+
+# runtime span -> what the runtime column does with it (pinned against
+# what libs/tracing.py's collection hook records)
+RUNTIME_SPANS = frozenset({"gc_pause"})
 
 # consensus instants counted per height (zero-duration markers)
 CONSENSUS_MARKERS = frozenset({
@@ -115,6 +128,8 @@ def _events(record: dict) -> list[dict]:
             "name": e.get("name", ""),
             "height": _to_int(e.get("height")),
             "attrs": e.get("attrs") or {},
+            "id": _to_int(e.get("id")),
+            "parent": _to_int(e.get("parent")),
         })
     out.sort(key=lambda e: e["ts_ns"])
     return out
@@ -148,12 +163,31 @@ def _attribute(events: list[dict],
                 break
 
 
+def _outermost_gc_us(events: list[dict]) -> dict[int, int]:
+    """height -> ``gc_us`` summed over the spans that note it and lie
+    under no other span that does (a sync_height's holds its
+    commit_verifys')."""
+    by_id = {e["id"]: e for e in events if e["id"]}
+    out: dict[int, int] = {}
+    for e in events:
+        if "gc_us" not in e["attrs"]:
+            continue
+        up = by_id.get(e["parent"])
+        while up is not None and "gc_us" not in up["attrs"]:
+            up = by_id.get(up["parent"])
+        if up is None:
+            out[e["height"]] = out.get(e["height"], 0) + \
+                _to_int(e["attrs"]["gc_us"])
+    return out
+
+
 def analyze(record: dict,
             height: Optional[int] = None) -> dict[int, dict]:
     """Per-height breakdown (values in ms) keyed by height."""
     events = _events(record)
     windows = _height_windows(events)
     _attribute(events, windows)
+    gc_us = _outermost_gc_us(events)
     out: dict[int, dict] = {}
     for h, (lo, hi) in sorted(windows.items()):
         if height is not None and h != height:
@@ -162,6 +196,8 @@ def analyze(record: dict,
                "verify_ms": 0.0, "execute_ms": 0.0, "commit_ms": 0.0,
                "pipeline_ms": 0.0,
                "p2p_events": 0, "p2p_bytes": 0, "stalls": 0,
+               "gc_ms": gc_us.get(h, 0) / 1e3,
+               "gc_pause_ms": [0.0, 0.0, 0.0], "release_ms": 0.0,
                "markers": {}, "batches": []}
         propose_span = 0.0
         proposal_complete_ts = None
@@ -194,7 +230,12 @@ def analyze(record: dict,
                     e["attrs"].get("bytes", 0))
                 if name.endswith(("_full", "_stall")):
                     row["stalls"] += 1
+            elif cat == "runtime" and name in RUNTIME_SPANS:
+                gen = _to_int(e["attrs"].get("generation"))
+                row["gc_pause_ms"][min(max(gen, 0), 2)] += dur / _MS
             elif cat == "consensus":
+                if name == "commit_release":
+                    row["release_ms"] += dur / _MS
                 bucket = CONSENSUS_SPAN_BUCKETS.get(name)
                 if bucket is not None:
                     row[bucket + "_ms"] += dur / _MS
@@ -210,6 +251,12 @@ def analyze(record: dict,
                             else propose_span)
         out[h] = row
     return out
+
+
+def _runtime_cell(r: dict) -> str:
+    return (f"gc {r['gc_ms']:.1f} (" +
+            "/".join(f"{ms:.1f}" for ms in r["gc_pause_ms"]) +
+            f") rel {r['release_ms']:.2f}")
 
 
 def render_report(record: dict,
@@ -229,7 +276,7 @@ def render_report(record: dict,
         return "\n".join(lines) + "\n"
     hdr = (f"{'height':>7} {'wall_ms':>9} {'gossip_ms':>10} "
            f"{'verify_ms':>10} {'execute_ms':>11} {'commit_ms':>10} "
-           f"{'pipe_ms':>8} {'p2p ev':>7} {'stalls':>7}")
+           f"{'pipe_ms':>8} {'p2p ev':>7} {'stalls':>7}  runtime")
     lines.append(hdr)
     lines.append("-" * len(hdr))
     for h, r in rows.items():
@@ -237,7 +284,8 @@ def render_report(record: dict,
             f"{h:>7} {r['wall_ms']:>9.2f} {r['gossip_ms']:>10.2f} "
             f"{r['verify_ms']:>10.2f} {r['execute_ms']:>11.2f} "
             f"{r['commit_ms']:>10.2f} {r['pipeline_ms']:>8.2f} "
-            f"{r['p2p_events']:>7} {r['stalls']:>7}")
+            f"{r['p2p_events']:>7} {r['stalls']:>7}  "
+            f"{_runtime_cell(r)}")
     for h, r in rows.items():
         if r["markers"]:
             mk = " ".join(f"{k}={v}" for k, v in
