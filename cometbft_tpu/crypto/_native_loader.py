@@ -52,7 +52,8 @@ def _sources() -> list[str]:
             os.path.join(d, "bls12381.hpp"),
             os.path.join(d, "ed25519_msm.hpp"),
             os.path.join(d, "chacha20poly1305.hpp"),
-            os.path.join(d, "wire_codec.hpp")]
+            os.path.join(d, "wire_codec.hpp"),
+            os.path.join(d, "commit_walk.hpp")]
 
 
 def _host_tag() -> str:

@@ -274,7 +274,7 @@ class GuardedTpuBatchVerifier(BatchVerifier):
     the breaker (latched open for non-transient faults, so the failing
     kernel is attempted at most once per process), is logged with its
     type and message, and the SAME batch, whole, falls back to the CPU
-    verifier, which rebuilds the keys from their bytes — callers
+    verifier, which takes the same raw triples — callers
     always get a verdict.
 
     The batch_verify span opens when the verifier first does work on
@@ -304,6 +304,17 @@ class GuardedTpuBatchVerifier(BatchVerifier):
         items = self._items
         items.append((pub_key.bytes(), bytes(msg), bytes(sig)))
         if len(items) >= self._feed_at:
+            self._feed_eagerly()
+
+    def extend_raw(self, triples) -> None:
+        """add() for raw (pub, msg, sig) byte triples that already hold
+        what add() checks: 32-byte ed25519 keys, 64-byte signatures.
+        The native commit walk hands its chunks here
+        (types/validation._walk_native), a tile at a time; a tile is
+        fed at the same item counts as from add()."""
+        items = self._items
+        items.extend(triples)
+        while len(items) >= self._feed_at:
             self._feed_eagerly()
 
     def __len__(self) -> int:
@@ -400,8 +411,7 @@ class GuardedTpuBatchVerifier(BatchVerifier):
                           batch=len(self._items), backend="cpu",
                           fallback=attempted_tpu):
             cpu = ed25519.CpuBatchVerifier()
-            for pub, m, s in self._items:
-                cpu.add(ed25519.Ed25519PubKey(pub), m, s)
+            cpu.extend_raw(self._items)
             out = cpu.verify()
         _observe_verify("cpu", len(self._items),
                         time.perf_counter() - t0)
@@ -418,6 +428,9 @@ class TracedBatchVerifier(BatchVerifier):
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
         self._inner.add(pub_key, msg, sig)
+
+    def extend_raw(self, triples) -> None:
+        self._inner.extend_raw(triples)
 
     def __len__(self) -> int:
         try:

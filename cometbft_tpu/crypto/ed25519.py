@@ -201,7 +201,8 @@ class CpuBatchVerifier(BatchVerifier):
     """
 
     def __init__(self, monolithic: bool = False):
-        self._items: list[tuple[Ed25519PubKey, bytes, bytes]] = []
+        # (pub, msg, sig) as bytes: what the native kernels read
+        self._items: list[tuple[bytes, bytes, bytes]] = []
         self._monolithic = monolithic
 
     def add(self, pub_key: PubKey, msg: bytes, sig: bytes) -> None:
@@ -209,21 +210,27 @@ class CpuBatchVerifier(BatchVerifier):
             raise TypeError("CpuBatchVerifier requires ed25519 keys")
         if len(sig) != SIGNATURE_SIZE:
             raise ValueError("malformed signature")
-        self._items.append((pub_key, bytes(msg), bytes(sig)))
+        self._items.append((pub_key.bytes(), bytes(msg), bytes(sig)))
+
+    def extend_raw(self, triples) -> None:
+        """add() for raw (pub, msg, sig) byte triples that already hold
+        what add() checks: 32-byte ed25519 keys, 64-byte signatures
+        (the native commit walk's chunks)."""
+        self._items.extend(triples)
 
     def __len__(self) -> int:
         return len(self._items)
 
     def _verify_one(self, i: int) -> bool:
         pk, m, s = self._items[i]
-        return pk.verify_signature(m, s)
+        return Ed25519PubKey(pk).verify_signature(m, s)
 
     def verify(self) -> tuple[bool, Sequence[bool]]:
         n = len(self._items)
         if n >= 2:
             native = _native_msm()
             if native is not None:
-                raw = [(pk.bytes(), m, s) for pk, m, s in self._items]
+                raw = self._items
                 try:
                     from . import pipeline
                     if not self._monolithic and \
@@ -245,7 +252,8 @@ class CpuBatchVerifier(BatchVerifier):
                     return all(mask), mask
                 except Exception:
                     pass    # malformed shapes fall through per-sig
-        per = [pk.verify_signature(m, s) for pk, m, s in self._items]
+        per = [Ed25519PubKey(pk).verify_signature(m, s)
+               for pk, m, s in self._items]
         return all(per), per
 
     @staticmethod

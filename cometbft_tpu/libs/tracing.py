@@ -145,7 +145,11 @@ class Recorder:
         self.current_height = 0
         self._rings: dict[str, deque] = {}
         self._dump_seq = 0
-        self._lock = threading.Lock()
+        # reentrant: a garbage collection can strike while the lock is
+        # held (a ring allocated, the rings freed by clear()) and the
+        # collector's hook records a gc_pause into a ring it may have
+        # to create, on the same thread
+        self._lock = threading.RLock()
 
     # -- hot path ----------------------------------------------------
     def enabled_for(self, category: str) -> bool:

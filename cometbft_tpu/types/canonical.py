@@ -152,6 +152,10 @@ def vote_sign_bytes_template(chain_id: str, type_: int, height: int,
         field = b"".join(parts)
         return heads[len(field)] + field + suf
 
+    # what the native commit walk splices from (native/commit_walk.hpp):
+    # heads[len(field)] + field + suf, the field written as above
+    make.heads = heads
+    make.suf = suf
     return make
 
 

@@ -22,9 +22,13 @@ verdicts identical to the per-signature path.
 from __future__ import annotations
 
 import hashlib
+import threading
 from typing import Callable, NamedTuple, Optional
 
+from ..crypto import _native_loader
 from ..crypto import batch as crypto_batch
+from ..crypto import pipeline as crypto_pipeline
+from ..libs import metrics as libmetrics
 from ..libs import tracing
 from ..libs.bits import BitArray
 from .commit import AggregateCommit, Commit, CommitSig, CommitError
@@ -45,7 +49,6 @@ _COMMIT_VERIFY_HIST = None
 def commit_verify_histogram():
     global _COMMIT_VERIFY_HIST
     if _COMMIT_VERIFY_HIST is None:
-        from ..libs import metrics as libmetrics
         _COMMIT_VERIFY_HIST = libmetrics.DEFAULT.histogram(
             "consensus", "commit_verify_seconds",
             "Commit verification latency in seconds, by verification "
@@ -83,6 +86,56 @@ class _observe_kind:
         commit_verify_histogram().with_labels(kind).observe(
             self.sp.seconds)
         return False
+
+
+# Walks of the batched path by who ran them, raised or not: `native`
+# (the native module's walk, _walk_native), `python` (_walk_commit: a
+# cache, a walk by address, other rules, or no native module),
+# `declined` (the native walk handed an input it was not written for
+# back to _walk_commit, which walked it whole).  Plain integers, read
+# at scrape time; walks run on the loop and on the staging worker.
+_WALKS = {"native": 0, "python": 0, "declined": 0}
+_WALKS_LOCK = threading.Lock()
+
+
+def walk_stats() -> dict:
+    """Batched commit walks of this process by executor."""
+    with _WALKS_LOCK:
+        return dict(_WALKS)
+
+
+libmetrics.DEFAULT.counter_func(
+    "consensus", "commit_walk_total",
+    "Signature walks of batched commit verification by who ran them: "
+    "native (the native module's walk: by index, no signature cache, "
+    "an ed25519 set), python (the reference walk), declined (the "
+    "native walk handed an input back to the reference walk).",
+    "executor", walk_stats)
+
+
+# The walk's two rule pairs, by name, so the native walk can recognise
+# them: verify_commit skips absent signatures and counts those for the
+# block; the light variants take only those for the block, and count
+# every one they take.
+def _ignore_absent(c: CommitSig) -> bool:
+    return c.block_id_flag == BLOCK_ID_FLAG_ABSENT
+
+
+def _count_for_block(c: CommitSig) -> bool:
+    return c.block_id_flag == BLOCK_ID_FLAG_COMMIT
+
+
+def _ignore_not_for_block(c: CommitSig) -> bool:
+    return c.block_id_flag != BLOCK_ID_FLAG_COMMIT
+
+
+def _count_every(c: CommitSig) -> bool:
+    return True
+
+
+# rule -> its code in native/commit_walk.hpp
+_NATIVE_IGNORE = {_ignore_absent: 0, _ignore_not_for_block: 1}
+_NATIVE_COUNT = {_count_for_block: 0, _count_every: 1}
 
 
 class Fraction(NamedTuple):
@@ -197,8 +250,7 @@ def verify_commit(chain_id: str, vals: ValidatorSet, block_id: BlockID,
     _verify_per_signature(
         height, chain_id, vals, commit,
         vals.total_voting_power() * 2 // 3,
-        lambda c: c.block_id_flag == BLOCK_ID_FLAG_ABSENT,
-        lambda c: c.block_id_flag == BLOCK_ID_FLAG_COMMIT,
+        _ignore_absent, _count_for_block,
         count_all_signatures=True, look_up_by_index=True, cache=cache)
 
 
@@ -218,8 +270,7 @@ def verify_commit_light(chain_id: str, vals: ValidatorSet,
     _verify_per_signature(
         height, chain_id, vals, commit,
         vals.total_voting_power() * 2 // 3,
-        lambda c: c.block_id_flag != BLOCK_ID_FLAG_COMMIT,
-        lambda c: True,
+        _ignore_not_for_block, _count_every,
         count_all_signatures=count_all_signatures,
         look_up_by_index=True, cache=cache)
 
@@ -271,8 +322,7 @@ def verify_commit_light_trusting(
         return
     _verify_per_signature(
         commit.height, chain_id, vals, commit, voting_power_needed,
-        lambda c: c.block_id_flag != BLOCK_ID_FLAG_COMMIT,
-        lambda c: True,
+        _ignore_not_for_block, _count_every,
         count_all_signatures=count_all_signatures,
         look_up_by_index=False, cache=cache)
 
@@ -559,6 +609,73 @@ def _walk_span(look_up_by_index: bool):
         lookup="index" if look_up_by_index else "address")
 
 
+# what a call of the native walk ended on (native/commit_walk.hpp)
+_MORE, _DONE, _NEED_TEMPLATE, _NIL_KEY, _MALFORMED = range(5)
+
+
+def _native_walker(vals: ValidatorSet, ignore_sig, count_sig,
+                   look_up_by_index: bool, cache):
+    """The native module's commit walk, when it can stand in for
+    _walk_commit with the batch path's handle: the walk is by index,
+    with no signature cache, the rules are a pair verify_commit or
+    verify_commit_light passes, every key of the set is ed25519 (so
+    the verifier is one of the seam's two ed25519 verifiers, which
+    take raw triples in bulk) and the module is loaded.  Else None."""
+    if (cache is not None or not look_up_by_index or
+            ignore_sig not in _NATIVE_IGNORE or
+            count_sig not in _NATIVE_COUNT or
+            vals.ed25519_key_table() is None):
+        return None
+    return _native_loader.load(allow_build=False)
+
+
+def _walk_native(native, chain_id: str, vals: ValidatorSet,
+                 commit: Commit, voting_power_needed: int,
+                 ignore_sig, count_sig, count_all_signatures: bool,
+                 bv, sp) -> Optional[tuple[int, memoryview]]:
+    """_walk_commit with the batch path's handle, in the native
+    module: the same signatures, sign bytes, tally, early exit and
+    errors, a tile of triples at a time, each handed to the verifier
+    in one extend_raw() (so its tiles feed at the same item counts as
+    from add()).  Returns (tallied, the commit index of every lane),
+    or None when the native walk declined the commit: what it handed
+    over may then not be used."""
+    keys, powers = vals.ed25519_key_table()
+    ignore, count = _NATIVE_IGNORE[ignore_sig], _NATIVE_COUNT[count_sig]
+    sigs = commit.signatures
+    tile = crypto_pipeline.tile_bucket()
+    templates: dict = {}    # block-id flag -> (heads, suf)
+    lanes = bytearray()
+    triples: list = []
+    nxt = tallied = 0
+    while True:
+        status, nxt, tallied, walked = native.commit_walk(
+            sigs, nxt, keys, powers, templates, ignore, count,
+            voting_power_needed, tallied, count_all_signatures, triples,
+            tile)
+        lanes += walked
+        if status in (_MORE, _DONE):
+            if triples:
+                bv.extend_raw(triples)
+                triples = []
+            if status == _DONE:
+                break
+        elif status == _NEED_TEMPLATE:
+            cs = sigs[nxt]
+            make = commit.vote_sign_bytes_maker(chain_id, cs)
+            templates[cs.block_id_flag] = (make.heads, make.suf)
+        elif status == _NIL_KEY:
+            raise VerificationError(
+                f"validator {vals.validators[nxt]} has a nil PubKey at "
+                f"index {nxt}")
+        elif status == _MALFORMED:
+            raise _wrong_signature(commit, nxt)
+        else:
+            return None
+    sp.note(walked=nxt, cache_hits=0)
+    return tallied, memoryview(lanes).cast("I")
+
+
 def _verify_commit_batch(
         chain_id: str, vals: ValidatorSet, commit: Commit,
         voting_power_needed: int,
@@ -573,9 +690,18 @@ def _verify_commit_batch(
     attacker-controlled, and caching it would let one validator's
     signature poison the cache under another validator's address
     (canonical vote sign bytes exclude address/index, so a later
-    by-index lookup in the other validator's slot would hit)."""
-    bv = crypto_batch.create_batch_verifier(vals.get_proposer().pub_key)
+    by-index lookup in the other validator's slot would hit).
+
+    The walk runs in the native module where _native_walker allows
+    (_walk_native: no entries, only each lane's commit index, which is
+    all a refused batch needs with no cache), else _walk_commit."""
+    def new_verifier():
+        return crypto_batch.create_batch_verifier(
+            vals.get_proposer().pub_key)
+
+    bv = new_verifier()
     entries: list[tuple[int, bytes, bytes]] = []
+    lanes = None
 
     def handle(idx, val, sign_bytes, commit_sig):
         try:
@@ -584,21 +710,37 @@ def _verify_commit_batch(
             # malformed (e.g. wrong-length) signature the structural
             # checks let through — the reference returns Add's error
             # here; surface it as the usual wrong-signature verdict
-            raise VerificationError(
-                f"wrong signature (#{idx}): "
-                f"{commit_sig.signature.hex().upper()}") from e
+            raise _wrong_signature(commit, idx) from e
         entries.append((idx, val.pub_key.address(), sign_bytes))
 
+    native = _native_walker(vals, ignore_sig, count_sig,
+                            look_up_by_index, cache)
+    executor = "python" if native is None else "native"
     with _walk_span(look_up_by_index) as sp:
-        tallied = _walk_commit(
-            chain_id, vals, commit, voting_power_needed, ignore_sig,
-            count_sig, count_all_signatures, look_up_by_index, cache,
-            strict=False, handle=handle, sp=sp)
+        try:
+            if native is not None:
+                walked = _walk_native(
+                    native, chain_id, vals, commit, voting_power_needed,
+                    ignore_sig, count_sig, count_all_signatures, bv, sp)
+                if walked is None:
+                    executor = "declined"
+                    bv = new_verifier()
+                else:
+                    tallied, lanes = walked
+            if lanes is None:
+                tallied = _walk_commit(
+                    chain_id, vals, commit, voting_power_needed,
+                    ignore_sig, count_sig, count_all_signatures,
+                    look_up_by_index, cache, strict=False,
+                    handle=handle, sp=sp)
+        finally:
+            with _WALKS_LOCK:
+                _WALKS[executor] += 1
 
     if tallied <= voting_power_needed:
         raise NotEnoughVotingPowerError(tallied, voting_power_needed)
 
-    if not entries:
+    if not (entries or lanes):
         return  # everything was cached
 
     ok, valid_sigs = bv.verify()
@@ -608,18 +750,27 @@ def _verify_commit_batch(
             for idx, addr, sign_bytes in entries:
                 cache.add(commit.signatures[idx].signature,
                           SignatureCacheValue(addr, sign_bytes))
+    elif lanes is not None:
+        err = _first_invalid_lane(commit, lanes, valid_sigs)
     else:
         err = _first_invalid(commit, entries, valid_sigs, cache)
 
-    # what the walk gathered (the verifier's triples, the entries, the
-    # sign bytes both hold) is freed here, under a span of its own,
-    # and not as this frame unwinds under commit_verify's end: at
-    # 10,000 validators that is milliseconds.  Rebinding the names the
-    # closure shares drops the last references; nothing here can raise
+    # what the walk gathered (the verifier's triples, the entries or
+    # lanes, the sign bytes the triples hold) is freed here, under a
+    # span of its own, and not as this frame unwinds under
+    # commit_verify's end: at 10,000 validators that is milliseconds.
+    # Rebinding the names the closure shares drops the last
+    # references; nothing here can raise
     with tracing.span(tracing.CONSENSUS, "commit_release"):
-        bv = entries = valid_sigs = None
+        bv = entries = lanes = valid_sigs = None
     if err is not None:
         raise err
+
+
+def _wrong_signature(commit: Commit, idx: int) -> VerificationError:
+    return VerificationError(
+        f"wrong signature (#{idx}): "
+        f"{commit.signatures[idx].signature.hex().upper()}")
 
 
 def _first_invalid(commit: Commit, entries: list, valid_sigs,
@@ -627,15 +778,25 @@ def _first_invalid(commit: Commit, entries: list, valid_sigs,
     """The verdict of a refused batch: the first invalid signature by
     commit index; the valid ones before it enter the cache."""
     for sig_ok, (idx, addr, sign_bytes) in zip(valid_sigs, entries):
-        sig = commit.signatures[idx]
         if not sig_ok:
-            return VerificationError(
-                f"wrong signature (#{idx}): {sig.signature.hex().upper()}")
+            return _wrong_signature(commit, idx)
         if cache is not None:
-            cache.add(sig.signature,
+            cache.add(commit.signatures[idx].signature,
                       SignatureCacheValue(addr, sign_bytes))
-    return VerificationError(
-        "BUG: batch verification failed with no invalid signatures")
+    return VerificationError(_NO_INVALID)
+
+
+def _first_invalid_lane(commit: Commit, lanes, valid_sigs
+                        ) -> VerificationError:
+    """_first_invalid of a native walk's batch, which has no cache:
+    ``lanes`` holds each lane's commit index."""
+    for sig_ok, idx in zip(valid_sigs, lanes):
+        if not sig_ok:
+            return _wrong_signature(commit, idx)
+    return VerificationError(_NO_INVALID)
+
+
+_NO_INVALID = "BUG: batch verification failed with no invalid signatures"
 
 
 def _verify_commit_grouped(
@@ -709,9 +870,7 @@ def _verify_commit_grouped(
         group_bad = [entries[i][0] for i, sig_ok in enumerate(valid_sigs)
                      if not sig_ok]
         if not group_bad:
-            raise VerificationError(
-                "BUG: batch verification failed with no invalid "
-                "signatures")
+            raise VerificationError(_NO_INVALID)
         if cache is not None:
             bad_set = set(group_bad)
             for idx, addr, sign_bytes in entries:

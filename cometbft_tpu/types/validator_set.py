@@ -7,9 +7,11 @@ arithmetic (int64 clipping, floor-average centering) is consensus-critical.
 """
 from __future__ import annotations
 
+from array import array
 from typing import Iterable, Optional, Sequence
 
 from ..crypto import merkle
+from ..crypto.ed25519 import Ed25519PubKey
 from .validator import (
     INT64_MAX, INT64_MIN, MAX_TOTAL_VOTING_POWER,
     PRIORITY_WINDOW_SIZE_FACTOR, Validator, ValidatorError,
@@ -40,6 +42,7 @@ class ValidatorSet:
         self._all_keys_same_type = True
         self._hash_memo: Optional[bytes] = None
         self._addr_index_memo: Optional[dict] = None
+        self._key_table_memo = None     # see ed25519_key_table
         vals = [v.copy() for v in (validators or [])]
         if vals:
             self._update_with_change_set(vals, allow_deletes=False)
@@ -62,7 +65,8 @@ class ValidatorSet:
         cp._total_voting_power = self._total_voting_power
         cp._all_keys_same_type = self._all_keys_same_type
         cp._hash_memo = self._hash_memo
-        # _addr_index_memo stays None: rebuilt lazily on first use
+        # _addr_index_memo and _key_table_memo stay None: rebuilt
+        # lazily on first use
         return cp
 
     def has_address(self, address: bytes) -> bool:
@@ -86,6 +90,35 @@ class ValidatorSet:
             memo = {v.address: i for i, v in enumerate(self.validators)}
             self._addr_index_memo = memo
         return memo.get(address, -1)
+
+    def ed25519_key_table(self) -> Optional[tuple[tuple, bytes]]:
+        """(keys, powers) for the native commit walk
+        (types/validation._walk_native): every validator's raw 32-byte
+        ed25519 key in set order (None for a nil key) and the voting
+        powers as native int64s; None when a key is of another type.
+        Built once per mutation generation: keys and powers change
+        only through _update_with_change_set, which drops it with the
+        hash memo, and a copy builds its own."""
+        memo = self._key_table_memo
+        if memo is None:
+            memo = self._key_table_memo = self._build_key_table()
+        return memo or None
+
+    def _build_key_table(self):
+        keys = []
+        for v in self.validators:
+            pk = v.pub_key
+            if pk is None:
+                keys.append(None)
+            elif isinstance(pk, Ed25519PubKey):
+                keys.append(pk.bytes())
+            else:
+                return False    # memoised: not a table to build
+        try:
+            powers = array("q", [v.voting_power for v in self.validators])
+        except OverflowError:
+            return False
+        return tuple(keys), powers.tobytes()
 
     def get_by_index(self, index: int) -> tuple[bytes, Optional[Validator]]:
         if index < 0 or index >= len(self.validators):
@@ -215,6 +248,7 @@ class ValidatorSet:
             return
         self._hash_memo = None
         self._addr_index_memo = None
+        self._key_table_memo = None
         updates, deletes = self._process_changes(changes)
         if not allow_deletes and deletes:
             raise ValidatorSetError(

@@ -33,6 +33,7 @@
 #include "ed25519_msm.hpp"
 #include "bls12381.hpp"
 #include "chacha20poly1305.hpp"
+#include "commit_walk.hpp"
 #include "wire_codec.hpp"
 
 namespace {
@@ -1279,6 +1280,11 @@ PyMethodDef kMethods[] = {
      "wire/proto.py decode(desc, bytes) -> dict | None (declined)"},
     {"wire_stats", wire::wire_stats, METH_NOARGS,
      "(calls the wire executor answered, calls it declined)"},
+    {"commit_walk", commit_walk::walk, METH_VARARGS,
+     "types/validation's batched by-index walk, a tile at a time: "
+     "(sigs, start, keys, powers, templates, ignore, count, needed, "
+     "tallied, count_all, triples, tile) -> (status, next, tallied, "
+     "lanes)"},
     {nullptr, nullptr, 0, nullptr},
 };
 

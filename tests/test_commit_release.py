@@ -186,6 +186,9 @@ class TestRelease:
             def add(self, *a):
                 return self._inner.add(*a)
 
+            def extend_raw(self, triples):
+                return self._inner.extend_raw(triples)
+
             def verify(self):
                 return self._inner.verify()
 

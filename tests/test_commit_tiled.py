@@ -1,7 +1,9 @@
 """Commit verification through the TILED device path, verdict by
 verdict, with a golden kernel at the wire (tests/wire_golden.py) in
-place of the compiled ones: walk -> BatchVerifier seam, whose add()
-hands a full tile to ops TilePipeline the moment it has one (the
+place of the compiled ones: walk (the native walk since PR 38, a
+tile at a time into extend_raw()) -> BatchVerifier seam, whose add()
+and extend_raw() hand a full tile to ops TilePipeline the moment it
+has one (the
 tile's host prep begins on the native prep thread; the tile handed
 over before it is launched) -> tiles, pre_bad, mask assembly -> the
 index a refusal names.
@@ -244,16 +246,23 @@ def recorder(tmp_path):
 
 @pytest.fixture
 def adds(monkeypatch):
-    """Counts BatchVerifier.add calls: [n]."""
+    """Counts the items handed to the seam's verifier, by add() or by
+    the native walk's extend_raw(), each counted before it is taken:
+    [n]."""
     count = [0]
-    add = crypto_batch.GuardedTpuBatchVerifier.add
+    verifier = crypto_batch.GuardedTpuBatchVerifier
+    add, extend_raw = verifier.add, verifier.extend_raw
 
     def counted(self, *item):
         count[0] += 1
         return add(self, *item)
 
-    monkeypatch.setattr(crypto_batch.GuardedTpuBatchVerifier, "add",
-                        counted)
+    def counted_raw(self, triples):
+        count[0] += len(triples)
+        return extend_raw(self, triples)
+
+    monkeypatch.setattr(verifier, "add", counted)
+    monkeypatch.setattr(verifier, "extend_raw", counted_raw)
     return count
 
 
@@ -319,10 +328,10 @@ def test_masks_come_back_in_feed_order(device_path):
 
 def test_tiles_are_on_their_way_before_the_walk_ends(
         device_path, adds, monkeypatch):
-    """200 validators, strict: the 64th add() begins tile 0's prep,
-    the 128th launches tile 0, the 192nd tile 1; verify() hands over
-    the 8 left, which launches tile 2, and launches them.  Never
-    another shape."""
+    """200 validators, strict: the 64th signature handed over begins
+    tile 0's prep, the 128th launches tile 0, the 192nd tile 1;
+    verify() hands over the 8 left, which launches tile 2, and
+    launches them.  Never another shape."""
     seen = []
     golden = ej._jit_verify_packed
     for name in KERNELS:
@@ -386,8 +395,8 @@ def test_a_short_tally_drops_what_is_in_flight(device_path, recorder,
 @pytest.mark.parametrize("in_flight", IN_FLIGHT)
 def test_a_walk_that_raises_drops_what_is_in_flight(
         device_path, recorder, in_flight):
-    """A signature of 63 bytes: add() refuses it, the walk raises as
-    it always did, what was handed over by then is dropped."""
+    """A signature of 63 bytes: the walk refuses it and raises as it
+    always did, what was handed over by then is dropped."""
     n, at, launched = IN_FLIGHT[in_flight]
     vset, bid, height, commit = variant(
         n, {at: resigned(lambda sig: sig[:63])})

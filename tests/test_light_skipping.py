@@ -130,6 +130,10 @@ class _CountingVerifier:
         self._counts["added"] += 1
         self._inner.add(pub_key, msg, sig)
 
+    def extend_raw(self, triples):
+        self._counts["added"] += len(triples)
+        self._inner.extend_raw(triples)
+
     def verify(self):
         self._counts["batches"] += 1
         return self._inner.verify()

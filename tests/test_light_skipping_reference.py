@@ -73,6 +73,10 @@ class RecordingVerifier:
         self.items.append((pub_key.bytes(), msg, sig))
         self.inner.add(pub_key, msg, sig)
 
+    def extend_raw(self, triples) -> None:
+        self.items.extend(triples)
+        self.inner.extend_raw(triples)
+
     def verify(self):
         self.log.append(self.items)
         return self.inner.verify()

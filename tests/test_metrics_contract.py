@@ -297,9 +297,10 @@ _ALLOWED_LABELS = {
     "outcome",      # how a light-client hop ended: three literals
                     # (verified / cant_trust / invalid), bound once in
                     # light/client.hop_counters
-    "executor",     # who ran a wire descriptor: three literals
-                    # (native / python / declined), the keys of
-                    # wire/proto.codec_stats
+    "executor",     # who ran a wire descriptor or a commit walk:
+                    # three literals (native / python / declined), the
+                    # keys of wire/proto.codec_stats and of
+                    # types/validation.walk_stats
     "path",         # how a vote's signature was judged on the serial
                     # path: two literals (memo / serial), the keys of
                     # types/vote._VERIFY_COUNTS

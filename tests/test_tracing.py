@@ -776,6 +776,17 @@ class TestRuntime:
         # the unflagged span notes nothing
         assert "attrs" not in events["inner"]
 
+    def test_a_collection_under_the_recorders_lock_records(
+            self, recorder, quiet_gc):
+        """A collection can strike while the recorder holds its lock
+        (clear() freeing the rings, a ring being made); the hook then
+        makes the runtime ring on the same thread.  With a plain lock
+        that was a deadlock (PR 38: clear() between commits)."""
+        tracing.clear()
+        with recorder._lock:
+            gc.collect()
+        assert [e["name"] for e in tracing.snapshot()] == ["gc_pause"]
+
     @pytest.mark.parametrize("generation,min_ns,recorded", [
         (0, 10**12, False),     # short and young: counted only
         (0, 0, True),           # young but as long as the threshold

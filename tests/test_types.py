@@ -425,13 +425,18 @@ class _RecordingVerifier(crypto_batch.BatchVerifier):
         self.triples.append((pub_key.bytes(), msg, sig))
         self.inner.add(pub_key, msg, sig)
 
+    def extend_raw(self, triples):
+        self.triples.extend(triples)
+        self.inner.extend_raw(triples)
+
     def verify(self):
         return self.inner.verify()
 
 
 class TestWalkDecidesTheSame:
-    """What _walk_commit hands the batch verifier, against the plain
-    reference: Vote.sign_bytes of get_vote(idx), index by index."""
+    """What the walk (the native walk where it engages, PR 38) hands
+    the batch verifier, against the plain reference: Vote.sign_bytes
+    of get_vote(idx), index by index."""
 
     CHAIN = "walk-chain-1k"
     N = 1000
