@@ -377,6 +377,35 @@ class KVStoreApplication(abci.Application):
                     "Decreased val power for equivocation",
                     val=addr.hex(), new_power=max(new_power, 0))
 
+        tx_results = self._finalize_txs(req)
+        self._height = req.height
+        # one update per pubkey across ALL sources (punishments and
+        # validator txs may both touch the same validator in one
+        # block; duplicate entries are a consensus failure) — the
+        # LAST write wins, so an explicit val-tx overrides the
+        # evidence punishment, matching append order
+        by_key: dict[bytes, abci.ValidatorUpdate] = {}
+        for u in self._val_updates:
+            by_key[u.pub_key_bytes] = u
+        for u in by_key.values():
+            self._stage_validator(u)
+        # the app hash IS this height's tree root; Commit persists
+        # the same staged view (the tree caches the computation)
+        resp = abci.FinalizeBlockResponse(
+            tx_results=tx_results,
+            validator_updates=list(by_key.values()),
+            app_hash=self.tree.working_root(req.height),
+            next_block_delay_ns=self.next_block_delay_ns,
+        )
+        if self._gen_block_events:
+            resp.events = [abci.Event(type="begin_event", attributes=[
+                abci.EventAttribute("foo", "100", True),
+                abci.EventAttribute("bar", "200", True)])]
+        return resp
+
+    def _finalize_txs(self, req: abci.FinalizeBlockRequest
+                      ) -> list[abci.ExecTxResult]:
+        """Execute a block's txs into the working tree: a result each."""
         tx_results = []
         for tx in req.txs:
             if is_validator_tx(tx):
@@ -407,31 +436,7 @@ class KVStoreApplication(abci.Application):
                 ])],
             ))
             self._size += 1
-
-        self._height = req.height
-        # one update per pubkey across ALL sources (punishments and
-        # validator txs may both touch the same validator in one
-        # block; duplicate entries are a consensus failure) — the
-        # LAST write wins, so an explicit val-tx overrides the
-        # evidence punishment, matching append order
-        by_key: dict[bytes, abci.ValidatorUpdate] = {}
-        for u in self._val_updates:
-            by_key[u.pub_key_bytes] = u
-        for u in by_key.values():
-            self._stage_validator(u)
-        # the app hash IS this height's tree root; Commit persists
-        # the same staged view (the tree caches the computation)
-        resp = abci.FinalizeBlockResponse(
-            tx_results=tx_results,
-            validator_updates=list(by_key.values()),
-            app_hash=self.tree.working_root(req.height),
-            next_block_delay_ns=self.next_block_delay_ns,
-        )
-        if self._gen_block_events:
-            resp.events = [abci.Event(type="begin_event", attributes=[
-                abci.EventAttribute("foo", "100", True),
-                abci.EventAttribute("bar", "200", True)])]
-        return resp
+        return tx_results
 
     async def commit(self, req: abci.CommitRequest) -> abci.CommitResponse:
         # one atomic batch: kv writes, validator records, version

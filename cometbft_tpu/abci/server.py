@@ -149,10 +149,13 @@ def _build_app(name: str) -> abci.Application:
     if name == "kvstore":
         from .kvstore import KVStoreApplication
         return KVStoreApplication()
+    if name == "oracle":
+        from .oracle import OracleApplication
+        return OracleApplication()
     if name == "noop":
         from .types import BaseApplication
         return BaseApplication()
-    raise ABCIServerError(f"unknown app {name!r} (kvstore|noop)")
+    raise ABCIServerError(f"unknown app {name!r} (kvstore|oracle|noop)")
 
 
 def main(argv=None) -> int:

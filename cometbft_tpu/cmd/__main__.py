@@ -529,11 +529,15 @@ def cmd_replay(args) -> int:
         doc = GenesisDoc.from_file(cfg.base.path(cfg.base.genesis_file))
         app = None
         if cfg.base.abci in ("builtin", "builtin_unsync"):
-            if cfg.base.proxy_app not in ("kvstore",
-                                          "persistent_kvstore"):
+            if cfg.base.proxy_app == "oracle":
+                from ..abci.oracle import OracleApplication
+                app = OracleApplication(db=new_db("app", backend, db_dir))
+            elif cfg.base.proxy_app in ("kvstore", "persistent_kvstore"):
+                app = KVStoreApplication(db=new_db("app", backend,
+                                                   db_dir))
+            else:
                 raise SystemExit(
                     f"unknown proxy_app {cfg.base.proxy_app!r}")
-            app = KVStoreApplication(db=new_db("app", backend, db_dir))
         conns = ClientCreator(app=app, addr=cfg.base.proxy_app,
                               transport=cfg.base.abci).new_app_conns()
         await conns.start()

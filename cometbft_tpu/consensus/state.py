@@ -362,14 +362,16 @@ class ConsensusState:
         if len(votes) < 2:
             return len(votes)
         with tracing.span(tracing.CONSENSUS, "vote_preverify") as sp:
-            entries, late = self._burst_entries(votes)
+            entries, late, ext = self._burst_entries(votes)
             sp.note(entries=len(entries), late=late)
+            if ext:
+                sp.note(ext=len(ext))
             if len(entries) < 2:
                 return len(votes)
             try:
                 sp.note(fresh=await asyncio.wrap_future(
-                    vote_mod.preverify_signatures_async(entries,
-                                                        under=sp)))
+                    vote_mod.preverify_signatures_async(
+                        entries, under=sp, extension_lanes=ext)))
             except Exception:
                 # advisory: a worker failure just means the serial
                 # tally verifies each signature itself
@@ -378,10 +380,12 @@ class ConsensusState:
                     "decides)", exc_info=True)
         return len(votes)
 
-    def _burst_entries(self, votes) -> tuple[list, int]:
+    def _burst_entries(self, votes) -> tuple[list, int, set]:
         """(the signature triples of ``votes`` that name a validator
-        of their set, how many of them are late precommits')."""
+        of their set, how many of them are late precommits', the
+        indices of the extension signatures among them)."""
         entries: list = []
+        ext: set = set()
         late = 0
         rs = self.rs
         for vote in votes:
@@ -404,17 +408,18 @@ class ConsensusState:
                 continue
             n = len(entries)
             self._append_vote_entries(
-                entries, vote, val.pub_key, self.sm_state.chain_id)
+                entries, vote, val.pub_key, self.sm_state.chain_id, ext)
             if is_late:
                 late += len(entries) - n
-        return entries, late
+        return entries, late, ext
 
     def _append_vote_entries(self, entries, vote, pub_key,
-                             chain_id: str) -> None:
+                             chain_id: str, ext=None) -> None:
         """Append a vote's signature triples (main + both extension
-        signatures for non-nil precommits) for advisory batch
-        pre-verification.  Never raises: malformed fields are left for
-        the serial path's own errors."""
+        signatures for non-nil precommits, whose indices go to the set
+        ``ext`` when given) for advisory batch pre-verification.  Never
+        raises: malformed fields are left for the serial path's own
+        errors."""
         try:
             entries.append((pub_key, vote.sign_bytes(chain_id),
                             vote.signature))
@@ -422,6 +427,8 @@ class ConsensusState:
                     not vote.block_id.is_nil() and
                     vote.extension_signature and
                     vote.non_rp_extension_signature):
+                if ext is not None:
+                    ext.update((len(entries), len(entries) + 1))
                 entries.append((pub_key,
                                 vote.extension_sign_bytes(chain_id),
                                 vote.extension_signature))
@@ -675,21 +682,22 @@ class ConsensusState:
         vote (see _append_vote_entries).  Verdicts unchanged: lookup
         failures and invalid signatures fall to the serial path's own
         errors."""
-        entries = []
+        entries: list = []
+        ext: set = set()
         for v in votes:
             try:
                 _, val = vals.get_by_address(v.validator_address)
                 if val is None or val.pub_key is None:
                     continue
                 self._append_vote_entries(entries, v, val.pub_key,
-                                          chain_id)
+                                          chain_id, ext)
             except Exception:
                 self.logger.debug(
                     "vote preverify: validator lookup failed "
                     "(serial tally will report it)", exc_info=True)
                 continue
         if len(entries) >= 2:
-            vote_mod.preverify_signatures(entries)
+            vote_mod.preverify_signatures(entries, ext)
 
     def _vote_set_from_extended_commit(self, state: SMState,
                                        ec: ExtendedCommit) -> VoteSet:
@@ -1781,12 +1789,17 @@ class ConsensusState:
                     raise VoteSetError(
                         f"validator index {vote.validator_index} out of "
                         f"bounds")
-                vote.verify_extension(self.sm_state.chain_id,
-                                      val.pub_key)
-                # pipeline barrier: the app must be past the previous
-                # height's Commit before VerifyVoteExtension(H)
-                await self._sync_pipeline()
-                ok = await self.block_exec.verify_vote_extension(vote)
+                with tracing.span(tracing.CONSENSUS, "vote_extension",
+                                  bytes=len(vote.extension),
+                                  status="invalid") as sp:
+                    vote.verify_extension(self.sm_state.chain_id,
+                                          val.pub_key)
+                    # pipeline barrier: the app must be past the
+                    # previous height's Commit before
+                    # VerifyVoteExtension(H)
+                    await self._sync_pipeline()
+                    ok = await self.block_exec.verify_vote_extension(vote)
+                    sp.note(status="accepted" if ok else "rejected")
                 self.metrics.vote_extension_receive_count.with_labels(
                     "accepted" if ok else "rejected").add()
                 if not ok:

@@ -133,6 +133,14 @@ class Node:
                 app = KVStoreApplication(
                     db=new_db("app", backend, db_dir),
                     snapshot_interval=10)
+            elif config.base.proxy_app == "oracle":
+                from ..abci.oracle import OracleApplication
+                pv = self.priv_validator
+                app = OracleApplication(
+                    db=new_db("app", backend, db_dir),
+                    snapshot_interval=10,
+                    validator_address=pv.get_pub_key().address()
+                    if pv is not None else b"")
             else:
                 raise NodeError(
                     f"unknown proxy_app {config.base.proxy_app!r} "

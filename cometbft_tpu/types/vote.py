@@ -98,6 +98,10 @@ def _memo_reject(key: tuple[bytes, bytes, bytes]) -> None:
 # verified on the CPU: a vote no batch covered, or the confirmation
 # of a lane a batch refused.
 _VERIFY_COUNTS = {"memo": 0, "serial": 0}
+# the same for the two extension signatures of a non-nil precommit
+# (verify_extension), read as
+# cometbft_consensus_vote_extension_verify_total{path}
+_EXT_VERIFY_COUNTS = {"memo": 0, "serial": 0}
 _PREVERIFIED = {"valid": 0, "invalid": 0, "unjudged": 0}
 
 
@@ -106,11 +110,22 @@ def verify_counts() -> tuple[int, int]:
     return _VERIFY_COUNTS["memo"], _VERIFY_COUNTS["serial"]
 
 
+def extension_verify_counts() -> tuple[int, int]:
+    """The same for extension signatures."""
+    return _EXT_VERIFY_COUNTS["memo"], _EXT_VERIFY_COUNTS["serial"]
+
+
 _libmetrics.DEFAULT.counter_func(
     "consensus", "vote_verify_total",
     "Vote signatures judged on the serial path, by how: answered by "
     "the verified/rejected memo a batch filled, or verified one by "
     "one on the CPU.", "path", lambda: _VERIFY_COUNTS)
+_libmetrics.DEFAULT.counter_func(
+    "consensus", "vote_extension_verify_total",
+    "Vote extension signatures (both of a non-nil precommit) judged "
+    "on the serial path, by how: answered by the memo a batch filled, "
+    "or verified one by one on the CPU.", "path",
+    lambda: _EXT_VERIFY_COUNTS)
 _libmetrics.DEFAULT.counter_func(
     "consensus", "vote_preverified_total",
     "Vote signatures a burst pre-verification put through a batch "
@@ -119,9 +134,10 @@ _libmetrics.DEFAULT.counter_func(
 
 
 def _serial_verify(pub_key: PubKey, msg: bytes, sig: bytes,
-                   key: tuple[bytes, bytes, bytes]) -> bool:
+                   key: tuple[bytes, bytes, bytes],
+                   counts: dict = _VERIFY_COUNTS) -> bool:
     """One signature on the CPU; its verdict enters the memos."""
-    _VERIFY_COUNTS["serial"] += 1
+    counts["serial"] += 1
     ok = pub_key.verify_signature(msg, sig)
     if ok:
         _memo_add(key)
@@ -130,21 +146,24 @@ def _serial_verify(pub_key: PubKey, msg: bytes, sig: bytes,
     return ok
 
 
-def checked_verify(pub_key: PubKey, msg: bytes, sig: bytes) -> bool:
-    """pub_key.verify_signature with the verified/rejected memos."""
+def checked_verify(pub_key: PubKey, msg: bytes, sig: bytes,
+                   counts: dict = _VERIFY_COUNTS) -> bool:
+    """pub_key.verify_signature with the verified/rejected memos;
+    ``counts`` says how (_VERIFY_COUNTS, or _EXT_VERIFY_COUNTS for an
+    extension signature)."""
     key = _memo_key(pub_key, msg, sig)
     if key in _VERIFIED:
         _VERIFIED.move_to_end(key)
-        _VERIFY_COUNTS["memo"] += 1
+        counts["memo"] += 1
         return True
     if key in _REJECTED:
         _REJECTED.move_to_end(key)
-        _VERIFY_COUNTS["memo"] += 1
+        counts["memo"] += 1
         return False
-    return _serial_verify(pub_key, msg, sig, key)
+    return _serial_verify(pub_key, msg, sig, key, counts)
 
 
-def preverify_signatures(entries) -> int:
+def preverify_signatures(entries, extension_lanes=frozenset()) -> int:
     """Batch-verify (pub_key, msg, sig) triples and memoize both
     verdicts; returns how many of them were fresh (in neither memo)
     and so went to a batch.  Never raises and proves nothing on its
@@ -161,34 +180,40 @@ def preverify_signatures(entries) -> int:
     invalid-forever and this node would reject votes its peers accept
     (consensus divergence).  The confirmation costs what the caller's
     serial path would have paid anyway; re-sent storms then hit the
-    memo."""
+    memo.  The confirmation of an entry whose index is in
+    ``extension_lanes`` counts as an extension signature's."""
     from ..crypto import batch as crypto_batch
 
     fresh = []
     keys = []
-    for pub_key, msg, sig in entries:
+    counts = []
+    for i, (pub_key, msg, sig) in enumerate(entries):
         key = _memo_key(pub_key, msg, sig)
         if key in _VERIFIED or key in _REJECTED:
             continue
         fresh.append((pub_key, msg, sig))
         keys.append(key)
+        counts.append(_EXT_VERIFY_COUNTS if i in extension_lanes
+                      else _VERIFY_COUNTS)
     if len(fresh) < 2:
         return 0
     mask = crypto_batch.batch_verify_by_type(fresh)
-    for (pub_key, msg, sig), key, good in zip(fresh, keys, mask):
+    for (pub_key, msg, sig), key, how, good in zip(fresh, keys, counts,
+                                                   mask):
         if good:
             _memo_add(key)
             _PREVERIFIED["valid"] += 1
         elif good is None:
             _PREVERIFIED["unjudged"] += 1
-        elif _serial_verify(pub_key, msg, sig, key):
+        elif _serial_verify(pub_key, msg, sig, key, how):
             _PREVERIFIED["valid"] += 1   # batch false-negative fixed
         else:
             _PREVERIFIED["invalid"] += 1
     return len(fresh)
 
 
-def preverify_signatures_async(entries, under=None):
+def preverify_signatures_async(entries, under=None,
+                               extension_lanes=frozenset()):
     """``preverify_signatures`` on the verification staging worker:
     returns a concurrent Future that resolves (to the number of fresh
     entries) once the burst's verdicts are memoized — the consensus
@@ -204,7 +229,7 @@ def preverify_signatures_async(entries, under=None):
 
     def run() -> int:
         with _tracing.under(under):
-            return preverify_signatures(entries)
+            return preverify_signatures(entries, extension_lanes)
     return pipeline.submit(run)
 
 
@@ -293,11 +318,13 @@ class Vote:
             raise InvalidSignatureError("vote extension signature missing")
         if not checked_verify(pub_key,
                               self.extension_sign_bytes(chain_id),
-                              self.extension_signature):
+                              self.extension_signature,
+                              _EXT_VERIFY_COUNTS):
             raise InvalidSignatureError("invalid vote extension signature")
         if not checked_verify(pub_key,
                               self.non_rp_extension_sign_bytes(),
-                              self.non_rp_extension_signature):
+                              self.non_rp_extension_signature,
+                              _EXT_VERIFY_COUNTS):
             raise InvalidSignatureError(
                 "invalid non-RP vote extension signature")
 

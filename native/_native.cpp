@@ -294,8 +294,11 @@ void lanes(const ItemRef* refs, Py_ssize_t lo, Py_ssize_t hi,
            uint8_t* wire, uint8_t* bad_p) {
 #if COMETBFT_SHA512MB_X86
     const bool use_mb = sha512mb::available();
-    // groups keyed by block count (messages in one batch are nearly
-    // always uniform-length vote sign-bytes, so this stays tiny)
+    // groups keyed by block count: a few at most, since a batch holds
+    // few lengths — vote sign bytes (~110 bytes, 2 blocks) alone, or,
+    // with vote extensions on, beside the canonical extension sign
+    // bytes (~1.9 kB, 15-16 blocks) and the empty non-replay-protected
+    // extension (1 block), in any order
     std::vector<KGroup> groups;
     std::vector<uint8_t> scratch;
 #endif

@@ -78,6 +78,9 @@ CONSENSUS_SPAN_BUCKETS = {
     "wal_replay": None,
     "replay_height": None,
     "wal_read": None,
+    # a precommit's extension block inside the tally: its
+    # VerifyVoteExtension call is in the execute column
+    "vote_extension": None,
 }
 
 # state span -> bucket (pinned the same way): the executor and the
@@ -218,7 +221,8 @@ def analyze(record: dict,
                         "ms": dur / _MS})
             elif cat == "abci" and "/" in name:
                 # the calls, "<conn>/<method>"; state_root nests
-                # inside consensus/finalize_block
+                # inside consensus/finalize_block, oracle_validate
+                # inside consensus/process_proposal
                 row["execute_ms"] += dur / _MS
             elif cat == "state":
                 bucket = STATE_SPAN_BUCKETS.get(name)
