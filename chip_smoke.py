@@ -58,8 +58,9 @@ REHEARSAL_BANNER = "REHEARSAL — not a chip result"
 REHEARSAL_EXIT = 4
 
 # every jitted function that IS the verification kernel carries this
-# in its name: _pallas_verify_packed, _verify_packed, and the
-# shard_map'ed sharded_<kernel>_verify (parallel/mesh.py)
+# in its name: _pallas_verify_packed, _pallas_batch_verify_packed,
+# _verify_packed, and the shard_map'ed sharded_<kernel>_verify
+# (parallel/mesh.py)
 KERNEL_MARK = "verify"
 
 

@@ -91,6 +91,19 @@ def tile_bucket() -> int:
     return bucket(TILE)
 
 
+def batch_equation(m: int) -> bool:
+    """Whether a device dispatch of m padded lanes is settled by ONE
+    batch equation (ops/ed25519_pallas._batch_kernel) before any lane
+    is judged alone: at the tile's shape, and below it not.  The
+    equation pays a fixed cost a dispatch (Horner's 252 doublings and
+    the lanes' fold, on one block of lanes) for half the field
+    multiplications a lane, so it wins only with enough blocks: ~1.9x
+    at 1,024 lanes, ~1.6x at 512 and ~1.2x at 256 by the count, a loss
+    at 128; and every shape it engages traces and lowers one more
+    kernel in set-up (PERF.md §7)."""
+    return m == tile_bucket()
+
+
 def tile_plan(n: int, tile: int) -> list:
     """[(start, end), ...] covering n lanes in BALANCED slices of at
     most ``tile`` lanes: 10k at tile 4096 plans three ~3334-lane
@@ -349,6 +362,7 @@ def verify_items_pipelined(
 
 
 __all__ = ["BASE_BUCKETS", "BUCKETS", "TILE", "MSM_TILE", "bucket",
-           "tile_bucket", "tile_plan", "verify_items_pipelined", "submit",
+           "tile_bucket", "batch_equation", "tile_plan",
+           "verify_items_pipelined", "submit",
            "run_off_loop", "dispatch_histogram", "overlap_histogram",
            "reset_workers"]

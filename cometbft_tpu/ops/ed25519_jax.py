@@ -12,8 +12,11 @@ types/vote_set.go AddVote).  The design is TPU-first, not a port:
   * verification is *cofactored* ([8](s·B - R - k·A) == 0) exactly like the
     reference's ZIP-215 semantics, so single and batch verdicts agree;
   * shapes are bucketed (powers of two) so each bucket compiles once;
-  * the per-signature validity mask comes straight out of the kernel — no
-    batch-equation fallback pass is needed to attribute failures.
+  * the per-signature validity mask comes straight out of the kernel,
+    except at the tile's shape (crypto/pipeline.batch_equation), where
+    the Pallas kernel first checks ONE random-linear-combination batch
+    equation for the whole tile, and runs the per-lane ladder on the
+    same wire only when it fails.
 
 Host-side work is limited to SHA-512 reductions mod L (cheap, OpenSSL via
 hashlib) and nibble-window decomposition of the scalars.
@@ -34,7 +37,8 @@ from jax import lax
 from ..crypto import _ed25519_ref as ref
 from ..crypto.pipeline import (
     BASE_BUCKETS as _BASE_BUCKETS, BUCKETS as _BUCKETS, bucket as _bucket,
-    dispatch_histogram, overlap_histogram, tile_bucket, tile_plan,
+    batch_equation, dispatch_histogram, overlap_histogram, tile_bucket,
+    tile_plan,
 )
 from ..libs import tracing
 from . import device, field
@@ -321,6 +325,13 @@ def _byte_cols(b8):
     return jnp.transpose(b8).astype(jnp.int32)
 
 
+def _signed_cols(d8):
+    """Device-side: [m, k] uint8 two's-complement digits -> [k, m]
+    int32 in [-8, 7]."""
+    return jnp.transpose(
+        lax.bitcast_convert_type(d8, jnp.int8)).astype(jnp.int32)
+
+
 # The packed wire: everything one dispatch sends to the device is ONE
 # [m, 192] uint8 buffer, a lane a row — A | R | S windows | k windows.
 # The host fills it in place (native ed25519_prep, or prep_arrays'
@@ -335,6 +346,30 @@ def wire_views(wire):
     (parallel/mesh, tests), on the device inside the jitted kernels."""
     return (wire[:, 0:32], wire[:, 32:64], wire[:, 64:128],
             wire[:, 128:192])
+
+
+# A tile settled by the batch equation (crypto/pipeline.batch_equation)
+# carries more columns after those 192, each a signed 4-bit digit in
+# two's complement: c = z·k mod L (64 digits), the lane's 128-bit
+# randomizer z (33), and the tile's S = Σ z·s mod L (64), digit t at
+# byte t of the S columns read row-major: ONE column when m >= 64.
+# The native prep draws z (getrandom, the OS's generator) and computes
+# c and S in the same pass; a refused or padding lane has zero digits.
+_C_DIGITS, _Z_DIGITS, _S_DIGITS = 64, 33, 64
+_S_COL = WIRE_LANE_BYTES + _C_DIGITS + _Z_DIGITS     # 289
+
+
+def batch_lane_bytes(m: int) -> int:
+    """The lane width of a batch-equation wire of m lanes."""
+    return _S_COL + -(-_S_DIGITS // m)
+
+
+def batch_views(wire):
+    """(c_d8 [m,64], z_d8 [m,33], s_d8 [m, cols]) of a batch-equation
+    wire buffer: the columns after wire_views'."""
+    return (wire[:, WIRE_LANE_BYTES:WIRE_LANE_BYTES + _C_DIGITS],
+            wire[:, WIRE_LANE_BYTES + _C_DIGITS:_S_COL],
+            wire[:, _S_COL:])
 
 
 def _verify_packed(wire):
@@ -355,6 +390,28 @@ def _pallas_verify_packed(wire, interpret=False, block=0):
     return ep.verify_cols(_byte_cols(a8), _byte_cols(r8),
                           _win_cols(s8), _win_cols(k8),
                           interpret=interpret, block=block or ep.BLOCK)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block"))
+def _pallas_batch_verify_packed(wire, interpret=False, block=0):
+    """A tile's verdicts from ONE batch equation: the lanes' decode
+    flags when it holds, else today's per-lane kernel on the same wire
+    (``lax.cond``: one executable, one launch, one read-back).  Returns
+    [m + 1] bool: the mask, then whether the equation held."""
+    from . import ed25519_pallas as ep
+    block = block or ep.BLOCK
+    a8, r8, s8, k8 = wire_views(wire)
+    c8, z8, sd8 = batch_views(wire)
+    a, r = _byte_cols(a8), _byte_cols(r8)
+    digits = jnp.concatenate([_signed_cols(c8), _signed_cols(z8)])
+    s_digits = _signed_cols(sd8.reshape(1, -1)[:, :_S_DIGITS])
+    held, decoded = ep.batch_cols(a, r, digits, s_digits,
+                                  interpret=interpret, block=block)
+    mask = lax.cond(
+        held, lambda: decoded,
+        lambda: ep.verify_cols(a, r, _win_cols(s8), _win_cols(k8),
+                               interpret=interpret, block=block))
+    return jnp.concatenate([mask, held[None]])
 
 
 def verify_batch(
@@ -407,6 +464,40 @@ def streamed_tiles_counter():
     return _STREAMED_TILES
 
 
+_BATCH_EQUATION = None
+
+
+def batch_equation_counter():
+    """Pallas dispatches by what settled them: ``held`` (the tile's
+    batch equation held, so every lane took its decode flag),
+    ``failed`` (it did not, and the per-lane kernel decided every
+    lane), ``bypassed`` (a shape below the tile's, which the per-lane
+    kernel alone decides).  Counted where a dispatch is settled."""
+    global _BATCH_EQUATION
+    if _BATCH_EQUATION is None:
+        from ..libs import metrics as libmetrics
+        _BATCH_EQUATION = libmetrics.DEFAULT.counter(
+            "crypto", "batch_equation_total",
+            "Single-device Pallas dispatches by what settled them: "
+            "the tile's batch equation held, failed (the per-lane "
+            "kernel then decided every lane), or was bypassed (a "
+            "shape below the tile's).", labels=("outcome",))
+    return _BATCH_EQUATION
+
+
+def _settled(out: np.ndarray, m: int, choice: str,
+             single: bool) -> np.ndarray:
+    """A settled dispatch's [m] mask, its batch equation counted: a
+    batch-equation entry hands back [m + 1], the held bit last."""
+    if out.shape[0] > m:
+        batch_equation_counter().with_labels(
+            "held" if out[m] else "failed").add()
+        return out[:m]
+    if choice == "pallas" and single:
+        batch_equation_counter().with_labels("bypassed").add()
+    return out
+
+
 # a tile the pipeline has launched and not settled
 _Launched = collections.namedtuple("_Launched",
                                    "n warm pre_bad dev span")
@@ -456,6 +547,8 @@ class TilePipeline:
     def __init__(self, tile: int):
         self._choice = _kernel_choice()
         self._m = _padded(tile, self._choice)
+        self._part = _partitioner(self._m, self._choice)
+        self._batch = _batched(self._m, self._choice)
         self._hist = dispatch_histogram()
         self._masks: list[np.ndarray] = []
         self._prepping = None       # (handle, n, eager): begun
@@ -470,7 +563,7 @@ class TilePipeline:
         span's attribute, which
         benchmark/layers/eager_tiles_per_commit counts)."""
         prev, self._prepping = self._prepping, (
-            _prep_begin(chunk, self._m), len(chunk), eager)
+            _prep_begin(chunk, self._m, self._batch), len(chunk), eager)
         if prev is not None:
             self._launch_tile(*prev)
         while self._launched and self._launched[0].dev.is_ready():
@@ -500,8 +593,7 @@ class TilePipeline:
                            prep_wait_us=waited // 1000,
                            **attrs).begin()
         with tracing.under(sp):
-            dev = _launch(wire, choice=choice,
-                          part=_partitioner(m, choice))
+            dev = _launch(wire, choice=choice, part=self._part)
         dev.copy_to_host_async()
         _SEEN_SHAPES.add((choice, m))
         self._tiles += 1
@@ -521,6 +613,7 @@ class TilePipeline:
         self._hist.with_labels("kernel_execute", choice, pad_bucket,
                                "1" if warm else "0").observe(sp.seconds)
         self._phase_s += sp.seconds
+        ok = _settled(ok, self._m, choice, self._part is None)
         ok = ok[:n].copy()
         ok[pre_bad[:n]] = False
         self._masks.append(ok)
@@ -578,14 +671,18 @@ def _launch(wire, *, choice: str, interpret: bool = False,
     costs ~0.27 ms a call on a v5e whatever its size (PERF.md, PR 24
     and 26).  No buffer is donated: a tile's wire is 197 KB at the
     1,024 bucket and at most TilePipeline.IN_FLIGHT tiles are in
-    flight."""
+    flight.  A wire that carries the batch equation's columns (its
+    prep's shape was the tile's: crypto/pipeline.batch_equation) goes
+    to the batch-equation entry, any other Pallas wire to the per-lane
+    one."""
     if part is not None:
         return part.dispatch(*wire_views(wire))
     with tracing.span(tracing.CRYPTO, "h2d"):
         dw = jax.device_put(wire)
     if choice == "pallas":
-        fn = functools.partial(_pallas_verify_packed,
-                               interpret=interpret, block=block)
+        entry = _pallas_batch_verify_packed \
+            if wire.shape[1] > WIRE_LANE_BYTES else _pallas_verify_packed
+        fn = functools.partial(entry, interpret=interpret, block=block)
     else:
         fn = _jit_verify_packed
     with tracing.span(tracing.CRYPTO, "launch"):
@@ -635,6 +732,16 @@ def _kernel_choice() -> str:
     return "pallas" if device.probe().is_tpu else "xla"
 
 
+def _batched(m: int, choice: str) -> bool:
+    """Whether a dispatch of m lanes is prepped with the batch
+    equation's columns and settled by it: the Pallas kernel on one
+    device, at the shape crypto/pipeline.batch_equation names.  The
+    XLA kernel and the mesh partitioner keep the per-lane path, and so
+    does a host without the native prep, the columns' one producer."""
+    return choice == "pallas" and batch_equation(m) and \
+        not _sharded(m) and _native_prep() is not None
+
+
 def _padded(n: int, choice: str) -> int:
     """The lane count a chunk of n signatures dispatches at: its pad
     bucket, and at least one grid block for the Pallas kernel."""
@@ -655,7 +762,7 @@ def _verify_chunk(items) -> np.ndarray:
     # crypto_kernel_dispatch_seconds
     with tracing.timed(tracing.CRYPTO, "host_prep", batch=n,
                        bucket=m) as prep:
-        wire, pre_bad = prep_arrays(items, m)
+        wire, pre_bad = prep_arrays(items, m, _batched(m, choice))
     # compile-vs-execute attribution: the first dispatch of a
     # (kernel, bucket) shape includes trace+compile (unless warmup()
     # or the persistent cache served it); warm dispatches are pure
@@ -677,10 +784,12 @@ def _verify_chunk(items) -> np.ndarray:
     return out
 
 
-def _padding_wire(m: int) -> np.ndarray:
+def _padding_wire(m: int, batch: bool = False) -> np.ndarray:
     """A wire buffer of m padding lanes, which verify trivially:
-    0·B - identity - 0·A == identity."""
-    wire = np.zeros((m, WIRE_LANE_BYTES), np.uint8)
+    0·B - identity - 0·A == identity; with ``batch`` the batch
+    equation's zero digits too, which add the identity."""
+    wire = np.zeros((m, batch_lane_bytes(m) if batch
+                     else WIRE_LANE_BYTES), np.uint8)
     a_b, r_b, _, _ = wire_views(wire)
     a_b[:] = np.frombuffer(_B_BYTES, np.uint8)
     r_b[:] = np.frombuffer(_IDENTITY_BYTES, np.uint8)
@@ -705,9 +814,9 @@ def _native_prep():
 
 
 def _wire_arrays(wire: bytes, pre_bad: bytes, m: int):
-    """The native prep's two buffers as (wire [m,192]u8, pre_bad
-    [m]bool)."""
-    return (np.frombuffer(wire, np.uint8).reshape(m, WIRE_LANE_BYTES),
+    """The native prep's two buffers as (wire [m, lane bytes]u8,
+    pre_bad [m]bool)."""
+    return (np.frombuffer(wire, np.uint8).reshape(m, -1),
             np.frombuffer(pre_bad, np.uint8).astype(bool))
 
 
@@ -727,8 +836,8 @@ class _PrepNow:
                 t0, elapsed, elapsed)
 
 
-def _prep_begin(items, m: int):
-    """Begin prep_arrays(items, m) beside the caller: the native
+def _prep_begin(items, m: int, batch: bool = False):
+    """Begin prep_arrays(items, m, batch) beside the caller: the native
     module's handle (phase 2 on its prep thread, the GIL never taken
     there), whose ``result()`` waits with the GIL released and returns
     (wire, pre_bad, start_ns, elapsed_ns, waited_ns) — the two
@@ -738,10 +847,10 @@ def _prep_begin(items, m: int):
     if native is None:
         return _PrepNow(items, m)
     return native.ed25519_prep_begin(items, m, _B_BYTES,
-                                     _IDENTITY_BYTES)
+                                     _IDENTITY_BYTES, batch)
 
 
-def prep_arrays(items, m: int):
+def prep_arrays(items, m: int, batch: bool = False):
     """The full host-side prep for a batch of (pub, msg, sig) items,
     padded to m lanes: length/canonical-S checks, k = SHA-512(R||A||msg)
     mod L, 4-bit window split.  Returns (wire [m,192]u8, pre_bad
@@ -750,7 +859,11 @@ def prep_arrays(items, m: int):
     (64) — wire_views names the four column ranges; the device cuts
     them, transposes and casts to the kernels' int32 layouts, so the
     wire stays at 1 byte per element and one transfer.  Padding lanes
-    hold B, the identity and zero windows, and verify trivially.  Uses
+    hold B, the identity and zero windows, and verify trivially.  With
+    ``batch`` (a tile settled by the batch equation) the lane is
+    batch_lane_bytes(m) wide: the batch equation's digits follow
+    (batch_views), from a fresh z a lane; only the native prep writes
+    them (_batched).  Uses
     the one-pass C prep when the native module is built (the node
     builds it at start: ed25519_prep, which is _prep_begin's prep run
     on the calling thread with the GIL released), else the vectorized
@@ -760,7 +873,10 @@ def prep_arrays(items, m: int):
     native = _native_prep()
     if native is not None:
         return _wire_arrays(*native.ed25519_prep(
-            items, m, _B_BYTES, _IDENTITY_BYTES), m)
+            items, m, _B_BYTES, _IDENTITY_BYTES, batch), m)
+    if batch:
+        raise RuntimeError("the batch equation's columns need the "
+                           "native prep")
 
     wire = _padding_wire(m)
     a_b, r_b, s_w8, k_w8 = wire_views(wire)
@@ -820,6 +936,10 @@ def prep_arrays(items, m: int):
 SHARD_MIN = 1024
 
 
+def _sharded(m: int) -> bool:
+    return device.probe().count > 1 and m >= SHARD_MIN
+
+
 def _partitioner(m: int, choice: str, interpret: bool = False,
                  block: int = 0):
     """Multi-chip: when more than one JAX device is visible and the
@@ -827,11 +947,10 @@ def _partitioner(m: int, choice: str, interpret: bool = False,
     shards data-parallel over the full device mesh
     (parallel/mesh.py; SURVEY §2.11).  Returns that mesh's
     partitioner, or None for a single-device dispatch."""
-    ndev = device.probe().count
-    if ndev > 1 and m >= SHARD_MIN:
+    if _sharded(m):
         from ..parallel import mesh as pmesh
-        return pmesh.pipeline_partitioner(ndev, choice, interpret,
-                                          block)
+        return pmesh.pipeline_partitioner(device.probe().count, choice,
+                                          interpret, block)
     return None
 
 
@@ -843,10 +962,11 @@ def _dispatch(n: int, wire, pre_bad, *, kernel: str = "",
     (used by the interpret-mode Pallas parity tests, which exercise
     this exact path with a small block)."""
     choice = kernel or _kernel_choice()
-    part = _partitioner(wire.shape[0], choice, interpret, block)
+    m = wire.shape[0]
+    part = _partitioner(m, choice, interpret, block)
     ok = _force(_launch(wire, choice=choice, interpret=interpret,
                         block=block, part=part), sp)
-    ok = ok[:n].copy()
+    ok = _settled(ok, m, choice, part is None)[:n].copy()
     ok[pre_bad[:n]] = False
     return ok
 
@@ -862,7 +982,7 @@ def warmup(n: int) -> None:
 @functools.lru_cache(maxsize=None)
 def _warmup_bucket(m: int) -> None:
     choice = _kernel_choice()
-    wire = _padding_wire(m)
+    wire = _padding_wire(m, _batched(m, choice))
     with tracing.span(tracing.CRYPTO, "kernel_compile", bucket=m,
                       kernel=choice) as sp:
         _force(_launch(wire, choice=choice,

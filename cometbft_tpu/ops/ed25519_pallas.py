@@ -508,3 +508,253 @@ def verify_cols(a_cols, r_cols, s_win, k_win, interpret=False,
                 block=BLOCK):
     return _pallas_verify(a_cols, r_cols, s_win, k_win,
                           interpret=interpret, block=block)
+
+
+# --- the batch equation: one verdict a tile ---------------------------------
+#
+# [8](S·B - Σ z_i·R_i - Σ c_i·A_i) == identity, with fresh random
+# 128-bit z_i, c_i = z_i·k_i mod L and S = Σ z_i·s_i mod L, all made by
+# the host prep: the random-linear-combination equation of the
+# reference's BatchVerifier (curve25519-voi), of crypto/_ed25519_ref
+# .batch_verify and of the CPU tile kernel (native/ed25519_msm.hpp).
+# It holds for a tile whose every decodable lane satisfies the
+# cofactored single-signature equation, and for a tile holding a lane
+# that does not, with probability about 2^-125 at most.  It says
+# nothing about WHICH lane failed: the caller then runs the per-lane
+# kernel above on the same wire (ops/ed25519_jax).
+#
+# As a windowed multi-scalar multiplication: a grid step decodes its
+# block's A and R (one _decompress over both, side by side), builds
+# the lanes' tables i·(-A) and i·(-R) for i = 0..8 in cached form, and
+# adds each lane's term of window j (its signed digit's entry) into
+# window j's accumulator, a block of lane-wise partial sums that stays
+# in VMEM across the grid.  The S·B term is spread over the grid steps,
+# in lane 0 alone, from the constant B table.  The last step folds the
+# windows by Horner's rule (4 doublings a window, once a tile, on the
+# one accumulator block), sums the block's lanes by rotation, clears
+# the cofactor and tests for the identity.  No lane is ever doubled:
+# that is where the per-lane ladder's 256 doublings a lane go.
+
+_C_DIGITS = _WINDOWS            # signed 4-bit digits of c < L
+_Z_DIGITS = 33                  # signed 4-bit digits of the 128-bit z
+DIGIT_ROWS = _C_DIGITS + _Z_DIGITS
+_ENTRIES = 9                    # 0..8: a signed digit is in [-8, 7]
+
+
+def _build_b_cached() -> np.ndarray:
+    """Entries 0..8 of the constant B table in cached form,
+    [9, 4, 24, 1]: (y-x | y+x | Z = 1 | 2d·x·y) — the affine table of
+    _build_b_table_cols with a Z row, read by _cached_add."""
+    out = np.zeros((_ENTRIES, 4, LIMBS, 1), np.int32)
+    out[:, 0] = _B_TABLE_NP[:_ENTRIES, 0]
+    out[:, 1] = _B_TABLE_NP[:_ENTRIES, 1]
+    out[:, 2, 0, 0] = 1
+    out[:, 3] = _B_TABLE_NP[:_ENTRIES, 2]
+    return out
+
+
+_BATCH_CONSTS_NP = np.concatenate([
+    _CONSTS_NP[:6 * LIMBS],
+    _build_b_cached().reshape(_ENTRIES * 4 * LIMBS, 1),
+], axis=0)
+
+
+def _cached(p, two_d, pats):
+    """Extended point -> cached form (y-x, y+x, Z, 2d·T), [96, B]: the
+    operands _ext_add would form from it, formed once for every add
+    that reads the entry.  y-x and y+x take the one balanced pass
+    _ext_add gives them (the once-carried class of field24's bounds);
+    2d·T is a product, resting."""
+    X, Y, Z, T = p
+    return jnp.concatenate([_carry(Y - X), _carry(Y + X), Z,
+                            _mul(T, two_d, pats, 0, 0)], axis=0)
+
+
+def _cached_add(p, q, pats):
+    """p + q for a cached-form q: _ext_add with q's operands formed
+    ahead, 8 field multiplies for 9.  Every product meets the operand
+    classes it meets in _ext_add."""
+    X1, Y1, Z1, T1 = p
+    ymx2, ypx2, Z2, t2d2 = q
+    a = _mul(Y1 - X1, ymx2, pats, 1, 0)
+    b = _mul(Y1 + X1, ypx2, pats, 1, 0)
+    c = _mul(T1, t2d2, pats, 0, 0)
+    d = _mul_const(_mul(Z1, Z2, pats, 0, 0), 2, passes=1)
+    e = _carry(b - a)
+    ff = _carry(d - c)
+    g = _carry(d + c)
+    h = _carry(b + a)
+    return (_mul(e, ff, pats, 0, 0), _mul(g, h, pats, 0, 0),
+            _mul(ff, g, pats, 0, 0), _mul(e, h, pats, 0, 0))
+
+
+def _split(rows):
+    return tuple(rows[i * LIMBS:(i + 1) * LIMBS] for i in range(4))
+
+
+def _batch_kernel(a_ref, r_ref, dig_ref, sdig_ref, consts_ref, ok_ref,
+                  held_ref, tab_ref, acc_ref, *, s_per_step):
+    B = a_ref.shape[1]
+    step = pl.program_id(0)
+    d_col = consts_ref[0:LIMBS]
+    two_d = consts_ref[LIMBS:2 * LIMBS]
+    sqrt_m1 = consts_ref[2 * LIMBS:3 * LIMBS]
+    four_p = consts_ref[3 * LIMBS:4 * LIMBS]
+    pats = (consts_ref[4 * LIMBS:5 * LIMBS],
+            consts_ref[5 * LIMBS:6 * LIMBS])
+    zero = jnp.zeros((LIMBS, B), jnp.int32)
+    one = jnp.concatenate(
+        [jnp.ones((1, B), jnp.int32), zero[1:]], axis=0)
+    ident = jnp.concatenate([zero, one, one, zero], axis=0)
+
+    @pl.when(step == 0)
+    def _():
+        def clear(j, _):
+            acc_ref[j] = ident
+            return 0
+
+        lax.fori_loop(0, _WINDOWS, clear, 0)
+        for e in range(_ENTRIES):
+            b_e = consts_ref[(6 + 4 * e) * LIMBS:(10 + 4 * e) * LIMBS]
+            tab_ref[2, e] = jnp.broadcast_to(b_e, (4 * LIMBS, B))
+        held_ref[:] = jnp.zeros(held_ref.shape, jnp.int32)
+
+    # A and R side by side: one decompression over 2B lanes
+    x, y, ok2 = _decompress(
+        jnp.concatenate([a_ref[:], r_ref[:]], axis=1),
+        d_col, sqrt_m1, four_p, pats)
+    ok2 = ok2.astype(jnp.int32)
+    lane_ok = (ok2[:, :B] & ok2[:, B:]) != 0
+    ok_ref[:] = jnp.broadcast_to(lane_ok.astype(jnp.int32), (8, B))
+
+    # i·(-A) | i·(-R) for i = 0..8, cached form, tables 0 and 1
+    def store(e, p):
+        c = _cached(p, two_d, pats)
+        tab_ref[0, e] = c[:, :B]
+        tab_ref[1, e] = c[:, B:]
+
+    zero2 = jnp.concatenate([zero, zero], axis=1)
+    one2 = jnp.concatenate([one, one], axis=1)
+    nx = -x
+    p1 = (nx, y, one2, _mul(nx, y, pats, 0, 1))   # y: raw byte digits
+    store(0, (zero2, one2, one2, zero2))
+    store(1, p1)
+
+    def build(e, p):
+        p = _ext_add(p, p1, two_d, pats)
+        store(e, p)
+        return p
+
+    lax.fori_loop(2, _ENTRIES, build, p1)
+
+    lane = lax.broadcasted_iota(jnp.int32, (1, B), 1)
+
+    def term(t, _):
+        # t < 64: window t of c·(-A); t < 97: window t-64 of z·(-R);
+        # then this step's share of S's windows, on lane 0 alone
+        in_r = t >= _C_DIGITS
+        in_s = t >= DIGIT_ROWS
+        js = t - DIGIT_ROWS + step * s_per_step
+        j = jnp.where(in_s, jnp.minimum(js, _WINDOWS - 1),
+                      jnp.where(in_r, t - _C_DIGITS, t))
+        table = jnp.where(in_s, 2, jnp.where(in_r, 1, 0))
+        d_lane = dig_ref[pl.ds(jnp.minimum(t, DIGIT_ROWS - 1), 1)]
+        d_s = sdig_ref[pl.ds(jnp.minimum(js, _WINDOWS - 1), 1)]
+        d = jnp.where(
+            in_s, jnp.where((lane == 0) & (js < _WINDOWS), d_s, 0),
+            jnp.where(lane_ok, d_lane, 0))
+        mag = jnp.abs(d)
+        rows = [tab_ref[table, e] for e in range(_ENTRIES)]
+        bit = 1
+        while len(rows) > 2:                  # entries 0..7 by 3 bits
+            cond = (mag & bit) != 0
+            rows = [jnp.where(cond, rows[i + 1], rows[i])
+                    for i in range(0, len(rows) - 1, 2)] + [rows[-1]]
+            bit <<= 1
+        sel = jnp.where(mag == 8, rows[1], rows[0])
+        ymx, ypx, z, t2d = _split(sel)
+        neg = d < 0
+        q = (jnp.where(neg, ypx, ymx), jnp.where(neg, ymx, ypx), z,
+             jnp.where(neg, -t2d, t2d))
+        acc_ref[j] = jnp.concatenate(
+            _cached_add(_split(acc_ref[j]), q, pats), axis=0)
+        return 0
+
+    lax.fori_loop(0, DIGIT_ROWS + s_per_step, term, 0)
+
+    @pl.when(step == pl.num_programs(0) - 1)
+    def _():
+        def dbl(_, p):
+            return _ext_double(p, pats)
+
+        def horner(i, p):
+            p = lax.fori_loop(0, 4, dbl, p)
+            return _ext_add(p, _split(acc_ref[_WINDOWS - 2 - i]),
+                            two_d, pats)
+
+        p = lax.fori_loop(0, _WINDOWS - 1, horner,
+                          _split(acc_ref[_WINDOWS - 1]))
+
+        def fold(i, p):                       # every lane: the sum
+            shift = B >> (i + 1)
+            return _ext_add(p, tuple(pltpu.roll(c, shift, 1) for c in p),
+                            two_d, pats)
+
+        p = lax.fori_loop(0, B.bit_length() - 1, fold, p)
+        X, Y, Z, _ = lax.fori_loop(0, 3, dbl, p)
+        held = _is_zero(X, four_p) & _eq(Y, Z, four_p)
+        held_ref[:] = jnp.broadcast_to(held.astype(jnp.int32),
+                                       held_ref.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block"))
+def _pallas_batch(a_cols, r_cols, digits, s_digits, interpret=False,
+                  block=BLOCK):
+    """a_cols, r_cols: [32, n] int32 byte values; digits: [97, n]
+    int32 signed digits (c's 64, then z's 33); s_digits: [64, 1] S's.
+    Returns (held: bool scalar, decoded: [n] bool, A and R both
+    decodable).  n must be a multiple of block, a power of two."""
+    n = a_cols.shape[1]
+    if n % block != 0 or block & (block - 1):
+        raise ValueError(
+            f"lane count {n} must be a multiple of block {block}, a "
+            "power of two")
+    grid = n // block
+    kernel = functools.partial(_batch_kernel,
+                               s_per_step=-(-_WINDOWS // grid))
+    ok, held = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((8, n), jnp.int32),
+                   jax.ShapeDtypeStruct((8, block), jnp.int32)),
+        grid=(grid,),
+        in_specs=[
+            pl.BlockSpec((32, block), lambda i: (0, i),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((32, block), lambda i: (0, i),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((DIGIT_ROWS, block), lambda i: (0, i),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((_WINDOWS, 1), lambda i: (0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((_BATCH_CONSTS_NP.shape[0], 1),
+                         lambda i: (0, 0), memory_space=pltpu.VMEM),
+        ],
+        out_specs=(pl.BlockSpec((8, block), lambda i: (0, i),
+                                memory_space=pltpu.VMEM),
+                   pl.BlockSpec((8, block), lambda i: (0, 0),
+                                memory_space=pltpu.VMEM)),
+        scratch_shapes=[
+            pltpu.VMEM((3, _ENTRIES, 4 * LIMBS, block), jnp.int32),
+            pltpu.VMEM((_WINDOWS, 4 * LIMBS, block), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(a_cols, r_cols, digits, s_digits, jnp.asarray(_BATCH_CONSTS_NP))
+    return held[0, 0] != 0, ok[0] != 0
+
+
+def batch_cols(a_cols, r_cols, digits, s_digits, interpret=False,
+               block=BLOCK):
+    return _pallas_batch(a_cols, r_cols, digits, s_digits,
+                         interpret=interpret, block=block)

@@ -16,9 +16,11 @@
 #include <pthread.h>
 #include <sched.h>
 #include <signal.h>
+#include <sys/random.h>
 #include <time.h>
 
 #include <algorithm>
+#include <cerrno>
 #include <condition_variable>
 #include <cstdint>
 #include <cstring>
@@ -207,6 +209,17 @@ PyObject* ed25519_kscalars(PyObject*, PyObject* arg) {
 //       [128, 192) 4-bit windows of k
 //     (ops/ed25519_jax.wire_views names the same four column ranges)
 //   pre_bad: [m] uint8 (1 = malformed or non-canonical S)
+// ed25519_prep(items, m, b_bytes, identity_bytes, True) is the prep of
+// a tile settled by the batch equation (ops/ed25519_pallas
+// ._batch_kernel): phase 2 draws z, 16 bytes a lane, from the OS's
+// generator (getrandom), fresh for every prep, and a lane is
+// batch_lane_bytes(m) wide, the 192 above and then
+//       [192, 256)  c = z·k mod L, 64 signed 4-bit digits
+//       [256, 289)  z (its low bit set), 33 signed 4-bit digits
+//       [289, ...)  S = Σ z·s mod L, 64 signed 4-bit digits: digit t
+//                   at byte t of these columns read lane after lane
+//     (ops/ed25519_jax.batch_views names them).  A refused or padding
+//     lane has zero digits, so it adds the identity to the equation.
 // This is the batch verifier's entire host prep: pointers are
 // extracted under the GIL (cheap), then the SHA-512 / window loop
 // runs GIL-free across hardware threads — the budget (BASELINE:
@@ -234,6 +247,23 @@ static const uint8_t L_LE[32] = {
     0x00, 0x00, 0x00, 0x00, 0x00, 0x00, 0x00, 0x10,
 };
 
+// the batch equation's columns, on a tile's wire only
+constexpr Py_ssize_t WIRE_C = 192, WIRE_Z = 256, WIRE_SD = 289;
+constexpr int C_DIGITS = 64, Z_DIGITS = 33, S_DIGITS = 64;
+
+inline Py_ssize_t batch_lane_bytes(Py_ssize_t m) {
+    return WIRE_SD + (S_DIGITS + m - 1) / m;
+}
+
+// The wire of one prep: where a lane's row is, and, on a tile settled
+// by the batch equation, its randomizers and the running Σ z·s
+struct Wire {
+    uint8_t* p = nullptr;
+    Py_ssize_t stride = WIRE_LANE;
+    const uint8_t* z = nullptr;         // 16 bytes a lane, low bit set
+    uint64_t zs[8] = {0};               // Σ z·s, 512 bits, unreduced
+};
+
 inline void write_windows(uint8_t* row, const uint8_t le[32]) {
     for (int b = 0; b < 32; b++) {
         row[2 * b] = le[b] & 0x0F;
@@ -241,11 +271,98 @@ inline void write_windows(uint8_t* row, const uint8_t le[32]) {
     }
 }
 
-inline void k_windows_from_digest(const uint8_t digest[64],
-                                  uint8_t* wire, Py_ssize_t lane) {
+// Signed radix-16 digits in [-8, 7], two's complement bytes, of the
+// value x (64-bit limbs, little-endian): digit i is nibble i of
+// x + 0x88…8 (ndigits nibbles of 8) less 8, which is the recoding
+// with its carries; x + 0x88…8 must fit ndigits nibbles (it does for
+// the 128-bit z in 33, and for c, S < L in 64)
+inline void write_signed_digits(uint8_t* out, const uint64_t* x,
+                                int ndigits) {
+    uint64_t y[4];
+    unsigned __int128 carry = 0;
+    int limbs = (ndigits + 15) / 16;
+    for (int i = 0; i < limbs; i++) {
+        int nib = ndigits - 16 * i < 16 ? ndigits - 16 * i : 16;
+        uint64_t k = nib == 16 ? 0x8888888888888888ull
+                               : 0x8888888888888888ull >> (64 - 4 * nib);
+        unsigned __int128 t = (unsigned __int128)x[i] + k + (uint64_t)carry;
+        y[i] = uint64_t(t);
+        carry = t >> 64;
+    }
+    for (int i = 0; i < ndigits; i++)
+        out[i] = uint8_t(int((y[i >> 4] >> (4 * (i & 15))) & 0x0F) - 8);
+}
+
+// a lane's z, widened to a 32-byte scalar for ed25519_msm's sc_mul
+inline void lane_z(const Wire& w, Py_ssize_t lane, uint8_t z[32]) {
+    std::memcpy(z, w.z + 16 * lane, 16);
+    std::memset(z + 16, 0, 16);
+}
+
+inline void k_windows_from_digest(const uint8_t digest[64], Wire& w,
+                                  Py_ssize_t lane) {
     uint8_t k_le[32];
     sha512::reduce_mod_l(digest, k_le);
-    write_windows(wire + lane * WIRE_LANE + WIRE_K, k_le);
+    uint8_t* row = w.p + lane * w.stride;
+    write_windows(row + WIRE_K, k_le);
+    if (w.z) {
+        uint8_t z[32], c_le[32];
+        uint64_t c[4];
+        lane_z(w, lane, z);
+        ed25519_msm::sc_mul(z, k_le, c_le);
+        std::memcpy(c, c_le, 32);
+        write_signed_digits(row + WIRE_C, c, C_DIGITS);
+    }
+}
+
+// a lane's z digits, and z·s into the running sum (reduced once, at
+// the end of the prep: a tile's sum of products < 2^381 stays far
+// below 2^512)
+inline void batch_lane(Wire& w, Py_ssize_t lane, const uint8_t s_le[32]) {
+    uint8_t z[32];
+    uint64_t zl[3] = {0, 0, 0}, prod[8];
+    lane_z(w, lane, z);
+    std::memcpy(zl, z, 16);
+    write_signed_digits(w.p + lane * w.stride + WIRE_Z, zl, Z_DIGITS);
+    ed25519_msm::sc_mul_wide(z, s_le, prod);
+    unsigned __int128 carry = 0;
+    for (int i = 0; i < 8; i++) {
+        unsigned __int128 t = (unsigned __int128)w.zs[i] + prod[i] +
+                              (uint64_t)carry;
+        w.zs[i] = uint64_t(t);
+        carry = t >> 64;
+    }
+}
+
+// S = Σ z·s mod L, its digits into the S columns
+inline void write_s_digits(Wire& w) {
+    uint8_t wide[64], s_le[32], dig[S_DIGITS];
+    uint64_t s[4];
+    std::memcpy(wide, w.zs, 64);
+    sha512::reduce_mod_l(wide, s_le);
+    std::memcpy(s, s_le, 32);
+    write_signed_digits(dig, s, S_DIGITS);
+    Py_ssize_t cols = w.stride - WIRE_SD;
+    for (int t = 0; t < S_DIGITS; t++)
+        w.p[(t / cols) * w.stride + WIRE_SD + t % cols] = dig[t];
+}
+
+// z for every lane of a prep: 16 bytes from the OS's generator (the
+// source of Python's ``secrets``), low bit set; false if it could not
+// be read, and then the prep fails: a zero z would make every tile's
+// equation hold
+inline bool draw_z(std::vector<uint8_t>& z) {
+    size_t got = 0;
+    while (got < z.size()) {
+        ssize_t r = getrandom(z.data() + got, z.size() - got, 0);
+        if (r < 0) {
+            if (errno == EINTR) continue;
+            return false;
+        }
+        got += size_t(r);
+    }
+    for (size_t i = 0; i < z.size(); i += 16) z[i] |= 1;
+    return true;
 }
 
 #if COMETBFT_SHA512MB_X86
@@ -259,7 +376,7 @@ struct KGroup {
 };
 
 inline void flush_group(KGroup& g, std::vector<uint8_t>& scratch,
-                        uint8_t* wire) {
+                        Wire& wire) {
     if (g.n == 0) return;
     size_t slot = g.nblocks * 128;
     scratch.assign(slot * 8, 0);
@@ -291,7 +408,7 @@ inline void flush_group(KGroup& g, std::vector<uint8_t>& scratch,
 // (8-way multi-buffer where AVX-512 is present), item-major windows,
 // each lane into its own row of the wire buffer
 void lanes(const ItemRef* refs, Py_ssize_t lo, Py_ssize_t hi,
-           uint8_t* wire, uint8_t* bad_p) {
+           Wire& wire, uint8_t* bad_p) {
 #if COMETBFT_SHA512MB_X86
     const bool use_mb = sha512mb::available();
     // groups keyed by block count: a few at most, since a batch holds
@@ -318,10 +435,11 @@ void lanes(const ItemRef* refs, Py_ssize_t lo, Py_ssize_t hi,
             bad_p[i] = 1;
             continue;
         }
-        uint8_t* row = wire + i * WIRE_LANE;
+        uint8_t* row = wire.p + i * wire.stride;
         std::memcpy(row, it.pub, 32);
         std::memcpy(row + WIRE_R, it.sig, 32);
         write_windows(row + WIRE_S, s_le);
+        if (wire.z) batch_lane(wire, i, s_le);
 #if COMETBFT_SHA512MB_X86
         if (use_mb) {
             size_t nb = sha512mb::block_count(64 + it.msglen);
@@ -367,6 +485,9 @@ struct Job {
     std::vector<PyObject*> fits;        // each item as a fast sequence
     std::vector<ItemRef> refs;
     Py_ssize_t n = 0, m = 0;
+    Py_ssize_t lane_bytes = WIRE_LANE;
+    std::vector<uint8_t> z;             // the batch equation's, or empty
+    bool no_z = false;                  // z could not be drawn
     uint8_t b[32], id[32];
     PyObject* wire_out = nullptr;
     PyObject* bad_out = nullptr;
@@ -389,15 +510,28 @@ inline int64_t mono_ns() {
 // phase 2: no Python object is touched and the GIL is not needed
 void run(Job* j) {
     j->t0_ns = mono_ns();
-    // padding defaults (windows of unwritten lanes must be zero)
-    std::memset(j->wire_p, 0, size_t(WIRE_LANE) * size_t(j->m));
+    // padding defaults (windows and digits of unwritten lanes must be
+    // zero)
+    std::memset(j->wire_p, 0, size_t(j->lane_bytes) * size_t(j->m));
     std::memset(j->bad_p, 0, size_t(j->m));
     for (Py_ssize_t i = 0; i < j->m; i++) {
-        uint8_t* row = j->wire_p + i * WIRE_LANE;
+        uint8_t* row = j->wire_p + i * j->lane_bytes;
         std::memcpy(row, j->b, 32);
         std::memcpy(row + WIRE_R, j->id, 32);
     }
-    lanes(j->refs.data(), 0, j->n, j->wire_p, j->bad_p);
+    Wire w;
+    w.p = j->wire_p;
+    w.stride = j->lane_bytes;
+    if (!j->z.empty()) {
+        if (!draw_z(j->z)) {
+            j->no_z = true;
+            j->t1_ns = mono_ns();
+            return;
+        }
+        w.z = j->z.data();
+    }
+    lanes(j->refs.data(), 0, j->n, w, j->bad_p);
+    if (w.z) write_s_digits(w);
     j->t1_ns = mono_ns();
 }
 
@@ -542,6 +676,13 @@ PyObject* prep_handle_result(PyObject* self, PyObject*) {
         return nullptr;
     }
     int64_t waited = prep::settle(j, /*reading=*/true);
+    if (j->no_z) {
+        prep_handle_free_refs(j);
+        PyErr_SetString(PyExc_RuntimeError,
+                        "the OS's generator gave no randomizers for "
+                        "the batch equation");
+        return nullptr;
+    }
     PyObject* out = Py_BuildValue(
         "(OOLLL)", j->wire_out, j->bad_out, (long long)j->t0_ns,
         (long long)(j->t1_ns - j->t0_ns), (long long)waited);
@@ -578,11 +719,16 @@ PyObject* prep_begin(PyObject* args, bool post) {
     Py_ssize_t b_len;
     const char* id_bytes;
     Py_ssize_t id_len;
-    if (!PyArg_ParseTuple(args, "Ony#y#", &seq_in, &m, &b_bytes,
-                          &b_len, &id_bytes, &id_len))
+    int batch = 0;
+    if (!PyArg_ParseTuple(args, "Ony#y#|p", &seq_in, &m, &b_bytes,
+                          &b_len, &id_bytes, &id_len, &batch))
         return nullptr;
     if (b_len != 32 || id_len != 32) {
         PyErr_SetString(PyExc_ValueError, "constants must be 32 bytes");
+        return nullptr;
+    }
+    if (batch && m < 1) {
+        PyErr_SetString(PyExc_ValueError, "a batch needs a lane");
         return nullptr;
     }
     PyObject* fast = PySequence_Fast(seq_in, "expected a sequence");
@@ -604,8 +750,11 @@ PyObject* prep_begin(PyObject* args, bool post) {
     j->m = m;
     std::memcpy(j->b, b_bytes, 32);
     std::memcpy(j->id, id_bytes, 32);
-    j->wire_out = PyBytes_FromStringAndSize(nullptr,
-                                            prep::WIRE_LANE * m);
+    if (batch) {
+        j->lane_bytes = prep::batch_lane_bytes(m);
+        j->z.resize(size_t(16 * m));
+    }
+    j->wire_out = PyBytes_FromStringAndSize(nullptr, j->lane_bytes * m);
     j->bad_out = PyBytes_FromStringAndSize(nullptr, m);
     if (!j->wire_out || !j->bad_out) {
         Py_DECREF(h);
@@ -657,7 +806,7 @@ PyObject* prep_begin(PyObject* args, bool post) {
     return reinterpret_cast<PyObject*>(h);
 }
 
-// ed25519_prep_begin(items, m, b_bytes, identity_bytes) -> PrepHandle:
+// ed25519_prep_begin(items, m, b_bytes, identity_bytes[, batch]) -> PrepHandle:
 // phase 1 here, phase 2 on the native thread while the caller goes on
 PyObject* ed25519_prep_begin(PyObject*, PyObject* args) {
     return prep_begin(args, /*post=*/true);
@@ -1241,8 +1390,9 @@ PyMethodDef kMethods[] = {
     {"ed25519_batch_verify", ed25519_batch_verify, METH_VARARGS,
      "RLC batch verification of (pub, msg, sig) items (ZIP-215)"},
     {"ed25519_prep", ed25519_prep, METH_VARARGS,
-     "full batch-verify host prep: (items, m, B, identity) -> "
-     "(wire [m*192], pre_bad [m])"},
+     "full batch-verify host prep: (items, m, B, identity[, batch]) "
+     "-> (wire [m*192], pre_bad [m]); with batch the wire of a tile "
+     "settled by the batch equation, its z drawn from the OS"},
     {"ed25519_prep_begin", ed25519_prep_begin, METH_VARARGS,
      "the same prep as a future: phase 2 on the native thread; "
      "-> PrepHandle, whose result() is (wire, pre_bad, start_ns, "

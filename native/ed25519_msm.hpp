@@ -328,14 +328,14 @@ inline bool ge_is_identity_cofactored(ge p) {
 
 // ------------------------------------------------------------ scalars
 
-// 256x256 -> 512-bit product (little-endian bytes), then mod L via the
-// existing sha512::reduce_mod_l 512-bit reducer
-inline void sc_mul(const uint8_t a[32], const uint8_t b[32],
-                   uint8_t out[32]) {
+// 256x256 -> 512-bit product, 64-bit limbs little-endian (unreduced:
+// a caller summing many products reduces once, with reduce_mod_l)
+inline void sc_mul_wide(const uint8_t a[32], const uint8_t b[32],
+                        uint64_t prod[8]) {
     uint64_t al[4], bl[4];
     std::memcpy(al, a, 32);
     std::memcpy(bl, b, 32);
-    uint64_t prod[8] = {0};
+    std::memset(prod, 0, 64);
     for (int i = 0; i < 4; i++) {
         u128 carry = 0;
         for (int j = 0; j < 4; j++) {
@@ -345,6 +345,14 @@ inline void sc_mul(const uint8_t a[32], const uint8_t b[32],
         }
         prod[i + 4] = (uint64_t)carry;
     }
+}
+
+// the product mod L via the existing sha512::reduce_mod_l 512-bit
+// reducer
+inline void sc_mul(const uint8_t a[32], const uint8_t b[32],
+                   uint8_t out[32]) {
+    uint64_t prod[8];
+    sc_mul_wide(a, b, prod);
     uint8_t wide[64];
     std::memcpy(wide, prod, 64);
     sha512::reduce_mod_l(wide, out);

@@ -533,7 +533,8 @@ def test_the_plan_at_10000_validators(monkeypatch):
     from add()) + 523 (by verify()); verify_batch, handed the whole
     list, plans seven balanced tiles.  Either way every chunk
     dispatches at the 1,024 bucket, the pipeline's one shape: 7,168
-    lanes for 6,667, and warm-up warms that one shape.  The CPU
+    lanes for 6,667, and warm-up warms that one shape, which the
+    Pallas kernel settles by the batch equation first.  The CPU
     verifier's MSM keeps its 4,096."""
     monkeypatch.setattr(ej, "SHARD_MIN", 1000000)
     assert 10000 * 10 * 2 // 3 // 10 + 1 == 6667
@@ -551,7 +552,7 @@ def test_the_plan_at_10000_validators(monkeypatch):
         assert [ej._padded(hi - lo, kernel) for lo, hi in plan] == \
             [1024] * 7
     warmed = []
-    for name in KERNELS:
+    for name in KERNELS + ("_pallas_batch_verify_packed",):
         monkeypatch.setattr(
             ej, name, lambda wire, **static: warmed.append(wire.shape)
             or jnp.ones(wire.shape[0], dtype=bool))
@@ -561,4 +562,4 @@ def test_the_plan_at_10000_validators(monkeypatch):
         ej.warmup(6667)
     finally:
         ej._warmup_bucket.cache_clear()
-    assert warmed == [(1024, ej.WIRE_LANE_BYTES)]
+    assert warmed == [(1024, ej.batch_lane_bytes(1024))]

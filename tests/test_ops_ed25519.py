@@ -315,7 +315,8 @@ class TestOneTransferADispatch:
         # conftest's eight virtual devices would send 1,024 lanes to
         # the mesh partitioner: one chip is what a cell runs on
         monkeypatch.setattr(ej, "SHARD_MIN", 1000000)
-        for name in ("_jit_verify_packed", "_pallas_verify_packed"):
+        for name in ("_jit_verify_packed", "_pallas_verify_packed",
+                     "_pallas_batch_verify_packed"):
             monkeypatch.setattr(ej, name, stub)
         return puts, calls
 
@@ -331,12 +332,16 @@ class TestOneTransferADispatch:
         monkeypatch.setenv("COMETBFT_TPU_KERNEL", kernel)
         ok, mask = ej.verify_batch(self._commit(175))
         assert ok and len(mask) == 175
-        assert puts == [(1024, ej.WIRE_LANE_BYTES)]
+        # at the tile's shape the Pallas wire carries the batch
+        # equation's columns too: still one buffer
+        width = ej.batch_lane_bytes(1024) if kernel == "pallas" \
+            else ej.WIRE_LANE_BYTES
+        assert puts == [(1024, width)]
         # and nothing rides in beside it: one operand, on the device
         (operands,) = calls
         assert len(operands) == 1
         assert isinstance(operands[0], jax.Array)
-        assert operands[0].shape == (1024, ej.WIRE_LANE_BYTES)
+        assert operands[0].shape == (1024, width)
 
     def test_each_tile_of_the_pipelined_path(self, counted,
                                              monkeypatch):

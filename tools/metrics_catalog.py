@@ -64,6 +64,7 @@ def collect_catalog() -> list[dict]:
     crypto_pipeline.dispatch_histogram()
     ed25519_jax._refine_counter()
     ed25519_jax.streamed_tiles_counter()
+    ed25519_jax.batch_equation_counter()
     signature_cache._metrics()
     from cometbft_tpu.light import client as light_client
     light_client.hop_counters()
