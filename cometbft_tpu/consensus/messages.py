@@ -4,8 +4,10 @@ Reference: internal/consensus/msgs.go — ProposalMessage, BlockPartMessage,
 VoteMessage, NewRoundStepMessage, NewValidBlockMessage, HasVoteMessage,
 VoteSetMaj23Message, VoteSetBitsMessage, ProposalPOLMessage.
 
-WAL/JSON codec: proto-shaped dicts with bytes hex-tagged, so records are
-self-describing and durable across code changes.
+WAL records: ``to_wal`` gives a message's type and its proto dict, which
+``consensus/wal.py`` writes as a tag byte and the proto; ``jsonify`` /
+``dejsonify`` are the JSON payload's codec (bytes hex-tagged) for any
+other record.
 """
 from __future__ import annotations
 
@@ -48,7 +50,7 @@ class ProposalMessage:
 
     def to_wal(self) -> dict:
         return {"type": self.TYPE,
-                "proposal": jsonify(self.proposal.to_proto())}
+                "proposal": self.proposal.to_proto()}
 
 
 @dataclass
@@ -62,7 +64,7 @@ class BlockPartMessage:
     def to_wal(self) -> dict:
         return {"type": self.TYPE, "height": self.height,
                 "round": self.round,
-                "part": jsonify(self.part.to_proto())}
+                "part": self.part.to_proto()}
 
 
 @dataclass
@@ -72,7 +74,7 @@ class VoteMessage:
     TYPE = "vote"
 
     def to_wal(self) -> dict:
-        return {"type": self.TYPE, "vote": jsonify(self.vote.to_proto())}
+        return {"type": self.TYPE, "vote": self.vote.to_proto()}
 
 
 @dataclass
@@ -216,7 +218,7 @@ class AggregateCommitMessage:
 
     def to_wal(self) -> dict:
         return {"type": self.TYPE,
-                "commit": jsonify(self.commit.to_proto())}
+                "commit": self.commit.to_proto()}
 
 
 def make_compact_block(height: int, round_: int, block,
@@ -249,21 +251,21 @@ def reconstruct_block_bytes(skeleton: bytes, txs: list) -> bytes:
 
 
 def message_from_wal(d: dict):
-    """Decode a WAL msg record back into a message object."""
+    """A WAL message record, as ``WAL.iter_frames`` reads it (the body
+    a proto dict with its bytes), back into a message object."""
     t = d.get("type")
-    if t == ProposalMessage.TYPE:
-        return ProposalMessage(
-            Proposal.from_proto(dejsonify(d["proposal"])))
+    if t == VoteMessage.TYPE:
+        return VoteMessage(Vote.from_proto(d["vote"]))
     if t == BlockPartMessage.TYPE:
         return BlockPartMessage(
             height=d["height"], round=d["round"],
-            part=Part.from_proto(dejsonify(d["part"])))
-    if t == VoteMessage.TYPE:
-        return VoteMessage(Vote.from_proto(dejsonify(d["vote"])))
+            part=Part.from_proto(d["part"]))
+    if t == ProposalMessage.TYPE:
+        return ProposalMessage(Proposal.from_proto(d["proposal"]))
     if t == AggregateCommitMessage.TYPE:
         from ..types.commit import AggregateCommit
         return AggregateCommitMessage(
-            AggregateCommit.from_proto(dejsonify(d["commit"])))
+            AggregateCommit.from_proto(d["commit"]))
     raise ValueError(f"unknown WAL message type {t!r}")
 
 

@@ -29,7 +29,7 @@ from .messages import message_from_wal
 from .round_state import TimeoutInfo
 from .state import ConsensusState
 from .ticker import NilTicker
-from .wal import WAL
+from .wal import WAL, record_counts
 
 
 class ReplayError(Exception):
@@ -355,8 +355,10 @@ async def _feed(cs, records) -> tuple[int, bool]:
     height, n, read = cs.rs.height, 0, 1
     while read and cs.rs.height == height:
         with tracing.span(tracing.CONSENSUS, "wal_read") as sp:
+            json0 = record_counts()["json"]
             burst, read, size = _read_ahead(records)
-            sp.note(records=read, bytes=size)
+            sp.note(records=read, bytes=size,
+                    json=record_counts()["json"] - json0)
         await cs._handle_burst(burst, fair=False)
         n += len(burst)
     _REPLAYED.add(cs.rs.height - height)

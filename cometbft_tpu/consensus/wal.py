@@ -2,9 +2,15 @@
 
 Reference: internal/consensus/wal.go — CRC32C + length framed records via
 internal/autofile; WriteSync fsync barrier before height end;
-SearchForEndHeight for replay.  Record payloads here are canonical JSON
-(bytes hex-encoded) — WAL bytes are node-local, only durability and
-replayability matter.
+SearchForEndHeight for replay.  WAL bytes are node-local: only durability
+and replayability matter.
+
+A record's payload is one tag byte and a body.  The four message kinds
+carry their proto (``wire/pb.py``, through ``wire.encode``) after the
+tag, ``block_part`` its height and round first; ``timeout`` and
+``end_height`` carry their integers alone.  Anything else is canonical
+JSON (bytes hex-encoded), whose first byte is ``{``: no tag takes that
+value, so a WAL written in JSON throughout reads by the same rule.
 """
 from __future__ import annotations
 
@@ -12,7 +18,11 @@ import json
 import os
 import struct
 import zlib
-from typing import Iterator, Optional
+from typing import Any, Callable, Iterator, Optional
+
+from ..libs import metrics as libmetrics
+from ..wire import decode, encode, pb
+from .messages import dejsonify, jsonify
 
 MAX_MSG_SIZE_BYTES = 1024 * 1024 * 2  # reference: wal.go maxMsgSizeBytes
 
@@ -28,6 +38,105 @@ class CorruptWALError(WALError):
 def _frame(payload: bytes) -> bytes:
     crc = zlib.crc32(payload) & 0xFFFFFFFF
     return struct.pack(">II", crc, len(payload)) + payload
+
+
+# -- the record payload --------------------------------------------------------
+
+class _Kind:
+    """A record type in binary: its tag, the integer fields before the
+    body (big-endian int64 each), the key and descriptor of its proto
+    body (``None`` for a type with no body)."""
+
+    def __init__(self, tag: int, ints: tuple, body: Optional[str],
+                 desc=None):
+        self.tag = bytes((tag,))
+        self.ints = ints
+        self.head = struct.Struct(">" + "q" * len(ints))
+        self.body = body
+        self.desc = desc
+        self.keys = frozenset(("type", *ints) + ((body,) if body else ()))
+        self.fields = frozenset(f.name for f in desc.fields) \
+            if desc is not None else frozenset()
+
+
+_KINDS = {
+    "vote": _Kind(1, (), "vote", pb.VOTE),
+    "proposal": _Kind(2, (), "proposal", pb.PROPOSAL),
+    "block_part": _Kind(3, ("height", "round"), "part", pb.PART),
+    "aggregate_commit": _Kind(4, (), "commit", pb.AGGREGATE_COMMIT),
+    "timeout": _Kind(5, ("height", "round", "step"), None),
+    "end_height": _Kind(6, ("height",), None),
+}
+_BY_TAG = {k.tag: (t, k) for t, k in _KINDS.items()}
+
+# records decoded in this process, by payload: plain integers read at
+# scrape time, as the vote counters (types/vote.py)
+_RECORDS = {"binary": 0, "json": 0}
+
+
+def record_counts() -> dict:
+    """Records decoded so far in this process, by codec."""
+    return dict(_RECORDS)
+
+
+libmetrics.DEFAULT.counter_func(
+    "consensus", "wal_records_total",
+    "WAL records decoded, by the payload they were read from: binary "
+    "(a tag byte, then the proto of a message or the integers of a "
+    "timeout or end-height marker) or json (canonical JSON: any other "
+    "record, and every record of a WAL written before the binary "
+    "payload).", "codec", lambda: _RECORDS)
+
+
+def encode_record(msg: dict) -> bytes:
+    """A record's payload: binary for a type of ``_KINDS`` whose keys
+    are exactly that type's, with ints for its integers and, for a
+    message, a proto dict of its descriptor as its body (what
+    ``to_wal()`` gives); canonical JSON for anything else."""
+    kind = _KINDS.get(msg.get("type"))
+    if kind is not None and msg.keys() == kind.keys:
+        ints = [msg[k] for k in kind.ints]
+        body = msg.get(kind.body)
+        if all(type(v) is int for v in ints) and (
+                kind.body is None or
+                (type(body) is dict and body.keys() <= kind.fields)):
+            try:
+                out = kind.tag + kind.head.pack(*ints)
+                return out if kind.body is None else \
+                    out + encode(kind.desc, body)
+            except (TypeError, ValueError, OverflowError, struct.error):
+                pass        # a value the proto cannot hold
+    return json.dumps(jsonify(msg), separators=(",", ":"),
+                      sort_keys=True).encode()
+
+
+def decode_record(payload: bytes) -> dict:
+    """A payload as a dict: ``type``, the integer fields, and a
+    message's body under its key (``vote``, ``proposal``, ``part``,
+    ``commit``) as a proto dict; a JSON record with its bytes
+    restored.  Counted here, once a record."""
+    if payload[:1] == b"{":
+        _RECORDS["json"] += 1
+        return dejsonify(json.loads(payload))
+    t, kind = _BY_TAG.get(payload[:1], (None, None))
+    if kind is None:
+        raise CorruptWALError(f"unknown record tag {payload[:1].hex()}")
+    _RECORDS["binary"] += 1
+    rec = {"type": t}
+    if kind.ints:
+        rec.update(zip(kind.ints, kind.head.unpack_from(payload, 1)))
+    if kind.body is not None:
+        rec[kind.body] = decode(kind.desc, payload[1 + kind.head.size:])
+    return rec
+
+
+def _ends_height(payload: bytes, height: int) -> bool:
+    """Whether a payload is the end-height marker of ``height``; a
+    binary message's body is not decoded to tell."""
+    if payload[:1] not in (b"{", _KINDS["end_height"].tag):
+        return False
+    rec = decode_record(payload)
+    return rec.get("type") == "end_height" and rec.get("height") == height
 
 
 class WAL:
@@ -84,8 +193,7 @@ class WAL:
 
     def write(self, msg: dict) -> None:
         """Buffered append (reference: WAL.Write for peer messages)."""
-        payload = json.dumps(msg, separators=(",", ":"),
-                             sort_keys=True).encode()
+        payload = encode_record(msg)
         if len(payload) > MAX_MSG_SIZE_BYTES:
             raise WALError(f"msg is too big: {len(payload)} bytes")
         self._f.write(_frame(payload))
@@ -167,14 +275,17 @@ class WAL:
             yield msg
 
     @staticmethod
-    def iter_frames(path: str, strict: bool = False
-                    ) -> Iterator[tuple[dict, int]]:
+    def iter_frames(path: str, strict: bool = False,
+                    decode: Callable[[bytes], Any] = decode_record
+                    ) -> Iterator[tuple[Any, int]]:
         """(record, bytes of its frame) for one file, read a frame at
         a time: a playback of a long WAL holds one record, not the
-        file.  A torn tail (crash mid-write: a header or payload cut
-        short) is a clean stop unless strict; a whole frame that fails
-        its CRC or claims more than the size limit is corruption,
-        always an error — the rule of ``_scan_valid_prefix``."""
+        file.  ``decode`` makes the record of a payload (``bytes``:
+        the payload itself).  A torn tail (crash mid-write: a header or
+        payload cut short) is a clean stop unless strict; a whole frame
+        that fails its CRC or claims more than the size limit is
+        corruption, always an error — the rule of
+        ``_scan_valid_prefix``."""
         with open(path, "rb") as f:
             pos = 0
             while True:
@@ -191,7 +302,7 @@ class WAL:
                         if zlib.crc32(payload) & 0xFFFFFFFF != crc:
                             raise CorruptWALError(
                                 f"crc mismatch at offset {pos}")
-                        yield json.loads(payload), 8 + length
+                        yield decode(payload), 8 + length
                         pos += 8 + length
                         continue
                 if strict:
@@ -202,17 +313,18 @@ class WAL:
     def search_for_end_height(path: str, height: int
                               ) -> Optional[list[dict]]:
         """Messages AFTER the end-height marker for `height`, or None if
-        the marker is absent (reference: SearchForEndHeight)."""
+        the marker is absent (reference: SearchForEndHeight).  No
+        message before the marker is decoded."""
         if not WAL.group_files(path):
             return None
         found = False
         out: list[dict] = []
-        for msg in WAL.iter_group(path):
-            if found:
-                out.append(msg)
-            elif msg.get("type") == "end_height" and \
-                    msg.get("height") == height:
-                found = True
+        for f in WAL.group_files(path):
+            for payload, _ in WAL.iter_frames(f, decode=bytes):
+                if found:
+                    out.append(decode_record(payload))
+                elif _ends_height(payload, height):
+                    found = True
         return out if found else None
 
 

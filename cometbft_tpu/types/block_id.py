@@ -41,11 +41,21 @@ class BlockID:
 
     @classmethod
     def from_proto(cls, d: dict) -> "BlockID":
-        return cls(
-            hash=d.get("hash", b""),
-            part_set_header=PartSetHeader.from_proto(
-                d.get("part_set_header") or {}),
-        )
+        """The BlockID of a proto dict; the one made last when its
+        fields are the same (a run of votes for one block shares one
+        frozen object)."""
+        global _last
+        psh = d.get("part_set_header") or {}
+        key = (d.get("hash", b""), psh.get("total", 0),
+               psh.get("hash", b""))
+        last = _last
+        if last[0] == key and type(last[1]) is cls:
+            return last[1]
+        bid = cls(hash=key[0],
+                  part_set_header=PartSetHeader(total=key[1],
+                                                hash=key[2]))
+        _last = (key, bid)
+        return bid
 
     def __str__(self) -> str:
         if self.is_nil():
@@ -54,3 +64,5 @@ class BlockID:
 
 
 NIL_BLOCK_ID = BlockID()
+# from_proto's last (fields, BlockID), swapped whole
+_last: tuple = ((), None)

@@ -314,6 +314,9 @@ _ALLOWED_LABELS = {
                     # the indices of libs/tracing's totals
     "mode",         # CPU time in user or system mode: two literals,
                     # the keys of libs/metrics._cpu_seconds
+    "codec",        # the payload a WAL record was read from: two
+                    # literals (binary / json), the keys of
+                    # consensus/wal._RECORDS
 }
 
 
